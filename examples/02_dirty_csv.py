@@ -14,8 +14,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import tempfile
 
-import _platform  # noqa: F401 (platform default)
-
 import tuplex_tpu as tuplex
 
 path = os.path.join(tempfile.mkdtemp(), "sales.csv")

@@ -6,8 +6,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import _platform  # noqa: F401 (platform default)
-
 import tuplex_tpu as tuplex
 
 c = tuplex.Context()

@@ -18,8 +18,6 @@ import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-import _platform  # noqa: F401 (platform default)
-
 import tuplex_tpu
 
 tmp = tempfile.mkdtemp()

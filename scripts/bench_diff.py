@@ -1,18 +1,20 @@
 #!/usr/bin/env python3
 """Compare two bench JSON files and fail on regressions.
 
-The bench trajectory (BENCH_r01..r05, serve_bench output) has so far been
-checked by eyeball; this makes it a gate:
+Bench output used to be checked by eyeball; this makes it a gate over two
+saved result lines:
 
-    python scripts/bench_diff.py BENCH_r04.json BENCH_r05.json
+    python bench.py > old.json;  <change>;  python bench.py > new.json
     python scripts/bench_diff.py old.json new.json --threshold 0.05
-    python scripts/bench_diff.py a.json b.json --keys value compile_s
+    python scripts/serve_bench.py --out a.json   # likewise b.json
+    python scripts/bench_diff.py a.json b.json --keys p99_s compile_s
 
 Accepts either shape per file:
-  * a driver wrapper ``{"parsed": {...}, ...}`` (the committed BENCH_r*
-    files) — the ``parsed`` dict is compared;
   * a raw result line ``{"metric": ..., "value": ..., ...}`` (bench.py /
-    scripts/serve_bench.py stdout).
+    scripts/serve_bench.py stdout, or serve_bench's ``--out`` file such
+    as the committed BENCH_SERVE.json);
+  * a wrapper ``{"parsed": {...}, ...}`` around such a line — the
+    ``parsed`` dict is compared.
 
 Every numeric key present in BOTH files is compared with a per-key
 direction (rows/s and speedups must not fall; compile seconds, transfer

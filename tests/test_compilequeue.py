@@ -163,6 +163,89 @@ def test_aot_reuse_across_processes(fresh_cache, tmp_path):
     assert second["rows"] == first["rows"] and second["n"] == first["n"]
 
 
+_DEVICE_CHILD = """
+import json, sys
+sys.path.insert(0, {repo!r})
+import numpy as np
+import jax
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+import tuplex_tpu
+from tuplex_tpu.exec import compilequeue as CQ
+
+assert len(jax.devices()) == 8
+
+
+def fn(d):
+    return {{"y": d["x"] * 3 + 1}}
+
+
+x = np.arange(64, dtype=np.int64)
+one = CQ.aot_jit(fn, tag="one")({{"x": x}})["y"]
+mesh = Mesh(np.array(jax.devices()[2:6]), ("data",))
+xs = jax.device_put(x, NamedSharding(mesh, P("data")))
+four = CQ.aot_jit(fn, salt="/mesh0x4", tag="four")({{"x": xs}})["y"]
+print(json.dumps({{
+    "one": np.asarray(one).tolist() == (x * 3 + 1).tolist(),
+    "one_devices": sorted(d.id for d in one.devices()),
+    "four": np.asarray(four).tolist() == (x * 3 + 1).tolist(),
+    "four_devices": sorted(d.id for d in four.devices()),
+    "stats": CQ.snapshot()}}))
+"""
+
+
+def test_aot_load_runs_on_the_compiled_devices(fresh_cache, tmp_path):
+    """Regression (jax 0.9): ``deserialize_and_load`` defaults to EVERY
+    device of the backend, so on an 8-device platform a stored one-device
+    executable came back as an 8-shard one and the first call raised
+    "Expected args to execute_sharded_on_local_devices to have 8 shards"
+    — which the backend swallowed, running the stage in the interpreter.
+    A second process must load each artifact onto exactly the devices it
+    was compiled for: device 0, and a 4-device mesh that is not 0..3."""
+    script = tmp_path / "device_child.py"
+    script.write_text(_DEVICE_CHILD.format(
+        repo=os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+    env = dict(os.environ)
+    env["TUPLEX_AOT_CACHE"] = fresh_cache
+
+    def run():
+        r = subprocess.run([sys.executable, str(script)],
+                           capture_output=True, text=True, env=env,
+                           timeout=300)
+        assert r.returncode == 0, r.stderr[-2000:]
+        return json.loads(r.stdout.splitlines()[-1])
+
+    first = run()
+    assert first["stats"]["stage_compiles"] == 2
+    second = run()
+    for res in (first, second):
+        assert res["one"] and res["four"], res
+        assert res["one_devices"] == [0]
+        assert res["four_devices"] == [2, 3, 4, 5]
+    assert second["stats"]["stage_compiles"] == 0, second["stats"]
+    assert second["stats"]["aot_hits"] == 2
+    assert second["stats"]["aot_errors"] == 0
+
+
+def test_aot_artifact_for_absent_devices_is_a_miss(fresh_cache):
+    """An artifact whose recorded devices this process does not have is a
+    plain miss (recompile), never a load onto other devices."""
+    import jax
+    import numpy as np
+
+    def fn(d):
+        return {"y": d["x"] + 7}
+
+    avals = ({"x": jax.ShapeDtypeStruct((32,), np.int64)},)
+    compiled = CQ.compile_traced(fn, avals)
+    fp = CQ.fingerprint_fn(fn, avals)
+    meta = CQ._artifact_meta(compiled)
+    assert meta["device_ids"] == [0] and meta["exec_platform"] == "cpu"
+    assert [d.id for d in CQ._load_devices(meta)] == [0]
+    assert CQ._load_devices(dict(meta, device_ids=[4096])) is None
+    assert CQ._load_devices(dict(meta, device_ids=[])) is None
+    assert CQ._disk_load(fp) is not None
+
+
 def test_fingerprint_salt_and_donation_sensitivity(fresh_cache):
     """The cache key must move with anything that changes what the
     executable MEANS: donation spec, packing flag, mesh epoch salt."""

@@ -94,6 +94,48 @@ def test_platform_peaks_env_override(monkeypatch):
     assert p.kind == "override"
 
 
+class _FakeDev:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+@pytest.mark.parametrize("kind,flops,bw", [
+    ("TPU v5 lite", 197e12, 819e9),      # what a v5e reports
+    ("TPU v5e", 197e12, 819e9),
+    ("TPU v4", 275e12, 1228e9),
+])
+def test_platform_peaks_known_tpu_from_the_table(monkeypatch, kind, flops,
+                                                 bw):
+    import jax
+
+    monkeypatch.delenv("TUPLEX_DEVPROF_PEAKS", raising=False)
+    monkeypatch.setattr(jax, "devices", lambda *a: [_FakeDev("tpu", kind)])
+    DP.clear()
+    p = DP.platform_peaks()
+    assert (p.flops_per_s, p.bytes_per_s, p.kind) == (flops, bw, "table")
+    DP.clear()
+
+
+def test_unknown_accelerator_gets_no_peaks_and_no_roofline_share(
+        monkeypatch):
+    """An accelerator that is not in _TPU_PEAKS used to be handed v2's
+    peaks as an 'estimate'. It has no roof here: labeled unknown, achieved
+    rates only, no share of anything."""
+    import jax
+
+    monkeypatch.delenv("TUPLEX_DEVPROF_PEAKS", raising=False)
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDev("tpu", "TPU v9 mystery")])
+    DP.clear()
+    p = DP.platform_peaks()
+    assert p.kind == "unknown" and p.name == "tpu v9 mystery"
+    assert p.flops_per_s == 0.0 and p.bytes_per_s == 0.0
+    r = DP.roofline(1e10, 1e8, 0.1)
+    assert r == {"achieved_flops_per_s": 1e11, "achieved_bytes_per_s": 1e9}
+    assert DP.roofline(0.0, 5e9, 0.5) == {"achieved_bytes_per_s": 1e10}
+    DP.clear()
+
+
 # ---------------------------------------------------------------------------
 # StageCost harvest + sidecar persistence
 # ---------------------------------------------------------------------------

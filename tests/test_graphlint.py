@@ -174,10 +174,17 @@ def test_compile_hazard_is_a_compile_timeout():
 # construct-weighted split planning (plan/splittuner, satellite 1)
 # ---------------------------------------------------------------------------
 
+# the synthetic platform's op-count curve, handed in explicitly: the tuner
+# carries no curve for a platform it has not observed (and would keep every
+# stage fused, hazard costs or not)
+TESTONLY_CURVE = (20.0, 1.5, 1.8)
+
+
 def test_scatter_heavy_splits_differently_than_elementwise():
     from tuplex_tpu.plan import splittuner as ST
 
-    model = ST.CompileModel("testonly", path="")
+    model = ST.CompileModel("testonly", path="",
+                            default_curve=TESTONLY_CURVE)
     # budget above the op-count curve's fused prediction for 12 ops, so
     # the construct mix — not the curve — decides the split
     budget = 2.0 * model.predict(12)
@@ -201,7 +208,8 @@ def test_scatter_heavy_splits_differently_than_elementwise():
 def test_hazard_split_bounds_worst_segment():
     from tuplex_tpu.plan import splittuner as ST
 
-    model = ST.CompileModel("testonly", path="")
+    model = ST.CompileModel("testonly", path="",
+                            default_curve=TESTONLY_CURVE)
     costs = [1.0, 1.0, 20.0, 1.0, 1.0, 1.0]
     dec = ST.plan_split(6, 25.0, model, prefer_fusion=True,
                         op_costs=costs)

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from tuplex_tpu.ops import strings as S
+from tuplex_tpu.runtime.jaxcfg import jnp
 
 CORPUS = [
     "hello world",
@@ -217,6 +218,64 @@ def test_parse_f64_long_mantissa_routes():
         assert not route[i]
         want = float(vals[i])
         assert abs(got[i] - want) <= 1e-9 * want
+
+
+def test_decimal_to_binary53_is_strtod():
+    """The integer decimal->binary conversion (the parse_f64 path of a
+    device whose float64 is a float32 pair) rounds exactly as CPython's
+    float(): M * 2^E == float(Fraction(m) * 10**e), every case."""
+    import math
+    import random
+    from fractions import Fraction
+
+    rnd = random.Random(24)
+    ms = [0, 5, 1, 2 ** 53 + 1, 2 ** 63 - 1, 10 ** 18 - 1] + \
+        [rnd.getrandbits(63) >> rnd.randint(0, 62) for _ in range(4000)]
+    es = [-2, -2, 22, -27, 0, -18] + \
+        [rnd.randint(-27, 27) for _ in range(4000)]
+    M, E, ok = (np.asarray(x).tolist() for x in S.decimal_to_binary53(
+        jnp.asarray(np.array(ms, dtype=np.uint64)),
+        jnp.asarray(np.array(es, dtype=np.int32))))
+    checked = 0
+    for m, e, mm, ee, o in zip(ms, es, M, E, ok):
+        if not o:       # left 64 bits: the caller routes the row
+            assert e > 0 and m * 5 ** e >= 2 ** 63, (m, e)
+            continue
+        checked += 1
+        assert math.ldexp(mm, ee) == float(Fraction(m) * Fraction(10) ** e), \
+            (m, e, mm, ee)
+    assert checked > 3000
+    # out of range on either axis is flagged, never converted
+    _, _, ok = S.decimal_to_binary53(
+        jnp.asarray(np.array([7, 2 ** 63], dtype=np.uint64)),
+        jnp.asarray(np.array([28, 0], dtype=np.int32)))
+    assert not np.asarray(ok).any()
+
+
+def test_parse_f64_f32_pair_path(monkeypatch):
+    """parse_f64 as a TPU traces it (f64_is_f32_pair steered): the value is the
+    float32 pair the device holds for CPython's float — hi = RN24(x),
+    lo = RN24(x - hi) — so a parsed "0.05" equals the constant 0.05 there
+    (TPC-H Q6's boundary); what the integers cannot convert ROUTES."""
+    monkeypatch.setattr(S, "f64_is_f32_pair", lambda: True)
+    vals = ["0.05", "0.07", "-2.25", "1e3", "2.5e-2", "123456.78", "-0.0",
+            "  7.0 ", "9007199254740993", "x", "1.2.3", "",
+            "1234567890123456789", "1e-28", "1e40", "inf"]
+    b, l = enc(vals)
+    got, bad, route = (np.asarray(x).tolist() for x in S.parse_f64(b, l))
+
+    def pair(x):
+        hi = np.float32(x)
+        return float(np.float64(hi) + np.float64(np.float32(x - np.float64(hi))))
+
+    assert bad == [False] * 9 + [True] * 3 + [False] * 4
+    assert route == [False] * 12 + [True] * 4
+    for s, g in list(zip(vals, got))[:9]:
+        assert g == pair(float(s)), (s, g)
+    monkeypatch.setattr(S, "f64_is_f32_pair", lambda: False)
+    got, bad, route = (np.asarray(x).tolist() for x in S.parse_f64(b, l))
+    assert got[0] == 0.05 and got[8] == float("9007199254740993")
+    assert route[12:] == [False, False, False, True]
 
 
 def test_nfa_regex_golden():

@@ -129,7 +129,7 @@ class Metrics:
 
     def d2hBytes(self) -> int:
         """Device->host transfer bytes attributed per stage (the boundary
-        tunnel tax the varlen wire / handoff work is judged against)."""
+        transfer tax the varlen wire / handoff work is judged against)."""
         return sum(int(m.get("d2h_bytes", 0)) for m in self.stages)
 
     def h2dBytes(self) -> int:

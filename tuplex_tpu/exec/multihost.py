@@ -46,6 +46,7 @@ class MultiHostBackend(LocalBackend):
         self.mesh = M.make_mesh(n)
         self.n_devices = n
         self._mesh_epoch = 0    # bumped on elastic shrink
+        self.shard_layout: dict = {}    # see _note_shards
         # span streams key their pid lane by the HOST (jax process index)
         # so per-host dumps merge into one driver timeline without
         # colliding; single-process runs keep the default OS pid
@@ -127,22 +128,41 @@ class MultiHostBackend(LocalBackend):
 
     def _jit_stage_fn(self, raw_fn, packed: bool = True, tag: str = "",
                       n_ops: int = 0):
-        """Row-shard over ALL mesh devices (`packed`/`tag`/`n_ops` are
-        accepted for interface parity and ignored: mesh staging is per-leaf
-        sharded device_put, and sharded executables stay outside the AOT
-        artifact store — serialized sharding layouts are not portable
-        across mesh epochs). Non-pow2 meshes work too: the
+        """Row-shard over ALL mesh devices (`packed` is accepted for
+        interface parity and ignored: mesh staging is per-leaf sharded
+        device_put). Single-process meshes compile through the AOT store
+        keyed on the mesh epoch (fn_cache_salt): the artifact records the
+        mesh's device ids and a later process loads it onto those same
+        devices. Non-pow2 meshes work too: the
         batch pads up to a multiple of the mesh size before dispatch (padded
         rows carry #rowvalid=False and the host slices outputs back to the
         partition's row count) — round 1 silently rounded 6 devices down to
         4 and kept a dead pow2 raise here."""
-        inner = M.shard_stage_fn(raw_fn, self.mesh)
+        inner = M.shard_stage_fn(
+            raw_fn, self.mesh, salt=self.fn_cache_salt(), tag=tag,
+            n_ops=n_ops,
+            deadline=self.options.get_float("tuplex.tpu.compileDeadlineS",
+                                            0.0),
+            on_dispatch=self._note_shards)
         n_dev = self.n_devices
 
         def padded_dispatch(arrays):
             return inner(M.pad_batch_for_mesh(arrays, n_dev))
 
         return padded_dispatch
+
+    def _note_shards(self, placed, outs) -> None:
+        """Where the largest dispatch so far kept its batch: per-device
+        shard shapes of one staged input and one output (metadata only;
+        callers reset ``shard_layout`` to {} to start a new window)."""
+        if "#rowvalid" not in placed or "#err" not in outs:
+            return
+        rows = placed["#rowvalid"].shape[0]
+        if rows > self.shard_layout.get("rows", 0):
+            self.shard_layout = {
+                "rows": rows,
+                "input": M.shard_layout(placed["#rowvalid"]),
+                "output": M.shard_layout(outs["#err"])}
 
     # -- host-sharded reads (each process staged ONLY its byte range) ------
     def execute(self, stage, partitions, intermediate: bool = False):

@@ -8,10 +8,18 @@ width loop inside one kernel instance, keeping the state, the follow
 matrix, and the class table resident in VMEM (the Pallas playbook:
 sequential dependence inside the kernel, parallelism across the grid).
 
+The kernel is TRANSPOSED relative to match_dense: rows ride the 128-lane
+axis and string positions the sublane axis, so the per-step byte column
+is a dynamic SUBLANE slice of an int32 block (`bytes_ref[pl.ds(j, 1), :]`).
+The row-major layout needed a dynamic lane slice of a u8 block, which
+Mosaic refuses ("cannot statically prove that index in dimension 1 is a
+multiple of 128"). State is [Pp, B]; both products are taken from the
+left (classT @ onehot, followT @ S).
+
 Selected with TUPLEX_NFA_IMPL=pallas. On CPU the kernel runs in Pallas
 interpret mode (slow, for correctness tests); on TPU it compiles to
-Mosaic. Position tables pad to sublane multiples (8); Mosaic handles the
-lane-width relayout.
+Mosaic — tests/test_chip_compile.py compiles it for a described v5e.
+Position tables pad to sublane multiples (8).
 """
 
 from __future__ import annotations
@@ -30,98 +38,87 @@ def _build_kernel(P: int, w: int, anchored_start: bool, anchored_end: bool,
                   interpret: bool):
     from jax.experimental import pallas as pl
 
-    # pad positions to a SUBLANE multiple (8); Mosaic relayouts the
-    # 8-wide tiles onto 128-lane registers itself — padding P to 128
-    # here would waste 16x matmul work for small patterns
+    # pad positions to a SUBLANE multiple (8): padding P to 128 would
+    # waste 16x matmul work for small patterns
     Pp = max(8, -(-P // 8) * 8)
+    B = _ROW_BLOCK
 
     def kernel(bytes_ref, lens_ref, end_ref, m0_ref, follow_ref, class_ref,
                first_ref, last_ref, out_ref):
-        S = jnp.zeros((_ROW_BLOCK, Pp), dtype=jnp.float32)
-        matched = m0_ref[...] > 0.5
-        lens = lens_ref[...]
+        lens = lens_ref[...]                                  # [1, B]
         end_at = end_ref[...]
-        follow = follow_ref[...]
-        firstv = first_ref[...]
+        followT = follow_ref[...]                             # [Pp, Pp]
+        classT = class_ref[...]                               # [Pp, 256]
+        firstv = first_ref[...]                               # [Pp, 1]
         lastv = last_ref[...]
+        codes = jax.lax.broadcasted_iota(jnp.int32, (256, B), 0)
+        # f32 literals: under x64 a bare 1.0 is f64, and Mosaic has no
+        # f64->f32 cast
+        one = jnp.float32(1.0)
+        zero = jnp.float32(0.0)
 
         def body(j, carry):
             S, matched = carry
-            byte_col = bytes_ref[:, j]
-            if interpret:
-                # gather is legal (and far cheaper) off-Mosaic
-                cm = class_ref[byte_col, :]                   # [B, Pp]
-            else:
-                # class membership via one-hot matmul, not a ref gather:
-                # Mosaic rejects int indexing on VMEM refs ("Cannot do int
-                # indexing on TPU", mosaic/lowering.py — caught by
-                # tpu_diag/aot_lower_tpu.py), and the [B,256]x[256,Pp]
-                # product is MXU work anyway.
-                b32 = byte_col.astype(jnp.int32)
-                onehot = (b32[:, None] ==
-                          jnp.arange(256, dtype=jnp.int32)[None, :]
-                          ).astype(jnp.float32)               # [B, 256]
-                cm = jnp.dot(onehot, class_ref[...],
-                             preferred_element_type=jnp.float32)  # [B, Pp]
-            nxt = jnp.dot(S, follow,
+            row = bytes_ref[pl.ds(j, 1), :]                   # [1, B] i32
+            # class membership via one-hot matmul, not a ref gather:
+            # Mosaic rejects int indexing on VMEM refs, and the
+            # [Pp,256]x[256,B] product is MXU work anyway
+            onehot = jnp.where(codes == row, one, zero)       # [256, B]
+            cm = jnp.dot(classT, onehot,
+                         preferred_element_type=jnp.float32)  # [Pp, B]
+            nxt = jnp.dot(followT, S,
                           preferred_element_type=jnp.float32) > 0.5
             if anchored_start:
-                seed = jnp.where(j == 0, firstv, 0.0)[None, :]
+                seed = jnp.where(j == 0, firstv, zero)        # [Pp, 1]
             else:
-                seed = firstv[None, :]
-            # f32 literals: under x64 a bare 1.0 is f64, and Mosaic has no
-            # f64->f32 cast (finding 2 of 3 in tpu_diag/aot_lower_tpu.py;
-            # TPU_DIAGNOSIS.md lists all three)
-            one = jnp.float32(1.0)
-            zero = jnp.float32(0.0)
+                seed = firstv
             S2 = jnp.where((nxt | (seed > 0.5)) & (cm > 0.5), one, zero)
-            inb = (j < lens)[:, None]
-            S2 = jnp.where(inb, S2, zero)
-            hit = jnp.max(S2 * lastv[None, :], axis=1) > 0.5
+            S2 = jnp.where(j < lens, S2, zero)
+            hit = jnp.max(S2 * lastv, axis=0, keepdims=True) > 0.5
             if anchored_end:
                 hit = hit & ((j + 1 == lens) | (j + 1 == end_at))
-            return S2, matched | hit
+            return S2, jnp.where(hit, 1, matched)
 
-        S, matched = jax.lax.fori_loop(0, w, body, (S, matched))
+        _, matched = jax.lax.fori_loop(
+            0, w, body, (jnp.zeros((Pp, B), jnp.float32), m0_ref[...]))
         out_ref[...] = matched
 
-    def run(bytes_p, lens_p, end_p, m0_p, follow, classtab, firstv, lastv):
-        n_blocks = bytes_p.shape[0] // _ROW_BLOCK
+    def run(bytes_t, lens_p, end_p, m0_p, followT, classT, firstv, lastv):
+        npad = bytes_t.shape[1]
+        rows = pl.BlockSpec((1, B), lambda i: (0, i))
         return pl.pallas_call(
             kernel,
-            grid=(n_blocks,),
+            grid=(npad // B,),
             in_specs=[
-                pl.BlockSpec((_ROW_BLOCK, w), lambda i: (i, 0)),
-                pl.BlockSpec((_ROW_BLOCK,), lambda i: (i,)),
-                pl.BlockSpec((_ROW_BLOCK,), lambda i: (i,)),
-                pl.BlockSpec((_ROW_BLOCK,), lambda i: (i,)),
+                pl.BlockSpec((w, B), lambda i: (0, i)),
+                rows, rows, rows,
                 pl.BlockSpec((Pp, Pp), lambda i: (0, 0)),
-                pl.BlockSpec((256, Pp), lambda i: (0, 0)),
-                pl.BlockSpec((Pp,), lambda i: (0,)),
-                pl.BlockSpec((Pp,), lambda i: (0,)),
+                pl.BlockSpec((Pp, 256), lambda i: (0, 0)),
+                pl.BlockSpec((Pp, 1), lambda i: (0, 0)),
+                pl.BlockSpec((Pp, 1), lambda i: (0, 0)),
             ],
-            out_specs=pl.BlockSpec((_ROW_BLOCK,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((bytes_p.shape[0],), jnp.bool_),
+            out_specs=rows,
+            out_shape=jax.ShapeDtypeStruct((1, npad), jnp.int32),
             interpret=interpret,
-        )(bytes_p, lens_p, end_p, m0_p, follow, classtab, firstv, lastv)
+        )(bytes_t, lens_p, end_p, m0_p, followT, classT, firstv, lastv)
 
     return run, Pp
 
 
 def match_pallas(rx, bytes_, lens, interpret=None):
-    """Drive the kernel: pad rows to the block multiple and positions to
-    sublane width, then slice the matches back. `interpret=None` picks
-    automatically (Mosaic on TPU, interpret elsewhere); tpu_diag's AOT
-    lowering passes False explicitly to force the Mosaic path from a CPU
-    host."""
+    """Drive the kernel: transpose to [w, N] int32, pad rows to the block
+    multiple and positions to sublane width, then slice the matches back.
+    `interpret=None` picks automatically (Mosaic on TPU, interpret
+    elsewhere); the chip-compile test passes False explicitly to force
+    the Mosaic path from a CPU host."""
     n, w = bytes_.shape
     P = rx.n_pos
     if P == 0:          # pure-anchor pattern ('^$'): decided by matched0
         lens64, end_at = rx._end_masks(bytes_, lens, w)
         return rx._matched0(n, end_at)
     if interpret is None:
-        # Mosaic is the only native target this kernel is tuned for (1D
-        # blocks, VMEM-resident tables); every other backend interprets
+        # Mosaic is the only native target this kernel is tuned for;
+        # every other backend interprets
         interpret = jax.default_backend() != "tpu"
     run, Pp = _build_kernel(P, w, rx.anchored_start, rx.anchored_end,
                             interpret)
@@ -131,35 +128,25 @@ def match_pallas(rx, bytes_, lens, interpret=None):
 
     npad = -(-max(n, 1) // _ROW_BLOCK) * _ROW_BLOCK
 
-    def padrows(a, fill=0):
-        return jnp.pad(a, ((0, npad - n),) + ((0, 0),) * (a.ndim - 1),
-                       constant_values=fill)
+    def row(a):         # [N] -> [1, npad] i32
+        return jnp.pad(a.astype(jnp.int32), (0, npad - n))[None, :]
 
-    def padP(a):
-        return jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, Pp - P),))
+    def table(a, cols):     # numpy [r, c] -> f32 [Pp, cols]
+        return jnp.asarray(np.pad(a, ((0, Pp - a.shape[0]),
+                                      (0, cols - a.shape[1]))))
 
     # trace the kernel with x64 OFF: global x64 + pallas_call + the Mosaic
-    # TPU lowering recurses without bound in jax 0.9 (RecursionError even at
-    # limit 100k — minimized repro in tpu_diag/aot_lower_tpu.py notes). All
-    # kernel inputs are explicitly 32-bit, so narrowing the promotion rules
-    # changes nothing semantically. `jax.enable_x64` is the new-jax name;
-    # older releases ship the same context manager as
-    # jax.experimental.disable_x64.
-    try:
-        _x64_off = jax.enable_x64(False)
-    except AttributeError:
-        from jax.experimental import disable_x64 as _dx64
-
-        _x64_off = _dx64()
-    with _x64_off:
+    # TPU lowering recurses without bound in jax 0.9 (RecursionError even
+    # at limit 100k). All kernel inputs are explicitly 32-bit, so narrowing
+    # the promotion rules changes nothing semantically.
+    with jax.enable_x64(False):
         out = run(
-            padrows(bytes_), padrows(lens64.astype(jnp.int32)),
-            padrows(end_at.astype(jnp.int32)),
-            padrows(m0.astype(jnp.float32)),
-            padP(jnp.asarray(np.pad(rx._follow_dense,
-                                    ((0, Pp - P), (0, 0))))),
-            padP(jnp.asarray(rx._classtab_dense)),
-            padP(jnp.asarray(rx._first_dense)),
-            padP(jnp.asarray(rx._last_dense)),
+            jnp.pad(jnp.transpose(bytes_).astype(jnp.int32),
+                    ((0, 0), (0, npad - n))),
+            row(lens64), row(end_at), row(m0),
+            table(rx._follow_dense.T, Pp),
+            table(rx._classtab_dense.T, 256),
+            table(rx._first_dense[:, None], 1),
+            table(rx._last_dense[:, None], 1),
         )
-    return out[:n]
+    return out[0, :n] > 0

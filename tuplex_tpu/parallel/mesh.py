@@ -43,13 +43,28 @@ def row_sharding(mesh, axis: str = DATA_AXIS):
     return NamedSharding(mesh, P(axis))
 
 
-def shard_stage_fn(raw_fn, mesh, axis: str = DATA_AXIS):
-    """jit a stage function with every leading-dim array row-sharded over the
-    mesh. Row-wise stage bodies partition trivially (XLA inserts no
+def shard_layout(x) -> list:
+    """[(device id, shard shape)] of a placed array — which device holds
+    which piece (chip_smoke --chips 4 checks a staged batch is spread over
+    the whole mesh, not parked on device 0)."""
+    return [(s.device.id, tuple(s.data.shape))
+            for s in x.addressable_shards]
+
+
+def shard_stage_fn(raw_fn, mesh, axis: str = DATA_AXIS, salt: str = "",
+                   tag: str = "", n_ops: int = 0, deadline=None,
+                   on_dispatch=None):
+    """Compile a stage function with every leading-dim array row-sharded
+    over the mesh. Row-wise stage bodies partition trivially (XLA inserts no
     collectives); reduction stages contain their own psums.
 
-    Single-process (CI's virtual mesh, a single-host TPU slice): inputs
-    device_put inside the jit. Multi-process (jax.distributed / DCN): each
+    Single-process (CI's virtual mesh, a single-host TPU slice): the batch
+    is placed row-sharded on the mesh and the fn compiles through the
+    content-addressed store (exec/compilequeue) for exactly that mesh —
+    `salt` carries the backend's mesh epoch, and a second process loads the
+    stored executable onto the same devices instead of compiling.
+    `on_dispatch(placed, outs)` sees each call's device arrays.
+    Multi-process (jax.distributed / DCN): each
     process stages ONLY ITS ROW RANGE of the batch
     (make_array_from_process_local_data — host-sharded staging, so H2D is
     1/P per host), and outputs are constrained to replicated so every
@@ -62,12 +77,20 @@ def shard_stage_fn(raw_fn, mesh, axis: str = DATA_AXIS):
     nproc = jax.process_count()
 
     if nproc == 1:
-        def sharded(arrays):
-            placed = {k: jax.device_put(v, shard if v.ndim else repl)
-                      for k, v in arrays.items()}
-            return raw_fn(placed)
+        from ..exec.compilequeue import aot_jit
 
-        return jax.jit(sharded)
+        jfn = aot_jit(raw_fn, salt=salt, tag=tag, n_ops=n_ops,
+                      deadline=deadline)
+
+        def sharded(arrays):
+            placed = {k: jax.device_put(v, shard if np.ndim(v) else repl)
+                      for k, v in arrays.items()}
+            outs = jfn(placed)
+            if on_dispatch is not None:
+                on_dispatch(placed, outs)
+            return outs
+
+        return sharded
 
     def replicated_out(arrays):
         out = raw_fn(arrays)

@@ -1490,12 +1490,13 @@ def stage_fingerprint_prevet(stage: TransformStage):
 def _split_oversize(stage: TransformStage, options,
                     report=None) -> list:
     """Split a very large fused stage into balanced sub-stages on
-    accelerator backends. Remote TPU compiles scale superlinearly with
-    graph size (the 43-operator flights stage took >20 min in one
-    tpu_compile_helper call vs ~2-3 min for zillow's 13); two half-size
-    executables compile far faster and the intermediate rides the
-    device-resident handoff. CPU keeps maximal fusion (local XLA compiles
-    are cheap and stage boundaries cost real memcpys there).
+    accelerator backends. Compile time scales superlinearly with graph
+    size; two half-size executables can compile far faster and the
+    intermediate rides the device-resident handoff. CPU keeps maximal
+    fusion (stage boundaries cost real memcpys there) unless the
+    predicted compile blows the budget. A platform with no observed
+    compiles has no curve and is never split or degraded
+    (plan/splittuner.py).
 
     The split point is MEASURED, not hardcoded (plan/splittuner.py): the
     per-platform compile-seconds-vs-op-count curve (fed by every actual
@@ -1536,8 +1537,8 @@ def _split_oversize(stage: TransformStage, options,
         # CPU prefers fusion (boundaries are real memcpys, compiles are
         # usually cheap) and splits ONLY when the predicted compile blows
         # the budget — flights' 43-op mega-fusion ran >20 min at >120 GB
-        # on XLA:CPU, the same superlinear pathology as the tunnel.
-        # Accelerators cost-minimize across the whole curve.
+        # on XLA:CPU. Accelerators cost-minimize across the whole curve
+        # once compiles have been observed there (no curve, no split).
         from ..runtime import tracing as TR
 
         with TR.span("plan:split-tune", "plan") as _sp:
