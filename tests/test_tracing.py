@@ -60,15 +60,120 @@ def test_span_error_attribute(trace_on):
     assert e["args"]["error"] == "ValueError"
 
 
-def test_decorator_and_instant(trace_on):
-    @tracing.traced("decorated", "plan")
-    def f(x):
-        return x + 1
+def test_instant_and_complete_carry_the_cause(trace_on):
+    with tracing.span("job", "job") as j:
+        tracing.instant("marker", "exec", {"a": 1})
+        tracing.complete("waited", "compile", tracing.now_us() - 5.0, 5.0)
+    by = {e["name"]: e for e in tracing.events()}
+    assert by["marker"]["dur"] is None and by["waited"]["dur"] == 5.0
+    for name in ("marker", "waited"):
+        assert by[name]["parent"] == j.id and by[name]["job"] == j.id
+        assert by[name]["id"] not in (j.id, None)
+    assert not hasattr(tracing, "traced")      # the decorator form is gone
 
-    assert f(1) == 2
-    tracing.instant("marker", "exec", {"a": 1})
-    names = [e["name"] for e in tracing.events()]
-    assert "decorated" in names and "marker" in names
+
+def test_span_records_carry_id_parent_and_job(trace_on):
+    with tracing.span("outside", "plan"):
+        pass
+    with tracing.span("job", "job") as j:
+        with tracing.span("stage:execute", "exec") as st:
+            with tracing.span("partition:dispatch", "exec"):
+                pass
+        with tracing.span("collect:box-rows", "exec"):
+            pass
+    with tracing.span("job", "job") as j2:
+        pass
+    by = {e["name"]: e for e in tracing.events() if e["id"] != j2.id}
+    ids = [e["id"] for e in tracing.events()]
+    assert len(set(ids)) == len(ids) and all(isinstance(i, int)
+                                             for i in ids)
+    assert by["outside"]["parent"] is None and by["outside"]["job"] is None
+    assert by["job"]["parent"] is None and by["job"]["job"] == j.id
+    assert by["stage:execute"]["parent"] == j.id
+    assert by["partition:dispatch"]["parent"] == st.id
+    assert by["collect:box-rows"]["parent"] == j.id
+    assert {by[n]["job"] for n in ("stage:execute", "partition:dispatch",
+                                   "collect:box-rows")} == {j.id}
+    assert j2.job == j2.id != j.id             # every job is its own root
+    assert by["partition:dispatch"]["depth"] == 2      # depth stays
+
+
+def test_handoff_adopt_across_threads(trace_on):
+    assert tracing.handoff() is None           # nothing open: nothing to hand
+    seen = {}
+
+    def worker(h):
+        with tracing.adopt(h):
+            with tracing.span("worker-top", "io"):
+                with tracing.span("worker-inner", "io"):
+                    pass
+            seen["nested"] = tracing.handoff()   # re-handing what it adopted
+        with tracing.span("after-adopt", "io"):
+            pass
+
+    with tracing.span("job", "job") as j:
+        with tracing.span("submitter", "exec") as sub:
+            h = tracing.handoff()
+            t = threading.Thread(target=worker, args=(h,))
+            t.start()
+            t.join()
+    by = {e["name"]: e for e in tracing.events()}
+    assert by["worker-top"]["parent"] == sub.id
+    assert by["worker-top"]["job"] == j.id
+    assert by["worker-top"]["depth"] == 0      # top of ITS thread's stack
+    assert by["worker-top"]["tid"] != by["submitter"]["tid"]
+    assert by["worker-inner"]["parent"] == by["worker-top"]["id"]
+    assert by["worker-inner"]["job"] == j.id
+    assert seen["nested"] == h
+    # adoption ends with the block: pool workers are reused
+    assert by["after-adopt"]["parent"] is None
+    assert by["after-adopt"]["job"] is None
+
+
+def test_open_spans_sees_a_span_held_open_on_another_thread(trace_on):
+    opened, release = threading.Event(), threading.Event()
+
+    def hold():
+        with tracing.span("compile:xla", "compile") as sp:
+            sp.set("tag", "held")
+            opened.set()
+            release.wait(30)
+
+    with tracing.span("job", "job") as j:
+        t = threading.Thread(target=hold)
+        t.start()
+        assert opened.wait(30)
+        snap = tracing.open_spans()
+        names = [s["name"] for s in snap]
+        assert names == ["job", "compile:xla"]          # oldest first
+        held = snap[1]
+        assert {"name", "ts", "tid", "id", "parent", "job", "args"} \
+            <= set(held)
+        assert held["args"] == {"tag": "held"} and held["tid"] == t.ident
+        assert snap[0]["id"] == j.id
+        # still open: not in the ring yet
+        assert "compile:xla" not in [e["name"] for e in tracing.events()]
+        release.set()
+        t.join()
+    assert tracing.open_spans() == []
+    assert "compile:xla" in [e["name"] for e in tracing.events()]
+
+
+def test_dropped_counts_ring_evictions(trace_on, monkeypatch):
+    from collections import deque
+
+    monkeypatch.setattr(tracing, "_events", deque(maxlen=4))
+    assert tracing.dropped() == 0
+    for i in range(4):
+        tracing.instant(f"e{i}")
+    assert tracing.dropped() == 0
+    for i in range(3):
+        with tracing.span(f"s{i}"):
+            pass
+    assert tracing.dropped() == 3
+    assert [e["name"] for e in tracing.events()] == ["e3", "s0", "s1", "s2"]
+    tracing.clear()
+    assert tracing.dropped() == 0
 
 
 def test_disabled_is_noop_singleton_and_records_nothing():
@@ -83,13 +188,13 @@ def test_disabled_is_noop_singleton_and_records_nothing():
     tracing.instant("y")
     tracing.complete("z", "exec", 0.0, 1.0)
     assert tracing.events() == []
-
-    @tracing.traced()
-    def f():
-        return 7
-
-    assert f() == 7
-    assert tracing.events() == []
+    # the cross-thread pair is as free: nothing to hand, nothing adopted
+    assert tracing.handoff() is None
+    assert tracing.adopt(None) is tracing.NOOP
+    with tracing.adopt(tracing.handoff()):
+        with tracing.span("x"):
+            pass
+    assert tracing.events() == [] and tracing.open_spans() == []
 
 
 def test_disabled_zero_allocation_fast_path():
@@ -97,12 +202,21 @@ def test_disabled_zero_allocation_fast_path():
     tracing.clear()
     import tracemalloc
 
+    from tuplex_tpu.api.dataset import _harmonize
+
+    def hot():
+        tracing.span("hot", "exec")
+        with tracing.adopt(tracing.handoff()):
+            with tracing.span("dispatch:launch", "exec") as sp:
+                sp.set("module", None)
+        _harmonize([])            # a call site: `ingest:harmonize`
+
     for _ in range(64):           # warm any lazy caches
-        tracing.span("warm")
+        hot()
     tracemalloc.start()
     before = tracemalloc.take_snapshot()
     for _ in range(10000):
-        tracing.span("hot", "exec")
+        hot()
     after = tracemalloc.take_snapshot()
     tracemalloc.stop()
     grown = sum(s.size_diff for s in after.compare_to(before, "lineno")
@@ -444,6 +558,223 @@ def test_job_start_carries_lint_findings(ctx, tmp_path):
 # ===========================================================================
 # the zillow smoke (tier-1 wiring of scripts/trace_smoke.py)
 # ===========================================================================
+
+# ===========================================================================
+# spans where the work happens, names on the device, counters at the cause
+# ===========================================================================
+
+def _write_csv(path, n):
+    with open(path, "w") as fp:
+        fp.write("a,b,k\n")
+        for i in range(n):
+            fp.write(f"{i},{i * 0.5},{'xyz'[i % 3]}\n")
+    return os.path.getsize(path)
+
+
+def _descendants(evs, root_id):
+    kids = {}
+    for e in evs:
+        kids.setdefault(e.get("parent"), []).append(e)
+    out, todo = [], [root_id]
+    while todo:
+        for e in kids.get(todo.pop(), ()):
+            out.append(e)
+            todo.append(e["id"])
+    return out
+
+
+def test_csv_job_yields_ingest_and_boxing_spans_under_its_job(
+        ctx, trace_on, tmp_path):
+    p = str(tmp_path / "in.csv")
+    size = _write_csv(p, 3000)
+    x0 = xferstats.snapshot()
+    got = ctx.csv(p).map(lambda x: x["a"] + 1).collect()
+    assert got == [i + 1 for i in range(3000)]
+    evs = tracing.events()
+    (job,) = [e for e in evs if e["name"] == "job"]
+    under = _descendants(evs, job["id"])
+    names = {e["name"] for e in under}
+    assert {"ingest", "ingest:read-csv", "ingest:to-partition",
+            "ingest:harmonize", "compile:precompile-plan",
+            "dispatch:launch", "collect:box-rows"} <= names, names
+    assert all(e["job"] == job["id"] for e in under)
+    (read,) = [e for e in under if e["name"] == "ingest:read-csv"]
+    # projection pushdown: the pipeline reads one column of the three
+    assert read["args"] == {"bytes": size, "rows": 3000, "columns": 1}
+    (box,) = [e for e in under if e["name"] == "collect:box-rows"]
+    assert box["parent"] == job["id"] and box["args"]["rows"] == 3000
+    # the job span covers the boxing: it closes after its last child
+    assert box["ts"] + box["dur"] <= job["ts"] + job["dur"] + 1e-6
+    d = xferstats.delta(x0)
+    assert (d["ingest_bytes"], d["ingest_rows"], d["ingest_files"]) \
+        == (size, 3000, 1)
+    # before the job: the context's construction is outside this ring
+    # (the fixture made it before tracing went on), the sniff is in it
+    (sniff,) = [e for e in evs if e["name"] == "ingest:sniff"]
+    assert sniff["job"] is None and sniff["ts"] < job["ts"]
+    assert sniff["args"]["cached"] in (0, 1)
+    launch = [e for e in under if e["name"] == "dispatch:launch"][0]
+    assert str(launch["args"]["module"]).startswith("jit_tpx_")
+    assert launch["args"]["first_call"] == 1
+
+
+def test_context_init_span(trace_on):
+    import tuplex_tpu
+
+    c = tuplex_tpu.Context()
+    (e,) = [e for e in tracing.events() if e["name"] == "context:init"]
+    assert e["cat"] == "job" and e["job"] is None
+    assert e["args"] == {"backend": "LocalBackend", "devices": 1}
+    c.close()
+
+
+def test_lazy_source_reads_on_the_prefetch_thread_name_the_job(
+        trace_on, tmp_path):
+    import tuplex_tpu
+
+    c = tuplex_tpu.Context({"tuplex.inputSplitSize": "16KB",
+                            "tuplex.sample.maxDetectionRows": "64"})
+    p = str(tmp_path / "in.csv")
+    _write_csv(p, 20000)                  # a dozen batches of 16 KB
+    tracing.clear()
+    assert len(c.csv(p).take(5)) == 5
+    evs = tracing.events()
+    (job,) = [e for e in evs if e["name"] == "job"]
+    ingest = [e for e in evs if e["name"] == "ingest"]
+    assert ingest and all(e["job"] == job["id"] for e in ingest)
+    # the first pull is the job thread's own; the producer thread's pulls
+    # adopted the consumer's cause: top of their own stack, same job, and
+    # a parent that is a span of the job thread
+    produced = [e for e in ingest if e["tid"] != job["tid"]]
+    assert produced, [(e["tid"], e["depth"]) for e in ingest]
+    on_job_thread = {e["id"] for e in evs if e["tid"] == job["tid"]} \
+        | {job["id"]}
+    for e in produced:
+        assert e["depth"] == 0 and e["parent"] in on_job_thread
+    waits = [e for e in evs if e["name"] == "source:wait"]
+    assert waits and all(e["tid"] == job["tid"] and e["job"] == job["id"]
+                         for e in waits)
+    reads = [e for e in evs if e["name"] == "ingest:read-csv"]
+    assert reads and all(e["parent"] in {i["id"] for i in ingest}
+                         for e in reads)
+    c.close()
+
+
+def test_pool_compile_names_the_job_that_submitted_it(
+        ctx, trace_on, tmp_path):
+    from tuplex_tpu.exec import compilequeue as CQ
+
+    p = str(tmp_path / "in.csv")
+    _write_csv(p, 1500)
+    s0 = CQ.snapshot()
+    ctx.csv(p).map(lambda x: x["a"] * 7 + 3).collect()
+    deadline = time.time() + 120          # the speculative compile's spans
+    while time.time() < deadline:         # close on the pool, after the job
+        evs = tracing.events()
+        pool = [e for e in evs if e["name"] == "compile:trace"
+                and e["depth"] == 0]
+        if pool and not CQ.pending_info()["inflight"]:
+            break
+        time.sleep(0.05)
+    (job,) = [e for e in evs if e["name"] == "job"]
+    (pre,) = [e for e in evs if e["name"] == "compile:precompile-plan"]
+    assert pre["parent"] == job["id"]
+    assert pre["args"] == {"stages": 1, "submitted": 1}
+    assert pool, "no compile:trace span recorded on the pool"
+    for e in pool:
+        assert e["tid"] != job["tid"]
+        assert e["parent"] == pre["id"] and e["job"] == job["id"]
+    d = CQ.delta(s0)
+    assert d["prewarm_submitted"] >= 1
+    assert 0 <= d["prewarm_used"] <= d["prewarm_submitted"]
+    assert d["compile_starts"] >= d["stage_compiles"]
+    assert d["compile_starts"] - d["stage_compiles"] \
+        - d["compile_failures"] == 0      # nothing left in flight
+
+
+def test_aggregate_job_yields_the_four_agg_children(ctx, trace_on,
+                                                    tmp_path):
+    p = str(tmp_path / "in.csv")
+    _write_csv(p, 3000)
+    got = (ctx.csv(p).aggregateByKey(
+        lambda a, b: (a[0] + b[0], a[1] + b[1]),
+        lambda a, x: (a[0] + x["a"], a[1] + 1), (0, 0), ["k"]).collect())
+    assert sorted(got) == [("x", 1498500, 1000), ("y", 1499500, 1000),
+                           ("z", 1500500, 1000)]
+    evs = tracing.events()
+    (agg,) = [e for e in evs if e["name"] == "agg:execute"]
+    kids = [e for e in evs if e.get("parent") == agg["id"]]
+    names = [e["name"] for e in kids]
+    for n in ("agg:eval-exprs", "agg:factorize-keys", "agg:segment-fold",
+              "agg:host-merge"):
+        assert n in names, names
+    fk = [e for e in kids if e["name"] == "agg:factorize-keys"][0]
+    assert fk["args"] == {"rows": 3000, "groups": 3}
+    # the wrapper's self time is what its children leave over
+    covered = sum(e["dur"] for e in kids)
+    assert covered <= agg["dur"] + 1e-6
+    assert covered >= 0.5 * agg["dur"]
+
+
+def test_stage_module_is_named_and_fingerprint_ignores_the_name(ctx):
+    import jax
+
+    from tuplex_tpu.api.dataset import _source_partitions
+    from tuplex_tpu.compiler import stagefn as SF
+    from tuplex_tpu.exec import compilequeue as CQ
+    from tuplex_tpu.plan.physical import plan_stages
+
+    ds = (ctx.parallelize([(i, float(i)) for i in range(500)],
+                          columns=["a", "b"])
+          .map(lambda x: (x["a"] * 3, x["b"] + 1.5)))
+    st = plan_stages(ds._op, ctx.options_store)[0]
+    part = _source_partitions(ctx, st, lazy=False)[0]
+    avals = SF.partition_avals(part, "q8")
+    named = st.build_device_fn(part.schema)
+    assert named.__name__ == f"tpx_stage_{st.key()[:8]}"
+    plain = st.build_device_fn(part.schema)
+    plain.__name__ = plain.__qualname__ = "fn"     # as before this PR
+    t_named = jax.jit(named).trace(avals)
+    t_plain = jax.jit(plain).trace(avals)
+    assert t_named.lower().as_text().lstrip().startswith(
+        f"module @jit_tpx_stage_{st.key()[:8]}")
+    assert t_plain.lower().as_text().lstrip().startswith("module @jit_fn")
+    # nothing stored is orphaned and de-duplication is unchanged
+    assert CQ.fingerprint_traced(t_named, salt="/s") \
+        == CQ.fingerprint_traced(t_plain, salt="/s")
+    gen = st.build_device_fn(part.schema, compaction=False)
+    assert gen.__name__.startswith("tpx_stage_")
+    # the packed wire's closure carries the same key8 under its own role
+    from tuplex_tpu.runtime.packing import PackedStageFn
+
+    traced, _, _ = PackedStageFn(named, donate=False,
+                                 tag=st.key()).traced_for(avals)
+    assert traced.__name__ == f"tpx_pack_{st.key()[:8]}"
+    assert tracing.key8("a", 1) == tracing.key8("a", 1) != tracing.key8("a")
+
+
+def test_recorder_span_slice_keeps_the_open_job_root(trace_on, tmp_path):
+    import tuplex_tpu
+
+    c = tuplex_tpu.Context({"tuplex.webui.enable": True,
+                            "tuplex.logDir": str(tmp_path)})
+    c.parallelize([1, 2, 3]).map(lambda x: x + 1).collect()
+    c.close()
+    recs = []
+    for name in os.listdir(tmp_path):
+        with open(os.path.join(tmp_path, name)) as fp:
+            for line in fp:
+                try:
+                    recs.append(json.loads(line))
+                except ValueError:
+                    pass
+    spans = [r for r in recs if r.get("event") == "spans"]
+    assert spans, [r.get("event") for r in recs]
+    names = [s["name"] for s in spans[-1]["spans"]]
+    assert "job" in names and "collect:box-rows" in names
+    (job,) = [s for s in spans[-1]["spans"] if s["name"] == "job"]
+    assert job["depth"] == 0 and job["dur"] > 0
+
 
 def test_trace_smoke_zillow():
     """Acceptance: a zillow run with tuplex.tpu.trace=True produces a

@@ -25,6 +25,22 @@ class Context:
                                             else None, **kwargs)
         if isinstance(conf, str):
             self.options_store.update(conf)
+        from ..runtime import tracing
+
+        if self.options_store.get_bool("tuplex.tpu.trace", False):
+            # span tracing is process-wide (spans cross backend/compile-
+            # pool threads); the option turns it on, never off — another
+            # live Context (or TUPLEX_TRACE=1) may also depend on it
+            tracing.enable(True)
+        # the time a first job spends before its `job` span opens
+        with tracing.span("context:init", "job") as _sp:
+            self._init_planes()
+            _sp.set("backend", type(self.backend).__name__) \
+               .set("devices", getattr(self.backend, "n_devices", 1))
+
+    def _init_planes(self) -> None:
+        """The rest of construction: process-wide gates, the backend, the
+        recorder (inside the `context:init` span)."""
         # sample-free specialization gate (compiler/typeinfer.py): like
         # tracing, the flag is process-wide — planning code paths have no
         # Context handle at schema-inference depth. TUPLEX_STATIC_TYPES
@@ -33,13 +49,6 @@ class Context:
 
         _ti.set_enabled(self.options_store.get_bool(
             "tuplex.tpu.staticTypes", True))
-        if self.options_store.get_bool("tuplex.tpu.trace", False):
-            # span tracing is process-wide (spans cross backend/compile-
-            # pool threads); the option turns it on, never off — another
-            # live Context (or TUPLEX_TRACE=1) may also depend on it
-            from ..runtime import tracing
-
-            tracing.enable(True)
         # device-plane cost attribution (runtime/devprof): same process-
         # wide on-only semantics as tracing/telemetry; TUPLEX_DEVPROF=0
         # is the env kill switch that wins over everything

@@ -11,6 +11,7 @@ operator; nothing executes until an action (collect/take/show/tocsv).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, Optional, Sequence
 
 from ..core import typesys as T
@@ -248,24 +249,24 @@ class DataSet:
         return counts
 
     # ------------------------------------------------------------------
-    def _execute_partitions(self, limit: int,
-                        output_sink=None) -> list:
-        """Run the plan and return the OUTPUT PARTITIONS (columnar). The
-        sinks (tocsv/toorc) stream from these without boxing."""
+    @contextlib.contextmanager
+    def _job(self, limit: int):
+        """The `job` span (with the optional XLA profile and the multihost
+        span dump) around one action: everything the action does for its
+        result — plan, ingest, stages, and for collect()/take() the boxing
+        of the output rows — happens inside, so the span ring accounts for
+        the same seconds a caller's clock sees."""
+        import sys as _sys
         import time as _time
 
-        from ..utils.signals import capture_sigint, check_interrupted
-
-        self._t_job = _time.perf_counter()
         from ..runtime import tracing as TR
 
+        self._t_job = _time.perf_counter()
         # the history slice starts HERE — before the job span opens — so
         # the job/plan/analyzer spans land in the per-job waterfall too
-        _tmark = TR.now_us()
+        self._trace_mark = TR.now_us()
         _jsp = TR.span("job", "job")
         _jsp.__enter__()
-        partitions = None
-        all_exceptions = []
         prof_cm = None
         try:
             _jsp.set("action", "collect" if limit < 0 else f"take({limit})")
@@ -280,79 +281,16 @@ class DataSet:
 
                     prof_cm = _prof.trace(prof_dir)
                     prof_cm.__enter__()
+                    # clock tie: the span ring's time as the profile
+                    # starts, so Metrics.export_trace lays beside the
+                    # xprof trace (both run on perf_counter)
+                    with _prof.TraceAnnotation("tpx:clock",
+                                               ring_us=TR.now_us()):
+                        pass
                 except Exception:
                     prof_cm = None
-            sink = L.TakeOperator(self._op, limit) if limit >= 0 \
-                else self._op
-            from ..compiler import analyzer as _az
-
-            azsnap = _az.snapshot()
-            stages = plan_stages(sink, self._context.options_store)
-            azd = _az.delta(azsnap)
-            self._context.metrics.record_plan({
-                "analyzer_ms": azd["analyze_ms"],
-                "plan_fallback_ops": azd["plan_fallback_ops"],
-                "analyzer_inferred_ops": azd["inferred_ops"],
-                "sample_traces_skipped": azd["sample_traces_skipped"]})
-            backend = self._context.backend
-            recorder = self._context.recorder
-            recorder.job_started(
-                "collect" if limit < 0 else f"take({limit})",
-                stages, trace_mark=_tmark)
-            with capture_sigint():
-                for si, stage in enumerate(stages):
-                    check_interrupted()
-                    if getattr(stage, "source", None) is not None:
-                        # take(n): stream partitions lazily so the backend
-                        # stops pulling source data once n rows survive
-                        # (reference: range tasks, LocalBackend.cc:552-611;
-                        # round 1 loaded the WHOLE source for take(5))
-                        lazy = getattr(stage, "limit", -1) >= 0 and \
-                            isinstance(stage, TransformStage)
-                        partitions = _source_partitions(
-                            self._context, stage, lazy=lazy)
-                        if si == 0 and not lazy:
-                            # ahead-of-time compile of the WHOLE plan on
-                            # the pool: stage i+1's (predicted-spec)
-                            # compile overlaps stage i's execution
-                            # (exec/compilequeue; remote XLA compiles are
-                            # minutes, not the reference's milliseconds)
-                            pre = getattr(backend, "precompile_plan", None)
-                            if pre is not None:
-                                try:
-                                    pre(stages, partitions)
-                                except Exception:
-                                    pass
-                    # device handoff: tell the backend WHO consumes this
-                    # stage's output ("stage"/"agg"/"join" — all three
-                    # drain device views now; round 5 excluded joins and
-                    # aggregates, which made q19/flights round-trip every
-                    # boundary through the host)
-                    from ..plan.physical import consumer_kind
-
-                    consumer = consumer_kind(stages, si)
-                    kw = {}
-                    if output_sink is not None and \
-                            si == len(stages) - 1 and \
-                            getattr(backend, "supports_sink_pushdown",
-                                    False):
-                        kw["sink"] = output_sink
-                    recorder.stage_started(stage)
-                    backend.progress_cb = recorder.task_progress
-                    try:
-                        result = backend.execute_any(
-                            stage, partitions, self._context,
-                            intermediate=consumer, **kw)
-                    finally:
-                        backend.progress_cb = None
-                    partitions = result.partitions
-                    all_exceptions.extend(result.exceptions)
-                    self._context.metrics.record_stage(result.metrics)
-                    recorder.stage_done(stage, result.metrics,
-                                        result.exceptions)
+            yield
         finally:
-            import sys as _sys
-
             # pass the in-flight exception (if any) so a crashed job's
             # span carries the error attribute like every other span
             _jsp.__exit__(*_sys.exc_info())
@@ -382,6 +320,95 @@ class DataSet:
                             ".jsonl"))
                 except Exception:
                     pass    # span dump must never fail the job
+
+    def _execute_partitions(self, limit: int,
+                        output_sink=None) -> list:
+        """Run the plan and return the OUTPUT PARTITIONS (columnar). The
+        sinks (tocsv/toorc) stream from these without boxing."""
+        with self._job(limit):
+            return self._run_plan(limit, output_sink)
+
+    def _run_plan(self, limit: int, output_sink=None) -> list:
+        """The body of an action, inside its `job` span."""
+        from ..runtime import tracing as TR
+        from ..utils.signals import capture_sigint, check_interrupted
+
+        partitions = None
+        all_exceptions = []
+        try:
+            sink = L.TakeOperator(self._op, limit) if limit >= 0 \
+                else self._op
+            from ..compiler import analyzer as _az
+
+            azsnap = _az.snapshot()
+            stages = plan_stages(sink, self._context.options_store)
+            azd = _az.delta(azsnap)
+            self._context.metrics.record_plan({
+                "analyzer_ms": azd["analyze_ms"],
+                "plan_fallback_ops": azd["plan_fallback_ops"],
+                "analyzer_inferred_ops": azd["inferred_ops"],
+                "sample_traces_skipped": azd["sample_traces_skipped"]})
+            backend = self._context.backend
+            recorder = self._context.recorder
+            recorder.job_started(
+                "collect" if limit < 0 else f"take({limit})",
+                stages, trace_mark=self._trace_mark)
+            with capture_sigint():
+                for si, stage in enumerate(stages):
+                    check_interrupted()
+                    if getattr(stage, "source", None) is not None:
+                        # take(n): stream partitions lazily so the backend
+                        # stops pulling source data once n rows survive
+                        # (reference: range tasks, LocalBackend.cc:552-611;
+                        # round 1 loaded the WHOLE source for take(5))
+                        lazy = getattr(stage, "limit", -1) >= 0 and \
+                            isinstance(stage, TransformStage)
+                        partitions = _source_partitions(
+                            self._context, stage, lazy=lazy)
+                        if si == 0 and not lazy:
+                            # ahead-of-time compile of the WHOLE plan on
+                            # the pool: stage i+1's (predicted-spec)
+                            # compile overlaps stage i's execution
+                            # (exec/compilequeue; remote XLA compiles are
+                            # minutes, not the reference's milliseconds)
+                            pre = getattr(backend, "precompile_plan", None)
+                            if pre is not None:
+                                with TR.span("compile:precompile-plan",
+                                             "compile") as _psp:
+                                    _psp.set("stages", len(stages))
+                                    try:
+                                        _psp.set("submitted", int(bool(
+                                            pre(stages, partitions))))
+                                    except Exception:
+                                        pass
+                    # device handoff: tell the backend WHO consumes this
+                    # stage's output ("stage"/"agg"/"join" — all three
+                    # drain device views now; round 5 excluded joins and
+                    # aggregates, which made q19/flights round-trip every
+                    # boundary through the host)
+                    from ..plan.physical import consumer_kind
+
+                    consumer = consumer_kind(stages, si)
+                    kw = {}
+                    if output_sink is not None and \
+                            si == len(stages) - 1 and \
+                            getattr(backend, "supports_sink_pushdown",
+                                    False):
+                        kw["sink"] = output_sink
+                    recorder.stage_started(stage)
+                    backend.progress_cb = recorder.task_progress
+                    try:
+                        result = backend.execute_any(
+                            stage, partitions, self._context,
+                            intermediate=consumer, **kw)
+                    finally:
+                        backend.progress_cb = None
+                    partitions = result.partitions
+                    all_exceptions.extend(result.exceptions)
+                    self._context.metrics.record_stage(result.metrics)
+                    recorder.stage_done(stage, result.metrics,
+                                        result.exceptions)
+        finally:
             # interrupted jobs must not leave stale per-action state
             self._last_exceptions = all_exceptions
         return partitions or []
@@ -389,30 +416,59 @@ class DataSet:
     def _execute(self, limit: int):
         import time as _time
 
+        from ..runtime import tracing as TR
         from ..runtime.columns import partition_to_pylist
 
-        partitions = self._execute_partitions(limit)
-        out = []
-        for p in partitions:
-            self._context.backend.touch_partition(p)
-            out.extend(partition_to_pylist(p))
-        if limit >= 0:
-            out = out[:limit]
-        counts = {}
-        for rec in self._last_exceptions:
-            counts[rec.exc_name] = counts.get(rec.exc_name, 0) + 1
-        self._context.recorder.job_done(
-            len(out), _time.perf_counter() - self._t_job, counts)
+        with self._job(limit):
+            partitions = self._run_plan(limit)
+            out = []
+            with TR.span("collect:box-rows", "exec") as _bsp:
+                for p in partitions:
+                    self._context.backend.touch_partition(p)
+                    out.extend(partition_to_pylist(p))
+                if limit >= 0:
+                    out = out[:limit]
+                _bsp.set("rows", len(out))
+            counts = {}
+            for rec in self._last_exceptions:
+                counts[rec.exc_name] = counts.get(rec.exc_name, 0) + 1
+            self._context.recorder.job_done(
+                len(out), _time.perf_counter() - self._t_job, counts)
         return out
 
 
 def _source_partitions(context, stage, lazy: bool = False):
-    """Materialize the stage source into columnar partitions.
+    """Materialize the stage source into columnar partitions, inside an
+    `ingest` span (children: `ingest:read-csv`, `ingest:to-partition`,
+    `ingest:harmonize`).
 
     `lazy=True` returns a GENERATOR (no dataset-wide harmonization): used by
-    take(n) so the backend can stop consuming once the limit is met. Lazy
-    batches may have differing str widths — worst case a few extra jit
-    retraces, which a take() of a handful of rows never hits."""
+    take(n) so the backend can stop consuming once the limit is met — each
+    pull of the generator is then one `ingest` span. Lazy batches may have
+    differing str widths — worst case a few extra jit retraces, which a
+    take() of a handful of rows never hits."""
+    from ..runtime import tracing as TR
+
+    if lazy:
+        return TR.pulls(_load_source(context, stage, lazy=True),
+                        "ingest", "io")
+    with TR.span("ingest", "io") as _sp:
+        parts = _load_source(context, stage, lazy=False)
+        _sp.set("partitions", len(parts)) \
+           .set("rows", sum(p.num_rows for p in parts))
+    return parts
+
+
+def _harmonize(parts: list) -> list:
+    from ..runtime import columns as C
+    from ..runtime import tracing as TR
+
+    with TR.span("ingest:harmonize", "io") as _sp:
+        _sp.set("partitions", len(parts))
+        return C.harmonize_partitions(parts)
+
+
+def _load_source(context, stage, lazy: bool):
     from ..runtime import columns as C
 
     src = stage.source
@@ -427,7 +483,7 @@ def _source_partitions(context, stage, lazy: bool = False):
 
         if lazy:
             return gen_parallel()
-        return C.harmonize_partitions(list(gen_parallel()))
+        return _harmonize(list(gen_parallel()))
     if hasattr(src, "load_partitions"):
         import inspect
 
@@ -440,7 +496,7 @@ def _source_partitions(context, stage, lazy: bool = False):
         parts = src.load_partitions(context, **kwargs)
         if lazy:
             return iter(parts)
-        return C.harmonize_partitions(parts)
+        return _harmonize(parts)
     raise TuplexException(f"unknown source {src!r}")
 
 

@@ -27,16 +27,22 @@ from ..core.row import Row
 from ..plan import aggregates as A
 from ..plan import logical as L
 from ..runtime import columns as C
+from ..runtime import tracing as TR
 from .local import ExceptionRecord
 
 
-def _aot(fn):
+def _aot(fn, role: str, op, schema):
     """Content-addressed compile for the scan-fold executables (the agg
     analog of the stage fns' compilequeue route: identical fold structures
-    across jobs/processes reuse one executable)."""
+    across jobs/processes reuse one executable). The HLO module reads
+    `jit_tpx_<role>_<key8>`, keyed by the operator's identity (UDF
+    sources) and the input schema."""
+    from ..plan.physical import _op_identity
     from .compilequeue import aot_jit
 
-    return aot_jit(fn, tag="agg")
+    return aot_jit(TR.name_fn(fn, role,
+                              TR.key8(_op_identity(op), schema.name)),
+                   tag="agg")
 
 
 from ..parallel.collectives import reduce_identity as _identity
@@ -56,7 +62,6 @@ class AggregateExecutor:
 
     # ==================================================================
     def execute(self, stage, partitions: list[C.Partition]):
-        from ..runtime import tracing as TR
         from .local import StageResult
 
         op = stage.op
@@ -137,16 +142,20 @@ class AggregateExecutor:
                     device_ok = self._scan_fold_bykey(op, scan_k, part, kidx,
                                                       groups, excs)
                 if not device_ok:
-                    self._python_fold(op, part, range(part.num_rows),
-                                      groups, kidx, excs)
-            out_schema = op.schema()
-            values = []
-            for k, acc in groups.items():
-                accs = acc if isinstance(acc, tuple) else (acc,)
-                values.append(tuple(k) + tuple(accs))
-            if not values:
-                return [], excs
-            return [C.build_partition(values, out_schema)], excs
+                    with TR.span("agg:host-merge", "exec") as _sp:
+                        _sp.set("rows", part.num_rows).set("path", "python")
+                        self._python_fold(op, part, range(part.num_rows),
+                                          groups, kidx, excs)
+            with TR.span("agg:host-merge", "exec") as _sp:
+                _sp.set("groups", len(groups)).set("path", "output")
+                out_schema = op.schema()
+                values = []
+                for k, acc in groups.items():
+                    accs = acc if isinstance(acc, tuple) else (acc,)
+                    values.append(tuple(k) + tuple(accs))
+                if not values:
+                    return [], excs
+                return [C.build_partition(values, out_schema)], excs
 
         # whole-dataset aggregate: pattern folds vectorize; everything else
         # tries the compiled sequential scan fold before per-row python
@@ -176,13 +185,17 @@ class AggregateExecutor:
             if spec is not None:
                 partial, bad_rows = self._device_fold(op, spec, part)
                 if partial is not None:
-                    merge_partial(partial)
-                    self._python_fold(op, part, bad_rows, groups2, [], excs,
-                                      into_key=())
+                    with TR.span("agg:host-merge", "exec") as _sp:
+                        _sp.set("rows", len(bad_rows)).set("groups", 1)
+                        merge_partial(partial)
+                        self._python_fold(op, part, bad_rows, groups2, [],
+                                          excs, into_key=())
                     done = True
             if not done:
-                self._python_fold(op, part, range(part.num_rows), groups2,
-                                  [], excs, into_key=())
+                with TR.span("agg:host-merge", "exec") as _sp:
+                    _sp.set("rows", part.num_rows).set("path", "python")
+                    self._python_fold(op, part, range(part.num_rows),
+                                      groups2, [], excs, into_key=())
         # fold the python-side accumulator into the device-side one via the
         # user combine (both are real agg values, reference: agg_combine_f)
         py_acc = groups2[()]
@@ -219,26 +232,32 @@ class AggregateExecutor:
             outs = None
             if part.n_normal() > 0:
                 try:
-                    fn = self.backend.jit_cache.get_or_build(
-                        ("scanfold", op.id, part.schema.name),
-                        lambda: _aot(scan.build_fn()))
-                    batch = C.stage_partition(part, self.backend.bucket_mode)
-                    acc_in = scan.encode_acc(acc_val)
-                    outs = jax.device_get(fn(batch.arrays, acc_in))
+                    with TR.span("agg:segment-fold", "exec") as _sp:
+                        _sp.set("rows", part.num_rows).set("groups", 1)
+                        fn = self.backend.jit_cache.get_or_build(
+                            ("scanfold", op.id, part.schema.name),
+                            lambda: _aot(scan.build_fn(), "aggscan", op,
+                                         part.schema))
+                        batch = C.stage_partition(part,
+                                                  self.backend.bucket_mode)
+                        acc_in = scan.encode_acc(acc_val)
+                        outs = jax.device_get(fn(batch.arrays, acc_in))
                 except Exception as e:
                     from ..utils.logging import get_logger
 
                     get_logger("exec").warning(
                         "scan fold failed (%s: %s); partition folds on the "
                         "interpreter", type(e).__name__, e)
-            if outs is None:
-                fold_py(part, range(part.num_rows))
-                continue
-            *acc_leaves, bads = outs
-            acc_val = scan.decode_acc(acc_leaves)
-            bad_idx = np.nonzero(np.asarray(bads)[:part.num_rows])[0]
-            if len(bad_idx):
-                fold_py(part, bad_idx.tolist())
+            with TR.span("agg:host-merge", "exec") as _sp:
+                _sp.set("rows", part.num_rows)
+                if outs is None:
+                    fold_py(part, range(part.num_rows))
+                    continue
+                *acc_leaves, bads = outs
+                acc_val = scan.decode_acc(acc_leaves)
+                bad_idx = np.nonzero(np.asarray(bads)[:part.num_rows])[0]
+                if len(bad_idx):
+                    fold_py(part, bad_idx.tolist())
         schema = op.schema()
         return [C.build_partition([acc_val], schema)], excs
 
@@ -251,29 +270,35 @@ class AggregateExecutor:
         import jax
 
         real = _real_mask(part)
-        codes, uniq_rows = _factorize_keys(part, kidx, real)
-        if codes is None or len(uniq_rows) == 0:
-            return False
         n = part.num_rows
-        nseg = len(uniq_rows)
-        nseg_b = C.bucket_size(nseg)
-        # key columns only: a device-resident (lazy) partition must not be
-        # forced to host just to name its groups
-        keys = C.decode_key_tuples(part, uniq_rows.tolist(), kidx)
+        with TR.span("agg:factorize-keys", "exec") as _sp:
+            _sp.set("rows", n)
+            codes, uniq_rows = _factorize_keys(part, kidx, real)
+            if codes is None or len(uniq_rows) == 0:
+                return False
+            nseg = len(uniq_rows)
+            _sp.set("groups", nseg)
+            nseg_b = C.bucket_size(nseg)
+            # key columns only: a device-resident (lazy) partition must not
+            # be forced to host just to name its groups
+            keys = C.decode_key_tuples(part, uniq_rows.tolist(), kidx)
         try:
             seg_init = A._scanfold_encode_segments(
                 scan, [groups.get(k, op.initial) for k in keys], nseg_b)
         except Exception:
             return False   # an existing acc no longer conforms: python path
         try:
-            fn = self.backend.jit_cache.get_or_build(
-                ("scanfoldseg", op.id, part.schema.name),
-                lambda: _aot(A._seg_build_fn(scan)))
-            batch = C.stage_partition(part, self.backend.bucket_mode)
-            b = batch.arrays["#rowvalid"].shape[0]
-            codes_b = np.full(b, nseg_b, dtype=np.int32)
-            codes_b[:n][real] = codes
-            outs = jax.device_get(fn(batch.arrays, codes_b, seg_init))
+            with TR.span("agg:segment-fold", "exec") as _sp:
+                _sp.set("rows", n).set("groups", nseg)
+                fn = self.backend.jit_cache.get_or_build(
+                    ("scanfoldseg", op.id, part.schema.name),
+                    lambda: _aot(A._seg_build_fn(scan), "aggfold", op,
+                                 part.schema))
+                batch = C.stage_partition(part, self.backend.bucket_mode)
+                b = batch.arrays["#rowvalid"].shape[0]
+                codes_b = np.full(b, nseg_b, dtype=np.int32)
+                codes_b[:n][real] = codes
+                outs = jax.device_get(fn(batch.arrays, codes_b, seg_init))
         except Exception as e:
             from ..utils.logging import get_logger
 
@@ -281,19 +306,22 @@ class AggregateExecutor:
                 "segmented scan fold failed (%s: %s); partition folds on "
                 "the interpreter", type(e).__name__, e)
             return False
-        *leaves, bads = outs
-        bads_n = np.asarray(bads)[:n]
-        # ghost-group guard (matches the mesh fold's counts check): a key
-        # whose rows ALL errored must not emit an initial-only output row
-        ok_codes = codes_b[:n][~bads_n]
-        seg_ok = np.bincount(ok_codes, minlength=nseg_b + 1)
-        vals = A._scanfold_decode_segments(scan, leaves, nseg)
-        for si, k in enumerate(keys):
-            if seg_ok[si] or k in groups:
-                groups[k] = vals[si]
-        bad_idx = np.nonzero(bads_n)[0].tolist()
-        if bad_idx:
-            self._python_fold(op, part, bad_idx, groups, kidx, excs)
+        with TR.span("agg:host-merge", "exec") as _sp:
+            _sp.set("rows", n).set("groups", nseg)
+            *leaves, bads = outs
+            bads_n = np.asarray(bads)[:n]
+            # ghost-group guard (matches the mesh fold's counts check): a
+            # key whose rows ALL errored must not emit an initial-only
+            # output row
+            ok_codes = codes_b[:n][~bads_n]
+            seg_ok = np.bincount(ok_codes, minlength=nseg_b + 1)
+            vals = A._scanfold_decode_segments(scan, leaves, nseg)
+            for si, k in enumerate(keys):
+                if seg_ok[si] or k in groups:
+                    groups[k] = vals[si]
+            bad_idx = np.nonzero(bads_n)[0].tolist()
+            if bad_idx:
+                self._python_fold(op, part, bad_idx, groups, kidx, excs)
         return True
 
     # ------------------------------------------------------------------
@@ -329,23 +357,27 @@ class AggregateExecutor:
             except NotCompilable:
                 return None, range(part.num_rows)
         try:
-            vals, ok_mask, err = self._eval_exprs(op, spec, part)
+            with TR.span("agg:eval-exprs", "exec") as _sp:
+                _sp.set("rows", part.num_rows)
+                vals, ok_mask, err = self._eval_exprs(op, spec, part)
         except NotCompilable:
             return None, range(part.num_rows)
         import jax.numpy as jnp
 
         partials = []
-        for cv_data, reducer in zip(vals, spec.reducers):
-            is_float = cv_data.dtype.kind == "f"
-            ident = _identity(reducer, is_float)
-            masked = jnp.where(ok_mask, cv_data, ident)
-            if reducer == "sum":
-                r = masked.sum()
-            elif reducer == "min":
-                r = masked.min()
-            else:
-                r = masked.max()
-            partials.append(r.item())
+        with TR.span("agg:segment-fold", "exec") as _sp:
+            _sp.set("rows", part.num_rows).set("groups", 1)
+            for cv_data, reducer in zip(vals, spec.reducers):
+                is_float = cv_data.dtype.kind == "f"
+                ident = _identity(reducer, is_float)
+                masked = jnp.where(ok_mask, cv_data, ident)
+                if reducer == "sum":
+                    r = masked.sum()
+                elif reducer == "min":
+                    r = masked.min()
+                else:
+                    r = masked.max()
+                partials.append(r.item())
         bad = np.nonzero(~np.asarray(ok_mask)[: part.num_rows] &
                          _real_mask(part))[0].tolist()
         bad += [i for i in part.fallback if i not in bad]
@@ -361,20 +393,24 @@ class AggregateExecutor:
 
         if not part.leaves and part.fallback:
             raise NotCompilable("all-fallback partition")
-        batch = C.stage_partition(part, self.backend.bucket_mode)
-        arrays = M.pad_batch_for_mesh(batch.arrays, len(mesh.devices.flat))
-        schema = part.schema
-        eval_exprs = _make_eval_exprs(spec, schema)
-        shapes = tuple(sorted((k, v.shape, str(v.dtype))
-                              for k, v in arrays.items()))
-        run = self.backend.jit_cache.get_or_build(
-            ("meshfold", op.id, schema.name, shapes,
-             self.backend.fn_cache_salt()),
-            lambda: CC.sharded_fold_fn(eval_exprs, spec.reducers, mesh,
-                                       arrays))
-        outs = run(arrays)
-        ok_np = M.materialize_np(outs[-1])[: part.num_rows] & _real_mask(part)
-        partials = [o.item() for o in outs[:-1]]
+        with TR.span("agg:segment-fold", "exec") as _sp:
+            _sp.set("rows", part.num_rows).set("groups", 1)
+            batch = C.stage_partition(part, self.backend.bucket_mode)
+            arrays = M.pad_batch_for_mesh(batch.arrays,
+                                          len(mesh.devices.flat))
+            schema = part.schema
+            eval_exprs = _make_eval_exprs(spec, schema)
+            shapes = tuple(sorted((k, v.shape, str(v.dtype))
+                                  for k, v in arrays.items()))
+            run = self.backend.jit_cache.get_or_build(
+                ("meshfold", op.id, schema.name, shapes,
+                 self.backend.fn_cache_salt()),
+                lambda: CC.sharded_fold_fn(eval_exprs, spec.reducers, mesh,
+                                           arrays))
+            outs = run(arrays)
+            ok_np = M.materialize_np(outs[-1])[: part.num_rows] \
+                & _real_mask(part)
+            partials = [o.item() for o in outs[:-1]]
         bad = np.nonzero(~ok_np & _real_mask(part))[0].tolist()
         bad += [i for i in part.fallback if i not in bad]
         out = tuple(partials) if not spec.scalar else partials[0]
@@ -388,54 +424,71 @@ class AggregateExecutor:
                                                     groups, excs, mesh)
             except NotCompilable:
                 return False
+        n = part.num_rows
         try:
-            vals, ok_mask, err = self._eval_exprs(op, spec, part)
+            # staging, the eager expression ops and the fetch of the ok
+            # mask (which waits for them)
+            with TR.span("agg:eval-exprs", "exec") as _sp:
+                _sp.set("rows", n)
+                vals, ok_mask, err = self._eval_exprs(op, spec, part)
+                ok_host = np.asarray(ok_mask)
         except NotCompilable:
             return False
         import jax.numpy as jnp
         import jax.ops
 
-        n = part.num_rows
-        ok_np = np.asarray(ok_mask)[:n] & _real_mask(part)
-        codes, uniq_rows = _factorize_keys(part, kidx, ok_np)
-        if codes is None:
-            return False
-        nseg = len(uniq_rows)
-        b = np.asarray(ok_mask).shape[0]
-        codes_b = np.full(b, nseg, dtype=np.int32)  # padding -> dropped seg
-        codes_b[:n][ok_np] = codes
+        ok_np = ok_host[:n] & _real_mask(part)
+        with TR.span("agg:factorize-keys", "exec") as _sp:
+            _sp.set("rows", n)
+            codes, uniq_rows = _factorize_keys(part, kidx, ok_np)
+            if codes is None:
+                return False
+            nseg = len(uniq_rows)
+            _sp.set("groups", nseg)
+            b = ok_host.shape[0]
+            codes_b = np.full(b, nseg, dtype=np.int32)  # padding -> dropped
+            codes_b[:n][ok_np] = codes
         seg_partials = []
-        for cv_data, reducer in zip(vals, spec.reducers):
-            is_float = cv_data.dtype.kind == "f"
-            ident = _identity(reducer, is_float)
-            masked = jnp.where(ok_mask, cv_data, ident)
-            if reducer == "sum":
-                r = jax.ops.segment_sum(masked, codes_b,
-                                        num_segments=nseg + 1)
-            elif reducer == "min":
-                r = jax.ops.segment_min(masked, codes_b,
-                                        num_segments=nseg + 1)
-            else:
-                r = jax.ops.segment_max(masked, codes_b,
-                                        num_segments=nseg + 1)
-            seg_partials.append(np.asarray(r)[:nseg])
-        # merge per-key partials into the global dict (key columns only —
-        # see decode_key_tuples: full decode would force lazy leaves)
-        key_vals = C.decode_key_tuples(part, uniq_rows, kidx)
-        for si, row_i in enumerate(uniq_rows):
-            k = key_vals[si]
-            acc = groups.get(k, op.initial)
-            accs = list(acc) if isinstance(acc, tuple) else [acc]
-            merged = []
-            for j, reducer in enumerate(spec.reducers):
-                v = seg_partials[j][si].item()
-                merged.append(_combine_scalar(reducer, accs[j], v)
-                              if reducer != "sum" else accs[j] + v)
-            groups[k] = tuple(merged) if isinstance(acc, tuple) else merged[0]
-        # bad rows -> interpreter
-        bad = np.nonzero(~ok_np & _real_mask(part))[0].tolist()
-        bad += [i for i in part.fallback if i not in bad]
-        self._python_fold(op, part, sorted(set(bad)), groups, kidx, excs)
+        # eager segment reductions, one launch and one fetch a reducer
+        # (`jit_scatter-add` on the device: not wrapped in a jit here,
+        # ROADMAP S5)
+        with TR.span("agg:segment-fold", "exec") as _sp:
+            _sp.set("rows", n).set("groups", nseg)
+            for cv_data, reducer in zip(vals, spec.reducers):
+                is_float = cv_data.dtype.kind == "f"
+                ident = _identity(reducer, is_float)
+                masked = jnp.where(ok_mask, cv_data, ident)
+                if reducer == "sum":
+                    r = jax.ops.segment_sum(masked, codes_b,
+                                            num_segments=nseg + 1)
+                elif reducer == "min":
+                    r = jax.ops.segment_min(masked, codes_b,
+                                            num_segments=nseg + 1)
+                else:
+                    r = jax.ops.segment_max(masked, codes_b,
+                                            num_segments=nseg + 1)
+                seg_partials.append(np.asarray(r)[:nseg])
+        with TR.span("agg:host-merge", "exec") as _sp:
+            _sp.set("rows", n).set("groups", nseg)
+            # merge per-key partials into the global dict (key columns only
+            # — see decode_key_tuples: full decode would force lazy leaves)
+            key_vals = C.decode_key_tuples(part, uniq_rows, kidx)
+            for si, row_i in enumerate(uniq_rows):
+                k = key_vals[si]
+                acc = groups.get(k, op.initial)
+                accs = list(acc) if isinstance(acc, tuple) else [acc]
+                merged = []
+                for j, reducer in enumerate(spec.reducers):
+                    v = seg_partials[j][si].item()
+                    merged.append(_combine_scalar(reducer, accs[j], v)
+                                  if reducer != "sum" else accs[j] + v)
+                groups[k] = tuple(merged) if isinstance(acc, tuple) \
+                    else merged[0]
+            # bad rows -> interpreter
+            bad = np.nonzero(~ok_np & _real_mask(part))[0].tolist()
+            bad += [i for i in part.fallback if i not in bad]
+            self._python_fold(op, part, sorted(set(bad)), groups, kidx,
+                              excs)
         return True
 
     def _device_fold_bykey_mesh(self, op, spec, part, kidx, groups, excs,
@@ -451,43 +504,53 @@ class AggregateExecutor:
             raise NotCompilable("all-fallback partition")
         n = part.num_rows
         real = _real_mask(part)
-        codes, uniq_rows = _factorize_keys(part, kidx, real)
-        if codes is None:
-            return False
-        nseg = len(uniq_rows)
-        batch = C.stage_partition(part, self.backend.bucket_mode)
-        arrays = M.pad_batch_for_mesh(batch.arrays, len(mesh.devices.flat))
-        b = arrays["#rowvalid"].shape[0]
-        codes_b = np.full(b, nseg, dtype=np.int32)  # padding -> dropped seg
-        codes_b[:n][real] = codes
-        schema = part.schema
-        eval_exprs = _make_eval_exprs(spec, schema)
-        shapes = tuple(sorted((k, v.shape, str(v.dtype))
-                              for k, v in arrays.items()))
-        run = self.backend.jit_cache.get_or_build(
-            ("meshseg", op.id, schema.name, nseg, shapes,
-             self.backend.fn_cache_salt()),
-            lambda: CC.sharded_segment_fold_fn(
-                eval_exprs, spec.reducers, nseg, mesh, arrays))
-        outs = run(arrays, codes_b)
-        ok_np = M.materialize_np(outs[-1])[:n] & real
-        counts = M.materialize_np(outs[-2])[:nseg]
-        seg_partials = [np.asarray(o)[:nseg] for o in outs[:-2]]
-        key_vals = C.decode_key_tuples(part, uniq_rows, kidx)
-        for si, row_i in enumerate(uniq_rows):
-            if counts[si] == 0:
-                continue  # every row of this key failed: no ghost group —
-                          # the interpreter fold below decides its fate
-            k = key_vals[si]
-            acc = groups.get(k, op.initial)
-            accs = list(acc) if isinstance(acc, tuple) else [acc]
-            merged = [_combine_scalar(reducer, accs[j],
-                                      seg_partials[j][si].item())
-                      for j, reducer in enumerate(spec.reducers)]
-            groups[k] = tuple(merged) if isinstance(acc, tuple) else merged[0]
-        bad = np.nonzero(~ok_np & real)[0].tolist()
-        bad += [i for i in part.fallback if i not in bad]
-        self._python_fold(op, part, sorted(set(bad)), groups, kidx, excs)
+        with TR.span("agg:factorize-keys", "exec") as _sp:
+            _sp.set("rows", n)
+            codes, uniq_rows = _factorize_keys(part, kidx, real)
+            if codes is None:
+                return False
+            nseg = len(uniq_rows)
+            _sp.set("groups", nseg)
+        with TR.span("agg:segment-fold", "exec") as _sp:
+            _sp.set("rows", n).set("groups", nseg)
+            batch = C.stage_partition(part, self.backend.bucket_mode)
+            arrays = M.pad_batch_for_mesh(batch.arrays,
+                                          len(mesh.devices.flat))
+            b = arrays["#rowvalid"].shape[0]
+            codes_b = np.full(b, nseg, dtype=np.int32)  # padding -> dropped
+            codes_b[:n][real] = codes
+            schema = part.schema
+            eval_exprs = _make_eval_exprs(spec, schema)
+            shapes = tuple(sorted((k, v.shape, str(v.dtype))
+                                  for k, v in arrays.items()))
+            run = self.backend.jit_cache.get_or_build(
+                ("meshseg", op.id, schema.name, nseg, shapes,
+                 self.backend.fn_cache_salt()),
+                lambda: CC.sharded_segment_fold_fn(
+                    eval_exprs, spec.reducers, nseg, mesh, arrays))
+            outs = run(arrays, codes_b)
+            ok_np = M.materialize_np(outs[-1])[:n] & real
+            counts = M.materialize_np(outs[-2])[:nseg]
+            seg_partials = [np.asarray(o)[:nseg] for o in outs[:-2]]
+        with TR.span("agg:host-merge", "exec") as _sp:
+            _sp.set("rows", n).set("groups", nseg)
+            key_vals = C.decode_key_tuples(part, uniq_rows, kidx)
+            for si, row_i in enumerate(uniq_rows):
+                if counts[si] == 0:
+                    continue  # every row of this key failed: no ghost
+                              # group — the interpreter fold below decides
+                k = key_vals[si]
+                acc = groups.get(k, op.initial)
+                accs = list(acc) if isinstance(acc, tuple) else [acc]
+                merged = [_combine_scalar(reducer, accs[j],
+                                          seg_partials[j][si].item())
+                          for j, reducer in enumerate(spec.reducers)]
+                groups[k] = tuple(merged) if isinstance(acc, tuple) \
+                    else merged[0]
+            bad = np.nonzero(~ok_np & real)[0].tolist()
+            bad += [i for i in part.fallback if i not in bad]
+            self._python_fold(op, part, sorted(set(bad)), groups, kidx,
+                              excs)
         return True
 
     # ------------------------------------------------------------------

@@ -65,6 +65,17 @@ STATS: dict[str, Any] = {
     "fork_deadlocks": 0,
     "nodeser_marks": 0, "nodeser_skips": 0,
     "background_compiles": 0,
+    # the compile plane at its cause (fault 1: speculative compiles the
+    # next jobs do not find). prewarm_submitted = compiles queued by the
+    # precompile driver / PackedStageFn.warm; prewarm_used = fingerprints
+    # a prewarm looked up or built that a DISPATCH's lookup then took
+    # (in-process store, in-flight join or disk store; once per
+    # fingerprint). compile_starts is bumped as lowered.compile() begins
+    # (in process or in a child); stage_compiles counts the ends that
+    # succeeded, compile_failures the ones that raised, were killed or
+    # handed nothing back — starts less both is what is still running.
+    "prewarm_submitted": 0, "prewarm_used": 0,
+    "compile_starts": 0, "compile_failures": 0,
     # pre-submission jaxpr vetting (compiler/graphlint): hazards_found =
     # fresh vetoes from a live analysis, hazards_avoided = every compile
     # the vet plane spared XLA (fresh vetoes + `.hazard` marker skips +
@@ -85,6 +96,8 @@ _EXEC_SALT: dict[str, str] = {}      # fingerprint -> caller salt (_EXECS keys)
 _PENDING: dict[str, Future] = {}     # fingerprint -> in-flight compile
 _PENDING_T: dict[str, float] = {}    # fingerprint -> compile start (monotonic)
 _TAG: dict[str, list] = {}           # tag -> [seconds, count] (unconsumed)
+_PREWARM_FPS: set = set()            # fingerprints a prewarm asked for
+_PREWARM_USED: set = set()           # ... that a dispatch then took
 _POOL: Optional["_DaemonPool"] = None
 _BG_POOL: Optional["_DaemonPool"] = None   # low-priority background lane
 _BG_TLS = threading.local()          # background_lane() thread flag
@@ -146,17 +159,20 @@ class _DaemonPool:
 
     def _run(self) -> None:
         while True:
-            fut, fn, args, kwargs, stream = self._q.get()
+            fut, fn, args, kwargs, stream, cause = self._q.get()
             if not fut.set_running_or_notify_cancel():
                 continue
             # the submitter's span-stream tag (serve: the running job's
             # id) rides the queue item so compile/resolve-path spans
             # recorded on this pool thread stay tenant-tagged; workers
-            # are reused, so the tag is always cleared afterwards
+            # are reused, so the tag is always cleared afterwards. Its
+            # span cause (TR.handoff) rides along the same way: a pool
+            # compile's spans name the job that submitted it
             if stream is not None:
                 TR.set_stream(stream)
             try:
-                fut.set_result(fn(*args, **kwargs))
+                with TR.adopt(cause):
+                    fut.set_result(fn(*args, **kwargs))
             except BaseException as e:  # noqa: BLE001 - future carries it
                 fut.set_exception(e)
             finally:
@@ -165,7 +181,8 @@ class _DaemonPool:
 
     def submit(self, fn, *args, **kwargs) -> Future:
         fut: Future = Future()
-        self._q.put((fut, fn, args, kwargs, TR.current_stream()))
+        self._q.put((fut, fn, args, kwargs, TR.current_stream(),
+                     TR.handoff()))
         return fut
 
 
@@ -232,6 +249,8 @@ def clear() -> None:
         _EXECS.clear()
         _EXEC_SALT.clear()
         _TAG.clear()
+        _PREWARM_FPS.clear()
+        _PREWARM_USED.clear()
         _NODESER.clear()        # the on-disk .nodeser markers remain
         _DESER.clear()
         for k in STATS:
@@ -1001,7 +1020,7 @@ def _compile_with_watchdog(lowered, n_ops: int):
     teaches the model it is expensive; finished compiles are exactly the
     ones the observation set would otherwise be biased toward."""
     if n_ops <= 0:
-        return _compile_lowered(lowered)
+        return _counted_compile(lowered)
     stop = threading.Event()
     t0 = time.perf_counter()
 
@@ -1019,9 +1038,26 @@ def _compile_with_watchdog(lowered, n_ops: int):
                          name="tpx-compile-watchdog")
     t.start()
     try:
-        return _compile_lowered(lowered)
+        return _counted_compile(lowered)
     finally:
         stop.set()
+
+
+def _bump(name: str) -> None:
+    with _LOCK:
+        STATS[name] += 1
+
+
+def _counted_compile(lowered):
+    """`_compile_lowered` in this process between its two counters: a
+    start, and a failure where it raises (the caller's `_note_compile`
+    counts the end that succeeded)."""
+    _bump("compile_starts")
+    try:
+        return _compile_lowered(lowered)
+    except BaseException:
+        _bump("compile_failures")
+        raise
 
 
 def _note_devprof(tag: str, fp: str, compiled) -> None:
@@ -1078,13 +1114,34 @@ def default_deadline_s() -> float:
         return 0.0
 
 
+def _prewarm_owns(fp: str) -> None:
+    """A prewarm came to OWN this fingerprint: it will load or compile
+    it."""
+    with _LOCK:
+        _PREWARM_FPS.add(fp)
+
+
+def _lookup_satisfied(fp: str, prewarm: bool) -> None:
+    """A lookup was satisfied without a compile of its own (in-process
+    store, in-flight join, disk store). Where it is a dispatch's and a
+    prewarm owned the fingerprint, the prewarm was used: counted once."""
+    if prewarm:
+        return
+    with _LOCK:
+        if fp in _PREWARM_FPS and fp not in _PREWARM_USED:
+            _PREWARM_USED.add(fp)
+            STATS["prewarm_used"] += 1
+
+
 def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
                    tag: str = "", n_ops: int = 0,
-                   deadline_s: Optional[float] = None):
+                   deadline_s: Optional[float] = None,
+                   prewarm: bool = False):
     """Trace `fn` against `args` (avals or concrete arrays) and return a
     compiled executable for it, via — in order — the in-process fingerprint
     store, the on-disk AOT artifact cache, or an actual XLA compile (counted,
-    timed, tuner-fed, persisted to disk).
+    timed, tuner-fed, persisted to disk). `prewarm` marks a speculative
+    call (the precompile driver's): see `_lookup_satisfied`.
 
     Trace-time exceptions (NotCompilable, emitter rejections) propagate to
     the caller exactly as they would from ``jax.jit(fn)(args)`` — the local
@@ -1143,6 +1200,7 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
                     _PENDING_T[fp] = time.monotonic()
                     break
         if cached is not None:
+            _lookup_satisfied(fp, prewarm)
             xferstats.bump("cache_hits", 1, tag="dedup")
             TR.instant("compile:cache-hit", "compile",
                        {"tag": tag[:16], "cache": "hit",
@@ -1160,6 +1218,7 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
                    .set("fp", fp[:12])
                 joined = fut.result(
                     timeout=deadline_s if deadline_s else None)
+            _lookup_satisfied(fp, prewarm)
             try:    # the owner's _publish noted ITS tag; the joiner's
                 from ..runtime import devprof   # tag->fp edge is new
 
@@ -1175,6 +1234,8 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
             continue    # their attempt failed; try to own it ourselves
 
     gl_report = None        # graphlint report of the vetted trace, if any
+    if prewarm:
+        _prewarm_owns(fp)
 
     def _publish(compiled):
         """Store a finished executable process-wide (+ disk happened in
@@ -1252,6 +1313,7 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
             xferstats.bump("cache_hits" if compiled is not None
                            else "cache_misses", 1, tag="aot")
             if compiled is not None:
+                _lookup_satisfied(fp, prewarm)
                 _publish(compiled)
         if compiled is None and deadline_s and deadline_s > 0 \
                 and _deadline_known_exceeded(fp):
@@ -1295,8 +1357,13 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
                         _sp.set("tag", tag[:16]).set("n_ops", n_ops) \
                            .set("cache", "miss").set("fp", fp[:12]) \
                            .set("isolation", "subprocess")
-                        compiled = _compile_in_subprocess(
-                            fp, lowered, deadline_s, n_ops)
+                        _bump("compile_starts")   # in the child
+                        try:
+                            compiled = _compile_in_subprocess(
+                                fp, lowered, deadline_s, n_ops)
+                        finally:
+                            if compiled is None:   # killed, died, no handback
+                                _bump("compile_failures")
                     if compiled is not None:
                         _note_compile(tag, time.perf_counter() - t0,
                                       n_ops,
@@ -1318,10 +1385,12 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
                     # keeps burning in background and publishes if it
                     # ever finishes, but the job moves on at the deadline
                     cfut: Future = Future()
+                    cause = TR.handoff()    # the compile names its caller
 
                     def _runner():
                         try:
-                            cfut.set_result(_compile_job())
+                            with TR.adopt(cause):
+                                cfut.set_result(_compile_job())
                         except BaseException as e:  # noqa: BLE001
                             cfut.set_exception(e)
 
@@ -1354,7 +1423,7 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
 
 def submit_compile(fn, args: tuple, donate_argnums=(), salt: str = "",
                    tag: str = "", n_ops: int = 0,
-                   deadline_s=None) -> Future:
+                   deadline_s=None, prewarm: bool = False) -> Future:
     """Queue a compile on the pool (ahead-of-time / overlapped with
     execution). Foreground dispatches of the same fingerprint join the
     in-flight future instead of compiling again. Inside a
@@ -1366,11 +1435,14 @@ def submit_compile(fn, args: tuple, donate_argnums=(), salt: str = "",
         STATS["pool_jobs"] += 1
         if bg:
             STATS["background_compiles"] += 1
+        if prewarm:
+            STATS["prewarm_submitted"] += 1
     target = bg_pool() if bg else pool()
     if not TR.enabled():
         return target.submit(compile_traced, fn, args,
                              donate_argnums=donate_argnums, salt=salt,
-                             tag=tag, n_ops=n_ops, deadline_s=deadline_s)
+                             tag=tag, n_ops=n_ops, deadline_s=deadline_s,
+                             prewarm=prewarm)
 
     t_sub = TR.now_us()
 
@@ -1383,7 +1455,7 @@ def submit_compile(fn, args: tuple, donate_argnums=(), salt: str = "",
                     {"tag": tag[:16], "lane": "bg" if bg else "fg"})
         return compile_traced(fn, args, donate_argnums=donate_argnums,
                               salt=salt, tag=tag, n_ops=n_ops,
-                              deadline_s=deadline_s)
+                              deadline_s=deadline_s, prewarm=prewarm)
 
     return target.submit(_pool_job)
 
@@ -1455,6 +1527,26 @@ class AotJit:
         self._deadline = deadline
         self._by_spec: dict = {}
         self._jit = None
+        self._last = None        # the executable the last call launched
+        self._modnames: dict = {}
+
+    @property
+    def last_module(self) -> str:
+        """HLO module name of the executable the last call launched — as
+        the profiler's `XLA Modules` line reads it. De-duplication may
+        have handed this fn an executable built under another stage's
+        name; the plain-jit fallback launches this fn's own."""
+        entry = self._last
+        if entry is None:
+            return "jit_" + getattr(self._fn, "__name__", "")
+        name = self._modnames.get(id(entry))
+        if name is None:
+            try:
+                name = entry.runtime_executable().hlo_modules()[0].name
+            except Exception:
+                name = "jit_" + getattr(self._fn, "__name__", "")
+            self._modnames[id(entry)] = name
+        return name
 
     def _plain(self):
         if self._jit is None:
@@ -1484,7 +1576,9 @@ class AotJit:
                     entry = None
                 self._by_spec[key] = entry if entry is not None else _FALLBACK
         if entry in (None, _FALLBACK):
+            self._last = None
             return self._plain()(*args)
+        self._last = entry
         try:
             return entry(*args)
         except TypeError:

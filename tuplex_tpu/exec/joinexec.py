@@ -24,6 +24,7 @@ import numpy as np
 from ..core import typesys as T
 from ..core.row import Row
 from ..runtime import columns as C
+from ..runtime import tracing as TR
 from .local import ExceptionRecord, StageResult
 
 
@@ -40,8 +41,6 @@ class JoinExecutor:
 
     def execute(self, stage, left_partitions: list[C.Partition], context,
                 intermediate=False):
-        from ..runtime import tracing as TR
-
         with TR.span("join:execute", "exec") as _sp:
             res = self._execute_impl(stage, left_partitions, context,
                                      intermediate=intermediate)
@@ -52,7 +51,6 @@ class JoinExecutor:
     def _execute_impl(self, stage, left_partitions: list[C.Partition],
                       context, intermediate=False):
         from ..plan.physical import plan_stages
-        from ..runtime import tracing as TR
 
         op = stage.op
         t0 = time.perf_counter()
@@ -464,7 +462,18 @@ class _VectorBuild:
     def _probe_sig(self, lpart: C.Partition, sig: np.ndarray, excs: list
                    ) -> Optional[C.Partition]:
         plan = self._probe_plan(lpart, sig, excs)
-        return self._assemble_host(lpart, plan)
+        return self._assemble(lpart, plan, device=False)
+
+    def _assemble(self, lpart: C.Partition, plan: dict, device: bool
+                  ) -> Optional[C.Partition]:
+        """The join output from the gather program, inside `join:assemble`
+        (its gathers inside `join:gather`)."""
+        with TR.span("join:assemble", "exec") as _sp:
+            _sp.set("rows_out", int(plan["m"])) \
+               .set("path", "device" if device else "host")
+            if device:
+                return self._assemble_device(lpart, plan)
+            return self._assemble_host(lpart, plan)
 
     def _probe_plan(self, lpart: C.Partition, sig: np.ndarray,
                     excs: list) -> dict:
@@ -570,10 +579,12 @@ class _VectorBuild:
         m = plan["m"]
         extra_rows = plan["extra_rows"]
         # gather left (minus key), key, right (minus key)
-        lgather = self._gather(lpart, left_idx)
-        rgather = self._gather(self.big, build_rows,
-                               valid_rows=has_match
-                               if op.how == "left" else None)
+        with TR.span("join:gather", "exec") as _sp:
+            _sp.set("rows_out", m_vec)
+            lgather = self._gather(lpart, left_idx)
+            rgather = self._gather(self.big, build_rows,
+                                   valid_rows=has_match
+                                   if op.how == "left" else None)
         if lgather is None or rgather is None:
             return None
         out_cols, out_types, entries = self._output_layout(ls)
@@ -709,16 +720,22 @@ def _build_probe_fn(u: int, nw: int, mesh=None):
         # in-process and reuse the serialized artifact across processes
         from .compilequeue import aot_jit
 
-        return aot_jit(lower_bound, tag="join")
+        return aot_jit(TR.name_fn(lower_bound, "joinprobe",
+                                  TR.key8(u, nw, direct)), tag="join")
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import DATA_AXIS
     from ..runtime.jaxcfg import shard_map_compat
 
-    fn = shard_map_compat(lower_bound, mesh,
-                          (P(DATA_AXIS), P()),
-                          (P(DATA_AXIS), P(DATA_AXIS)))
-    return jax.jit(fn)
+    sharded = shard_map_compat(lower_bound, mesh,
+                               (P(DATA_AXIS), P()),
+                               (P(DATA_AXIS), P(DATA_AXIS)))
+
+    def fn(words, build_words):
+        return sharded(words, build_words)
+
+    return jax.jit(TR.name_fn(fn, "joinprobe", TR.key8(
+        u, nw, direct, tuple(mesh.devices.shape))))
 
 
 def _leaf_flat_arrays(part: C.Partition, prefix: str) -> Optional[dict]:
@@ -771,7 +788,9 @@ def _build_assemble_fn(pairs: tuple, left_join: bool):
 
     from .compilequeue import aot_jit
 
-    return aot_jit(fn, salt=f"assemble{int(left_join)}", tag="join")
+    return aot_jit(TR.name_fn(fn, "joinassemble",
+                              TR.key8(pairs, left_join)),
+                   salt=f"assemble{int(left_join)}", tag="join")
 
 
 def _build_gather_fn(lkeys: tuple, rkeys: tuple, left_join: bool):
@@ -797,7 +816,9 @@ def _build_gather_fn(lkeys: tuple, rkeys: tuple, left_join: bool):
 
     from .compilequeue import aot_jit
 
-    return aot_jit(gather, salt=f"gather{int(left_join)}", tag="join")
+    return aot_jit(TR.name_fn(gather, "joingather",
+                              TR.key8(lkeys, rkeys, left_join)),
+                   salt=f"gather{int(left_join)}", tag="join")
 
 
 class _DeviceProbe(_VectorBuild):
@@ -836,10 +857,10 @@ class _DeviceProbe(_VectorBuild):
                    ) -> Optional[C.Partition]:
         plan = self._probe_plan(lpart, sig, excs)
         if self.dev_out and self._mesh is None and not plan["extra_rows"]:
-            outp = self._assemble_device(lpart, plan)
+            outp = self._assemble(lpart, plan, device=True)
             if outp is not None:
                 return outp
-        return self._assemble_host(lpart, plan)
+        return self._assemble(lpart, plan, device=False)
 
     def _assemble_device(self, lpart: C.Partition, plan: dict
                          ) -> Optional[C.Partition]:

@@ -321,9 +321,10 @@ class LocalBackend:
                        tag=tag, n_ops=n_ops, deadline=deadline)
 
     # ------------------------------------------------------------------
-    def precompile_plan(self, stages, partitions) -> None:
+    def precompile_plan(self, stages, partitions):
         """Kick off ahead-of-time compilation of the whole plan on the
-        compile pool (exec/compilequeue). Speculative and asynchronous:
+        compile pool (exec/compilequeue); returns the driver's Future, or
+        None where nothing was submitted. Speculative and asynchronous:
         stage avals are PREDICTED by chaining abstract shape evaluation
         from the first source partition, so stage i+1 (and i+2, ...)
         compiles while stage i executes; a wrong prediction only wastes a
@@ -335,16 +336,19 @@ class LocalBackend:
         from . import compilequeue as CQ
 
         if type(self) is not LocalBackend:
-            return   # mesh/serverless dispatch builds different executables
+            return None  # mesh/serverless dispatch builds other executables
         if self.interpret_only or not CQ.parallel_compile_enabled() \
                 or not self.options.get_bool(
                     "tuplex.tpu.parallelCompile", True):
-            return
+            return None
         first = partitions[0] if isinstance(partitions, list) \
             and partitions else None
         if first is None:
-            return
-        CQ.pool().submit(self._precompile_driver, list(stages), first)
+            return None
+        # the pool hands the submitting span over (TR.handoff/adopt): the
+        # driver's compiles name `compile:precompile-plan` and its job
+        return CQ.pool().submit(self._precompile_driver, list(stages),
+                                first)
 
     def _precompile_driver(self, stages, first_part):
         """Walk the plan predicting each stage's dispatch avals and submit
@@ -423,7 +427,8 @@ class LocalBackend:
                 futs.append(CQ.submit_compile(
                     raw, (avals,), donate_argnums=(0,) if donate else (),
                     salt=self.fn_cache_salt(), tag=stage.key(),
-                    n_ops=len(stage.ops), deadline_s=deadline))
+                    n_ops=len(stage.ops), deadline_s=deadline,
+                    prewarm=True))
             if stage.limit >= 0 or any(
                     isinstance(op, L.FilterOperator) for op in stage.ops):
                 break        # output row count is data-dependent
@@ -1209,9 +1214,17 @@ class LocalBackend:
             # "sample" would poison the histograms and the tuner feed).
             dp_on = DP.enabled() and stage is not None
             t_dev = time.perf_counter() if dp_on else 0.0
-            with TR.device_annotation(f"tpx:dispatch:{skey[:12]}"
-                                      if TR.enabled() else ""):
-                outs = device_fn(batch.arrays)
+            with TR.span("dispatch:launch", "exec") as _lsp:
+                with TR.device_annotation(f"tpx:dispatch:{skey[:12]}"
+                                          if TR.enabled() else ""):
+                    outs = device_fn(batch.arrays)
+                if _lsp is not TR.NOOP:
+                    # the executable actually launched (de-duplication may
+                    # hand this stage another stage's): joins the span to
+                    # the profiler's `XLA Modules` line
+                    _lsp.set("module",
+                             getattr(device_fn, "last_module", None)) \
+                        .set("first_call", int(first_call))
             # the async-return stamp: everything up to here is staging +
             # H2D + launch; the split tuner's BOUNDARY sample below must
             # use this, not a post-block stamp — with devprof on, the
@@ -1227,7 +1240,8 @@ class LocalBackend:
                 # dispatch/merge overlap — that is the price of
                 # attribution; TUPLEX_DEVPROF=0 restores the fully-async
                 # window with a single flag check here.
-                DP.block_ready(outs)
+                with TR.span("dispatch:device-wait", "exec"):
+                    DP.block_ready(outs)
                 DP.record_dispatch(stage.key(),
                                    time.perf_counter() - t_dev,
                                    cold=first_call, rows=part.num_rows,
@@ -1926,6 +1940,7 @@ def _prefetch_iter(it, depth: int):
     # dispatch-path events were reliably tenant-tagged
     stream = TR.current_stream()
     scope = xferstats.current_scope()
+    cause = TR.handoff()     # the producer's reads name the consuming job
 
     def put(item) -> bool:
         while not stop.is_set():
@@ -1942,9 +1957,10 @@ def _prefetch_iter(it, depth: int):
         if scope is not None:
             xferstats.set_scope(scope)
         try:
-            for item in it:
-                if not put(item):
-                    return   # consumer stopped early (take-limit)
+            with TR.adopt(cause):
+                for item in it:
+                    if not put(item):
+                        return   # consumer stopped early (take-limit)
             put(_END)
         except BaseException as e:  # surface source errors on the consumer
             put(e)
@@ -1954,7 +1970,8 @@ def _prefetch_iter(it, depth: int):
     t.start()
     try:
         while True:
-            item = q.get()
+            with TR.span("source:wait", "io"):
+                item = q.get()
             if item is _END:
                 return
             if isinstance(item, BaseException):

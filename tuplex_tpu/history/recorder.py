@@ -5,6 +5,7 @@ from __future__ import annotations
 import html
 import json
 import os
+import threading
 import time
 import uuid
 from typing import Optional
@@ -246,6 +247,14 @@ class JobRecorder:
         evts = tracing.events_since(self._trace_mark)
         if not evts:
             return
+        # the `job` span is still open on this thread (it closes after this
+        # turn, so that it covers the boxing of the rows and this write):
+        # embed it as it stands, so the slice keeps its root
+        now, tid = tracing.now_us(), threading.get_ident()
+        mine = [s for s in tracing.open_spans()
+                if s["tid"] == tid and s["ts"] >= self._trace_mark]
+        evts = evts + [dict(s, dur=now - s["ts"], depth=d)
+                       for d, s in enumerate(mine)]
         spans, n_total, n_dropped = _span_slice(evts, self.SPAN_EVENT_CAP)
         self._write({"event": "spans", "n_total": n_total,
                      "n_dropped": n_dropped, "spans": spans})

@@ -30,6 +30,8 @@ from ..core.errors import TuplexException
 from ..core.row import Row
 from ..plan import logical as L
 from ..runtime import columns as C
+from ..runtime import tracing as TR
+from ..runtime import xferstats
 from .vfs import VirtualFileSystem, files_fingerprint
 
 DEFAULT_NULL_VALUES = ("",)
@@ -408,16 +410,25 @@ class CSVSourceOperator(L.LogicalOperator):
                 delimiter=stat.delimiter,
                 quote_char=getattr(stat, "quotechar", '"'),
                 invalid_row_handler=on_invalid)
+            file_rows = 0
             with pacsv.open_csv(_csv_input(path), read_options=read_opts,
                                 parse_options=parse_opts,
                                 convert_options=conv_opts) as reader:
-                for batch in reader:
+                for batch in TR.pulls(reader, "ingest:read-csv", "io"):
                     if batch.num_rows == 0:
                         continue
-                    tbl = pa.Table.from_batches([batch])
-                    p = _table_to_partition(tbl, raw_schema, max_w, offset)
+                    with TR.span("ingest:to-partition", "io") as _sp:
+                        _sp.set("rows", batch.num_rows)
+                        p = _table_to_partition(
+                            pa.Table.from_batches([batch]), raw_schema,
+                            max_w, offset)
                     offset += p.num_rows
+                    file_rows += p.num_rows
                     yield p
+            # streamed: the counters move once the file's last batch is in
+            # (a take() that stops early never read the whole file)
+            _note_read(TR.NOOP, path, file_rows + len(bad_rows),
+                       len(out_columns))
             if bad_rows:
                 p = _bad_rows_partition(bad_rows, stat, proj_idx, raw_schema,
                                         offset)
@@ -460,9 +471,12 @@ class CSVSourceOperator(L.LogicalOperator):
         out_columns = list(projection) if projection else stat.columns
         raw_schema = T.row_of(out_columns,
                               [T.option(T.STR)] * len(out_columns))
-        table = pacsv.read_csv(_csv_input(path), read_options=read_opts,
-                               parse_options=parse_opts,
-                               convert_options=conv_opts)
+        with TR.span("ingest:read-csv", "io") as _sp:
+            table = pacsv.read_csv(_csv_input(path), read_options=read_opts,
+                                   parse_options=parse_opts,
+                                   convert_options=conv_opts)
+            _note_read(_sp, path, table.num_rows + len(bad_rows),
+                       len(out_columns))
 
         max_w = context.options_store.get_int("tuplex.tpu.maxStrBytes", 4096)
         rows_per_part = _csv_rows_per_partition(context, table)
@@ -476,15 +490,17 @@ class CSVSourceOperator(L.LogicalOperator):
             # rows like the reference (advisor finding, round 1).
             scanned = _scan_bad_records(path, stat)
             if len(scanned) == len(bad_rows):
-                yield from _spliced_partitions(
+                yield from TR.pulls(_spliced_partitions(
                     table, scanned, raw_schema, proj_idx, max_w,
-                    rows_per_part, base_index)
+                    rows_per_part, base_index), "ingest:to-partition", "io")
                 return
         start = 0
         for m in _chunk_sizes(n, rows_per_part):
-            chunk = table.slice(start, m)
-            yield _table_to_partition(chunk, raw_schema, max_w,
-                                      base_index + start)
+            with TR.span("ingest:to-partition", "io") as _sp:
+                _sp.set("rows", m)
+                part = _table_to_partition(table.slice(start, m), raw_schema,
+                                           max_w, base_index + start)
+            yield part
             start += m
         # position recovery failed (python csv disagreed with Arrow about
         # which rows are malformed): append bad rows as one trailing
@@ -492,6 +508,20 @@ class CSVSourceOperator(L.LogicalOperator):
         if bad_rows:
             yield _bad_rows_partition(bad_rows, stat, proj_idx, raw_schema,
                                       base_index + n)
+
+
+def _note_read(sp, path: str, rows: int, columns: int) -> None:
+    """One file is read: its size, rows and columns go on the
+    `ingest:read-csv` span and into the `ingest_*` counters (always on,
+    like the transfer counters)."""
+    try:
+        nbytes = VirtualFileSystem.file_size(path)
+    except Exception:
+        nbytes = 0
+    sp.set("bytes", nbytes).set("rows", rows).set("columns", columns)
+    xferstats.bump("ingest_bytes", nbytes)
+    xferstats.bump("ingest_rows", rows)
+    xferstats.bump("ingest_files", 1)
 
 
 def _bad_rows_partition(bad_rows: list, stat: "CSVStatistic",
@@ -757,31 +787,32 @@ def make_csv_operator(options, pattern: str, columns=None, header=None,
     # sniffing an unchanged file with unchanged params is deterministic:
     # memoize so re-planned pipelines (repeat actions, benchmarks) skip the
     # sample read + type inference (reference re-runs CSVStatistic per plan)
-    sig = _file_sig(files[0])
-    skey = None
-    if sig is not None:
-        skey = (sig, max_sample, delimiter, header, quotechar,
-                tuple(null_values),
-                tuple(columns) if columns else None,
-                tuple(sorted(type_hints.items())) if type_hints else None,
-                options.get_float("tuplex.normalcaseThreshold", 0.9),
-                options.get_int("tuplex.csv.maxDetectionRows", 1000))
-        stat = _STAT_CACHE.get(skey)
-        if stat is not None:
-            src = CSVSourceOperator(options, pattern, stat, files)
-            return L.DecodeOperator(src, _decoded_schema(stat),
-                                    stat.null_values,
-                                    general=T.row_of(stat.columns,
-                                                     stat.general_types))
-    with VirtualFileSystem.open_read(files[0], "rb") as fp:
-        sample = fp.read(max_sample)
-    stat = CSVStatistic(sample, options, delimiter=delimiter, header=header,
-                        null_values=null_values, columns=columns,
-                        type_hints=type_hints, quotechar=quotechar)
-    if skey is not None:
-        if len(_STAT_CACHE) >= _STAT_CACHE_CAP:
-            _STAT_CACHE.pop(next(iter(_STAT_CACHE)))
-        _STAT_CACHE[skey] = stat
+    with TR.span("ingest:sniff", "io") as _sp:
+        sig = _file_sig(files[0])
+        skey = None
+        stat = None
+        if sig is not None:
+            skey = (sig, max_sample, delimiter, header, quotechar,
+                    tuple(null_values),
+                    tuple(columns) if columns else None,
+                    tuple(sorted(type_hints.items())) if type_hints
+                    else None,
+                    options.get_float("tuplex.normalcaseThreshold", 0.9),
+                    options.get_int("tuplex.csv.maxDetectionRows", 1000))
+            stat = _STAT_CACHE.get(skey)
+        _sp.set("cached", int(stat is not None))
+        if stat is None:
+            with VirtualFileSystem.open_read(files[0], "rb") as fp:
+                sample = fp.read(max_sample)
+            _sp.set("bytes", len(sample))
+            stat = CSVStatistic(sample, options, delimiter=delimiter,
+                                header=header, null_values=null_values,
+                                columns=columns, type_hints=type_hints,
+                                quotechar=quotechar)
+            if skey is not None:
+                if len(_STAT_CACHE) >= _STAT_CACHE_CAP:
+                    _STAT_CACHE.pop(next(iter(_STAT_CACHE)))
+                _STAT_CACHE[skey] = stat
     src = CSVSourceOperator(options, pattern, stat, files)
     return L.DecodeOperator(src, _decoded_schema(stat), stat.null_values,
                             general=T.row_of(stat.columns,

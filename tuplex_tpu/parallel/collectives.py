@@ -44,6 +44,12 @@ def _batch_specs(arrays_example, axis):
             for k, v in arrays_example.items()}
 
 
+def _shape_key(arrays_example) -> tuple:
+    """The builder's array argument as a process-stable key part."""
+    return tuple(sorted((k, tuple(np.shape(v)), str(v.dtype))
+                        for k, v in arrays_example.items()))
+
+
 def sharded_fold_fn(eval_exprs: Callable, reducers: Sequence[str], mesh,
                     arrays_example, axis: str = DATA_AXIS):
     """Build a jitted mesh-parallel fold (ONE compile per cache entry: the
@@ -77,9 +83,15 @@ def sharded_fold_fn(eval_exprs: Callable, reducers: Sequence[str], mesh,
     with TR.span("collective:build-fold", "compile") as _sp:
         _sp.set("reducers", list(reducers))
         specs = _batch_specs(arrays_example, axis)
-        fn = shard_map_compat(local_fold, mesh, (specs,),
-                              tuple(P() for _ in reducers) + (P(axis),))
-        return jax.jit(fn)
+        sharded = shard_map_compat(local_fold, mesh, (specs,),
+                                   tuple(P() for _ in reducers) + (P(axis),))
+
+        def fn(arrays):
+            return sharded(arrays)
+
+        return jax.jit(TR.name_fn(fn, "fold", TR.key8(
+            tuple(reducers), _shape_key(arrays_example),
+            tuple(mesh.devices.shape))))
 
 
 def sharded_segment_fold_fn(eval_exprs: Callable, reducers: Sequence[str],
@@ -122,6 +134,13 @@ def sharded_segment_fold_fn(eval_exprs: Callable, reducers: Sequence[str],
     with TR.span("collective:build-segment-fold", "compile") as _sp:
         _sp.set("reducers", list(reducers)).set("nseg", nseg)
         specs = _batch_specs(arrays_example, axis)
-        fn = shard_map_compat(local_fold, mesh, (specs, P(axis)),
-                              tuple(P() for _ in reducers) + (P(), P(axis)))
-        return jax.jit(fn)
+        sharded = shard_map_compat(
+            local_fold, mesh, (specs, P(axis)),
+            tuple(P() for _ in reducers) + (P(), P(axis)))
+
+        def fn(arrays, codes):
+            return sharded(arrays, codes)
+
+        return jax.jit(TR.name_fn(fn, "segfold", TR.key8(
+            tuple(reducers), nseg, _shape_key(arrays_example),
+            tuple(mesh.devices.shape))))

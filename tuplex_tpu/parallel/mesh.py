@@ -51,6 +51,14 @@ def shard_layout(x) -> list:
             for s in x.addressable_shards]
 
 
+def _named_mesh_fn(fn, raw_fn, tag: str):
+    """`jit_tpx_mesh_<key8>`: the replicated-output wrapper of a stage fn,
+    under the key8 its raw fn was named with (plan/physical)."""
+    from ..runtime import tracing as TR
+
+    return TR.name_fn(fn, "mesh", TR.fn_key8(raw_fn, tag))
+
+
 def shard_stage_fn(raw_fn, mesh, axis: str = DATA_AXIS, salt: str = "",
                    tag: str = "", n_ops: int = 0, deadline=None,
                    on_dispatch=None):
@@ -97,7 +105,7 @@ def shard_stage_fn(raw_fn, mesh, axis: str = DATA_AXIS, salt: str = "",
         return jax.tree.map(
             lambda o: jax.lax.with_sharding_constraint(o, repl), out)
 
-    jfn = jax.jit(replicated_out)
+    jfn = jax.jit(_named_mesh_fn(replicated_out, raw_fn, tag))
     pid = jax.process_index()
 
     def local_row_range(shape):
@@ -150,7 +158,7 @@ def hostblock_stage_fn(raw_fn, mesh, block_rows: int, axis: str = DATA_AXIS):
         return jax.tree.map(
             lambda o: jax.lax.with_sharding_constraint(o, repl), out)
 
-    jfn = jax.jit(replicated_out)
+    jfn = jax.jit(_named_mesh_fn(replicated_out, raw_fn, "hostblock"))
 
     def dispatch(local_arrays):
         placed = {}

@@ -564,7 +564,12 @@ class TransformStage:
                         rowidx].set(outs["#foldok"], mode="drop")
             return outs
 
-        return fn
+        # the HLO module reads `jit_tpx_stage_<key8>` (general tier:
+        # `stagegen`), so a device trace names the stage it ran
+        from ..runtime import tracing as TR
+
+        return TR.name_fn(fn, "stagegen" if general else "stage",
+                          self.key())
 
 
 def _fusion_barrier(ctx: EmitCtx, row: CV, keep):

@@ -599,6 +599,13 @@ class PackedStageFn:
         self._tag = tag          # compile-seconds attribution (stage key)
         self._n_ops = n_ops      # feeds the stage-split tuner curve
         self._deadline = deadline   # compile deadline (CompileTimeout)
+        self._last_fn = None     # the per-layout fn the last call launched
+
+    @property
+    def last_module(self):
+        """HLO module name of the executable the last call launched
+        (`dispatch:launch.module`)."""
+        return getattr(self._last_fn, "last_module", None)
 
     def _make_entry(self, spec, ekey):
         """Build (and cache) the per-layout compiled entry: the traced
@@ -627,6 +634,11 @@ class PackedStageFn:
             cell["vspec"] = vspec
             return obuf, vbuf, extra_outs
 
+        # the wire closure carries the stage's own key: `jit_tpx_pack_<key8>`
+        # beside the unpacked `jit_tpx_stage_<key8>` of the same stage
+        from . import tracing as TR
+
+        TR.name_fn(traced, "pack", TR.fn_key8(self._raw, self._tag))
         # content-addressed AOT route (exec/compilequeue): the trace —
         # which records ospec/vspec into `cell` as a side effect — runs
         # on every path (fingerprinting always traces); only the XLA
@@ -679,7 +691,8 @@ class PackedStageFn:
         return CQ.submit_compile(
             traced, (buf_aval, ex_avals),
             donate_argnums=(0,) if self._donate else (), salt="pack",
-            tag=self._tag, n_ops=self._n_ops, deadline_s=self._deadline)
+            tag=self._tag, n_ops=self._n_ops, deadline_s=self._deadline,
+            prewarm=True)
 
     def note_async_defect(self) -> bool:
         """Forward the async deserialize-defect verdict (see
@@ -703,6 +716,7 @@ class PackedStageFn:
         if entry is None:
             entry = self._make_entry(spec, ekey)
         fn, cell = entry[0], entry[1]
+        self._last_fn = fn
         import os
 
         if os.environ.get("TUPLEX_PACK_DEBUG"):

@@ -6,11 +6,21 @@ clock to compile-queue waits, D2H materialization, or the interpreter
 resolve tier. Per-stage sums (api/metrics.py) can't show that; this module
 records WHERE the seconds went as a span timeline:
 
-  * ``span(name, cat)`` is a context manager (and ``traced()`` a
-    decorator) that records one closed interval per entered span. Spans
-    nest naturally — a per-thread stack tracks depth, and concurrent
-    threads (the compile pool, source prefetch) interleave without locks
-    on the hot path.
+  * ``span(name, cat)`` is a context manager that records one closed
+    interval per entered span. Spans nest naturally — a per-thread stack
+    tracks depth, and concurrent threads (the compile pool, source
+    prefetch) interleave without locks on the hot path.
+  * every record names its CAUSE: ``id`` (process-wide serial),
+    ``parent`` (the enclosing span on that thread, else the span that
+    handed the work over) and ``job`` (id of the ``job`` span at the
+    root; None outside a job). Work crosses threads through one pair:
+    ``handoff()`` on the submitting thread, ``with adopt(h):`` on the
+    worker — a pool compile is put down to the job that submitted it.
+  * ``open_spans()`` snapshots the spans open NOW on every thread (a
+    record is written when a span closes, so a minutes-long background
+    compile in flight at the end of a window is in no other reading) and
+    ``dropped()`` counts ring evictions, so a reader can tell a sum over
+    a ring that wrapped.
   * storage is a RING BUFFER (``TUPLEX_TRACE_BUFFER`` events, default
     65536): a long job keeps the most recent window instead of growing
     without bound. deque.append is atomic under the GIL, so recording
@@ -36,6 +46,8 @@ in microseconds (the Chrome trace unit).
 from __future__ import annotations
 
 import contextlib
+import hashlib
+import itertools
 import json
 import os
 import threading
@@ -63,6 +75,10 @@ _events: "deque[dict]" = deque(maxlen=_capacity())
 _tls = threading.local()
 _host_pid: Optional[int] = None        # multihost lane (jax process index)
 _tid_names: dict[int, str] = {}        # tid -> thread name (export metadata)
+_ids = itertools.count(1)              # span serial; next() is GIL-atomic
+_open: "dict[int, _Span]" = {}         # id -> span open now (open_spans)
+_dropped = 0                           # ring evictions since clear()
+_drop_lock = threading.Lock()          # taken only once the ring is full
 
 
 def enabled() -> bool:
@@ -77,8 +93,26 @@ def enable(on: bool = True) -> None:
 
 
 def clear() -> None:
+    """Drop the recorded events and the eviction count. Spans still open
+    stay open (``open_spans``) and record when they close."""
+    global _dropped
     _events.clear()
     _tid_names.clear()
+    _dropped = 0
+
+
+def dropped() -> int:
+    """Records the ring evicted since ``clear()``: anything but 0 means a
+    sum over ``events()`` misses the oldest part of the window."""
+    return _dropped
+
+
+def _record(rec: dict) -> None:
+    global _dropped
+    if len(_events) == _events.maxlen:
+        with _drop_lock:
+            _dropped += 1
+    _events.append(rec)
 
 
 def set_host(idx: int) -> None:
@@ -123,7 +157,7 @@ def to_trace_us(perf_s: float) -> float:
 class _NoopSpan:
     """Shared do-nothing span for the disabled path: entering, exiting and
     setting attributes all fall through. One module-level instance — a
-    disabled ``span()`` call allocates nothing."""
+    disabled ``span()`` (or ``adopt(None)``) call allocates nothing."""
 
     __slots__ = ()
 
@@ -140,13 +174,28 @@ class _NoopSpan:
 NOOP = _NoopSpan()
 
 
+def _cause() -> tuple:
+    """(parent id, job id) for something starting now on this thread: the
+    innermost open span, else what the thread adopted, else nothing."""
+    stack = getattr(_tls, "stack", None)
+    if stack:
+        top = stack[-1]
+        return top.id, top.job
+    return getattr(_tls, "adopted", None) or (None, None)
+
+
 class _Span:
-    __slots__ = ("name", "cat", "args", "_ts", "_depth")
+    __slots__ = ("name", "cat", "args", "id", "parent", "job", "tid",
+                 "_ts", "_depth")
 
     def __init__(self, name: str, cat: str, args: Optional[dict]):
         self.name = name
         self.cat = cat
         self.args = args
+        self.id = 0
+        self.parent = None
+        self.job = None
+        self.tid = 0
         self._ts = 0.0
         self._depth = 0
 
@@ -165,12 +214,19 @@ class _Span:
         if stack is None:
             stack = _tls.stack = []
         self._depth = len(stack)
+        self.id = next(_ids)
+        self.parent, self.job = _cause()
+        if self.job is None and self.name == "job":
+            self.job = self.id             # the root of its own job
+        self.tid = threading.get_ident()
         stack.append(self)
+        _open[self.id] = self
         self._ts = now_us()
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
         dur = now_us() - self._ts
+        _open.pop(self.id, None)
         stack = getattr(_tls, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
@@ -178,19 +234,20 @@ class _Span:
             stack.remove(self)
         if et is not None:
             self.set("error", et.__name__)
-        tid = threading.get_ident()
+        tid = self.tid
         if tid not in _tid_names:
             _tid_names[tid] = threading.current_thread().name
         rec = {
             "name": self.name, "cat": self.cat,
             "ts": self._ts, "dur": dur,
             "tid": tid, "depth": self._depth,
+            "id": self.id, "parent": self.parent, "job": self.job,
             "args": self.args,
         }
         st = current_stream()
         if st is not None:
             rec["stream"] = st
-        _events.append(rec)
+        _record(rec)
         return False
 
 
@@ -204,21 +261,88 @@ def span(name: str, cat: str = "exec", args: Optional[dict] = None):
     return _Span(name, cat, args)
 
 
-def traced(name: Optional[str] = None, cat: str = "exec"):
-    """Decorator form: the wrapped call body becomes one span."""
-    def deco(fn):
-        import functools
+def pulls(it, name: str, cat: str = "exec"):
+    """Iterate `it` with each pull — the work a generator does to produce
+    one item — inside its own span; `rows` is the item's ``num_rows``
+    where it has one. A generator cannot hold one span open across its
+    yields (the consumer's spans would nest inside it)."""
+    it = iter(it)
+    while True:
+        with span(name, cat) as sp:
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+            if sp is not NOOP:
+                sp.set("rows", getattr(item, "num_rows", None))
+        yield item
 
-        sname = name or fn.__qualname__
 
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            if not _enabled:
-                return fn(*a, **kw)
-            with _Span(sname, cat, None):
-                return fn(*a, **kw)
-        return wrapper
-    return deco
+# -- cause across threads ----------------------------------------------------
+
+def handoff():
+    """On the SUBMITTING thread: what a worker needs to put its spans down
+    to this thread's work — (parent id, job id) of the innermost span open
+    here (or of what this thread itself adopted). None when tracing is
+    off or nothing is open: ``adopt(None)`` then does nothing."""
+    if not _enabled:
+        return None
+    parent, job = _cause()
+    return None if parent is None else (parent, job)
+
+
+class _Adopted:
+    """``with adopt(h):`` on the worker — top-level spans opened inside
+    take `h` as parent and job. Restores what was adopted before on exit
+    (pool workers are reused)."""
+
+    __slots__ = ("_h", "_prev")
+
+    def __init__(self, h: tuple):
+        self._h = h
+        self._prev = None
+
+    def __enter__(self):
+        self._prev = getattr(_tls, "adopted", None)
+        _tls.adopted = self._h
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        _tls.adopted = self._prev
+        return False
+
+
+def adopt(h):
+    """Worker side of ``handoff()``; ``adopt(None)`` is the shared no-op."""
+    if h is None:
+        return NOOP
+    return _Adopted(h)
+
+
+def open_spans() -> list[dict]:
+    """Snapshot of the spans open NOW on every thread, oldest first: name,
+    cat, ts (us), tid, id, parent, job, args. A reader at the end of a
+    window sees here what the ring cannot hold yet — a background compile
+    still in flight."""
+    return [{"name": s.name, "cat": s.cat, "ts": s._ts, "tid": s.tid,
+             "id": s.id, "parent": s.parent, "job": s.job,
+             "args": dict(s.args) if s.args else None}
+            for s in sorted(list(_open.values()), key=lambda s: s.id)]
+
+
+def _point(name: str, cat: str, ts: float, dur, args) -> None:
+    """One record that is not a context-managed span (instant/complete)."""
+    tid = threading.get_ident()
+    if tid not in _tid_names:
+        _tid_names[tid] = threading.current_thread().name
+    parent, job = _cause()
+    ev = {"name": name, "cat": cat, "ts": ts, "dur": dur, "tid": tid,
+          "depth": len(getattr(_tls, "stack", ())),
+          "id": next(_ids), "parent": parent, "job": job, "args": args}
+    st = current_stream()
+    if st is not None:
+        ev["stream"] = st
+    _record(ev)
 
 
 def instant(name: str, cat: str = "exec",
@@ -226,16 +350,7 @@ def instant(name: str, cat: str = "exec",
     """Record a zero-duration marker (Chrome 'i' instant event)."""
     if not _enabled:
         return
-    tid = threading.get_ident()
-    if tid not in _tid_names:
-        _tid_names[tid] = threading.current_thread().name
-    ev = {"name": name, "cat": cat, "ts": now_us(), "dur": None,
-          "tid": tid,
-          "depth": len(getattr(_tls, "stack", ())), "args": args}
-    st = current_stream()
-    if st is not None:
-        ev["stream"] = st
-    _events.append(ev)
+    _point(name, cat, now_us(), None, args)
 
 
 def complete(name: str, cat: str, ts_us: float, dur_us: float,
@@ -246,16 +361,35 @@ def complete(name: str, cat: str, ts_us: float, dur_us: float,
     bracket the gap."""
     if not _enabled:
         return
-    tid = threading.get_ident()
-    if tid not in _tid_names:
-        _tid_names[tid] = threading.current_thread().name
-    ev = {"name": name, "cat": cat, "ts": float(ts_us),
-          "dur": float(dur_us), "tid": tid,
-          "depth": len(getattr(_tls, "stack", ())), "args": args}
-    st = current_stream()
-    if st is not None:
-        ev["stream"] = st
-    _events.append(ev)
+    _point(name, cat, float(ts_us), float(dur_us), args)
+
+
+# -- names for what runs on the device ---------------------------------------
+
+def key8(*parts) -> str:
+    """First 8 hex digits of a hash over `parts`' reprs: stable across
+    processes (never an id() or a counter)."""
+    return hashlib.sha256(repr(parts).encode()).hexdigest()[:8]
+
+
+def fn_key8(fn, *fallback) -> str:
+    """The key8 `fn` was named with by ``name_fn`` (a wrapper takes its
+    stage's), else ``key8`` of its name and `fallback`."""
+    name = getattr(fn, "__name__", "")
+    if name.startswith("tpx_"):
+        return name.rsplit("_", 1)[-1]
+    return key8(name, *fallback)
+
+
+def name_fn(fn, role: str, key: str):
+    """Give `fn` a stable ``__name__`` BEFORE it is jitted, so its HLO
+    module and the profiler's `XLA Modules` line read
+    ``jit_tpx_<role>_<key8>``. `key` is 8+ hex digits stable across
+    processes (a stage's ``key()``, or ``key8`` of the builder's
+    arguments). The name is no part of the content fingerprint
+    (exec/compilequeue hashes the jaxpr text)."""
+    fn.__name__ = fn.__qualname__ = f"tpx_{role}_{key[:8]}"
+    return fn
 
 
 _NULL_CM = contextlib.nullcontext()   # shared, stateless
@@ -283,7 +417,7 @@ def device_annotation(name: str):
 
 def events() -> list[dict]:
     """Snapshot of the recorded span records (ring-buffer order: oldest
-    first). Each record: name/cat/ts/dur(us)/tid/depth/args.
+    first). Each record: name/cat/ts/dur(us)/tid/depth/id/parent/job/args.
 
     Recording stays lock-free, so a compile-pool (or abandoned deadline-
     compile) thread can append mid-snapshot — deques raise RuntimeError on
