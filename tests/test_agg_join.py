@@ -724,3 +724,375 @@ def test_elastic_partial_mesh_degrade(monkeypatch):
     actions = [f.get("action") for f in be.failure_log]
     assert "elastic-mesh" in actions, actions
     assert be.n_devices == 5
+
+
+# ---------------------------------------------------------------------------
+# the by-key fold in one executable a partition: key table matched on the
+# device, masked reductions a slot, host-made codes above the capacity
+# ---------------------------------------------------------------------------
+
+def _sum2(a, b):
+    return (a[0] + b[0], a[1] + b[1])
+
+
+def _minmax4(a, b):
+    return (a[0] + b[0], min(a[1], b[1]), max(a[2], b[2]), min(a[3], b[3]))
+
+
+def _sum1(a, b):
+    return a + b
+
+
+# one UDF a statement: reflection cannot tell two lambdas of one line apart
+def _agg_v_f(a, x):
+    return (a[0] + x["v"], a[1] + x["f"])
+
+
+def _agg_v(a, x):
+    return a + x["v"]
+
+
+def _agg_f(a, x):
+    return a + x["f"]
+
+
+def _agg_div(a, x):
+    return a + 10 // x["v"]
+
+
+def _agg_minmax(a, x):
+    return (a[0] + x["f"], min(a[1], x["v"]), max(a[2], x["f"]),
+            min(a[3], x["f"] * 2))
+
+
+def _first_two(x):
+    return (x["k"][:2] if x["k"] else None, x["v"])
+
+
+def _agg_second(a, x):
+    return a + x[1]
+
+
+def _case_first_partition_has_every_key():
+    data = [("ab"[i % 2] * (1 + i % 3), i, i / 7) for i in range(1500)]
+    return dict(data=data, cols=["k", "v", "f"], keys=["k"],
+                comb=_sum2, agg=_agg_v_f, init=(0, 0.0),
+                paths={"device-table"})
+
+
+def _case_new_key_in_a_later_partition():
+    # "m" first shows up deep into the data, and sorts between the others
+    data = [("zz" if i % 2 else "a", i, float(i)) for i in range(900)] \
+        + [("m" if i % 3 == 0 else "zz", i, float(i)) for i in range(600)]
+    return dict(data=data, cols=["k", "v", "f"], keys=["k"],
+                comb=_sum2, agg=_agg_v_f, init=(0, 0.0),
+                paths={"device-table"})
+
+
+def _case_a_keys_rows_all_raise():
+    # key 7 divides by zero in every row of the early partitions (no slot,
+    # no answer), then folds beside another new key that sorts before it;
+    # key 9 never folds at all
+    data = [(7 if i % 4 == 0 else 9 if i % 4 == 1 else 3, 0 if i % 4 < 2
+             else 5) for i in range(800)] \
+        + [(7 if i % 2 else 1, 2) for i in range(400)]
+    return dict(data=data, cols=["k", "v"], keys=["k"],
+                comb=_sum1, agg=_agg_div, init=0, paths={"device-table"},
+                excs={"ZeroDivisionError": 400})
+
+
+def _case_option_str_keys_and_stale_padding():
+    # Option[str] keys out of a map stage (stale bytes past a length and
+    # under a None: test_device_key_signature_is_the_hosts)
+    words = ["alpha", "alps", "beta", "b", None, "alpine"]
+    data = [(words[i % 6], i) for i in range(1800)]
+    return dict(data=data, cols=["k", "v"], keys=["k"],
+                pre=_first_two, pre_cols=["_0", "_1"], pre_keys=["_0"],
+                comb=_sum1, agg=_agg_second, init=0, paths={"device-table"})
+
+
+def _case_float_keys_signed_zero():
+    fs = [0.0, -0.0, 1.5, -1.5, 0.0, 2.0 ** -1074]
+    data = [(fs[i % 6], (i // 6) % 2, i) for i in range(1500)]
+    return dict(data=data, cols=["g", "h", "v"], keys=["g", "h"],
+                comb=_sum1, agg=_agg_v, init=0, paths={"device-table"})
+
+
+def _case_min_max_beside_sums():
+    data = [(i % 5, (i * 37) % 101 - 50, ((i * 13) % 97) / 3 - 11)
+            for i in range(2000)]
+    return dict(data=data, cols=["k", "v", "f"], keys=["k"], comb=_minmax4,
+                agg=_agg_minmax, init=(0.0, 10 ** 9, -1e300, 1e300), paths={"device-table"})
+
+
+def _case_table_outgrows_its_bucket():
+    # 5 keys at first, 12 by the end: the table re-buckets from 8 to 16
+    data = [(i % 5 if i < 700 else i % 12, i) for i in range(2100)]
+    return dict(data=data, cols=["k", "v"], keys=["k"],
+                comb=_sum1, agg=_agg_v, init=0,
+                paths={"device-table", "table-miss"}, slots={8, 16})
+
+
+def _case_more_keys_than_the_capacity():
+    data = [(i % 7 if i < 500 else i % 400, float(i)) for i in range(3000)]
+    return dict(data=data, cols=["k", "f"], keys=["k"],
+                comb=_sum1, agg=_agg_f, init=0.0,
+                paths={"device-table", "table-miss", "host-codes"})
+
+
+_BYKEY_CASES = {
+    "every-key-in-the-first-partition": _case_first_partition_has_every_key,
+    "new-key-in-a-later-partition": _case_new_key_in_a_later_partition,
+    "a-keys-rows-all-raise": _case_a_keys_rows_all_raise,
+    "option-str-keys-stale-padding": _case_option_str_keys_and_stale_padding,
+    "float-keys-signed-zero": _case_float_keys_signed_zero,
+    "min-max-beside-sums": _case_min_max_beside_sums,
+    "table-outgrows-its-bucket": _case_table_outgrows_its_bucket,
+    "more-keys-than-the-capacity": _case_more_keys_than_the_capacity,
+}
+
+
+def _interpreter_fold(rows, cols, keys, agg, init):
+    """What CPython folds, and the rows it raises on."""
+    from tuplex_tpu.core.row import Row
+
+    out: dict = {}
+    raised = 0
+    for r in rows:
+        x = Row(list(r), cols)
+        k = tuple(x[c] for c in keys)
+        try:
+            out[k] = agg(out.get(k, init), x)
+        except Exception:
+            raised += 1
+    return out, raised
+
+
+def _order_before_this_fold(rows, cols, keys, agg, init, sizes):
+    """The order of the groups as the fold emitted them before the key
+    table: a partition at a time, its keys that had a row fold, new to the
+    answer, by their canonical signature (C.key_signature_matrix)."""
+    import tuplex_tpu
+    from tuplex_tpu.core.row import Row
+    from tuplex_tpu.runtime import columns as C
+
+    key_schema = tuplex_tpu.Context().parallelize(
+        rows[:64], columns=cols).selectColumns(keys)._op.schema()
+    order: list = []
+    at = 0
+    for n in sizes:
+        chunk = rows[at:at + n]
+        at += n
+        folded = []
+        for r in chunk:
+            x = Row(list(r), cols)
+            try:
+                agg(init, x)
+            except Exception:
+                continue
+            folded.append(tuple(x[c_] for c_ in keys))
+        new = [k for k in dict.fromkeys(folded) if k not in order]
+        if not new:
+            continue
+        part = C.build_partition(
+            [k if len(k) > 1 else k[0] for k in new], key_schema)
+        sig = C.key_signature_matrix(part, list(range(len(keys))),
+                                     reject_nan=False)
+        order += [new[i] for i in
+                  sorted(range(len(new)), key=lambda i: sig[i].tobytes())]
+    return order
+
+
+@pytest.mark.parametrize("case", list(_BYKEY_CASES))
+def test_bykey_fold_on_the_key_table(case):
+    """Over several partitions the fold equals the interpreter's, in the
+    order the fold had before the key table, by the path the case is for."""
+    import tuplex_tpu
+    from tuplex_tpu.runtime import tracing
+
+    k = _BYKEY_CASES[case]()
+    c = tuplex_tpu.Context({"tuplex.partitionSize": "4KB",
+                            "tuplex.sample.maxDetectionRows": "64"})
+    ds = c.parallelize(k["data"], columns=k["cols"])
+    rows = k["data"]
+    cols = k["cols"]
+    if "pre" in k:
+        ds = ds.map(k["pre"])
+        from tuplex_tpu.core.row import Row
+        rows = [k["pre"](Row(list(r), cols)) for r in rows]
+        cols = k["pre_cols"]
+    keys = k.get("pre_keys", k["keys"])
+    ds = ds.aggregateByKey(k["comb"], k["agg"], k["init"], keys)
+    tracing.clear()
+    tracing.enable(True)
+    try:
+        got = ds.collect()
+        evs = tracing.events()
+    finally:
+        tracing.enable(False)
+        tracing.clear()
+    want, raised = _interpreter_fold(rows, cols, keys, k["agg"],
+                                     k["init"])
+    nk = len(keys)
+    got_d = {tuple(r[:nk]): (r[nk] if len(r) == nk + 1 else tuple(r[nk:]))
+             for r in got}
+    assert len(got_d) == len(got) == len(want)
+    for key, w in want.items():
+        g = got_d[key]
+        assert g == pytest.approx(w, rel=1e-12), (key, g, w)
+        for gv, wv in zip(g if isinstance(g, tuple) else (g,),
+                          w if isinstance(w, tuple) else (w,)):
+            assert type(gv) is type(wv)
+            if isinstance(wv, int):
+                assert gv == wv
+    assert ds.exception_counts() == k.get("excs", {})
+    assert sum(k.get("excs", {}).values()) == raised
+    folds = [e["args"] for e in evs if e["name"] == "agg:segment-fold"]
+    sizes = [e["args"]["rows"] for e in evs if e["name"] == "agg:eval-exprs"]
+    assert len(sizes) > 3 and sum(sizes) == len(rows)
+    assert {f["path"] for f in folds} == k["paths"]
+    assert sum(f["rows"] for f in folds) == len(rows)
+    if "slots" in k:
+        assert {f["slots"] for f in folds
+                if f["path"] == "device-table"} == k["slots"]
+    # the host factorizes only once the keys have outgrown the table
+    assert len([e for e in evs if e["name"] == "agg:factorize-keys"]) \
+        == len([f for f in folds if f["path"] == "host-codes"])
+    assert [tuple(r[:nk]) for r in got] == _order_before_this_fold(
+        rows, cols, keys, k["agg"], k["init"], sizes)
+
+
+def test_bykey_fold_fingerprint_ignores_the_key_table():
+    """The table is an argument of the fold, not a constant: two tables of
+    one capacity trace to one content address (no key mints an executable),
+    and the fold itself gives a table the keys it lacks."""
+    import jax
+    import numpy as np
+
+    import tuplex_tpu
+    from tuplex_tpu.exec import aggexec as AE
+    from tuplex_tpu.exec import compilequeue as CQ
+    from tuplex_tpu.plan import aggregates as A
+    from tuplex_tpu.runtime import columns as C
+
+    c = tuplex_tpu.Context()
+    ds = c.parallelize([("a", 1), ("b", 2), ("c", 3)], columns=["k", "v"]) \
+        .aggregateByKey(_sum1, _agg_v, 0, ["k"])
+    op = ds._op
+    schema = op.parent.schema()
+    spec = A.recognize_fold(op.aggregate_udf)
+    part = C.build_partition([("b", 1), ("a", 2), ("b", 3)], schema)
+    batch = C.stage_partition(part)
+    fn = AE._make_bykey_fold(spec, schema, [0])
+    empty = AE._KeyTable()
+    empty.fit(AE._key_sig_plan(batch.arrays, schema, [0]))
+    assert empty.live and empty.rows.shape == (8, 1 + 8 + 4)
+    (partials, counts, unmatched, n_bad, rows, first, key_arrays), ok = \
+        jax.device_get(jax.jit(fn)(batch.arrays, empty.rows))
+    assert (int(unmatched), int(n_bad)) == (0, 0)
+    assert first.tolist() == [0, 1] + [-1] * 6      # "b" then "a"
+    assert counts.tolist() == [2, 1] + [0] * 6
+    assert partials[0].tolist()[:2] == [4, 2]
+    empty.learn(schema, [0], rows, first, key_arrays)
+    assert empty.keys == [("b",), ("a",)] and empty.order == [1, 0]
+    # the learnt table takes every row without another key
+    again = jax.device_get(jax.jit(fn)(batch.arrays, empty.rows)[0])
+    assert again[5].tolist() == [-1] * 8 and (again[4] == rows).all()
+    fps = {CQ.fingerprint_traced(jax.jit(fn).trace(batch.arrays, t),
+                                 salt="/t")
+           for t in (np.zeros_like(rows), rows)}
+    assert len(fps) == 1
+
+
+def _sig_leaf(kind):
+    """A key leaf with every quirk the canonical signature has to erase,
+    and its column type."""
+    import numpy as np
+
+    from tuplex_tpu.core import typesys as T
+    from tuplex_tpu.runtime import columns as C
+
+    rng = np.random.default_rng(7)
+    n = 40
+    valid = rng.random(n) < 0.7
+    if kind in ("str", "option-str"):
+        by = rng.integers(1, 255, (n, 5), dtype=np.uint8)  # stale everywhere
+        ln = rng.integers(0, 6, n).astype(np.int32)
+        by[::2] = by[0]                  # equal prefixes, other lengths
+        return (C.StrLeaf(by, ln, valid if kind == "option-str" else None),
+                T.option(T.STR) if kind == "option-str" else T.STR)
+    if kind in ("i64", "option-i64"):
+        d = rng.integers(-3, 3, n) * (2 ** 40 + 1)
+        return (C.NumericLeaf(d, valid if kind == "option-i64" else None),
+                T.option(T.I64) if kind == "option-i64" else T.I64)
+    if kind == "option-f64":
+        d = rng.choice([0.0, -0.0, 1.5, -1.5, 2.0 ** -1074, -2.0 ** -1074,
+                        float("nan"), float("inf")], n)
+        return C.NumericLeaf(d, valid), T.option(T.F64)
+    if kind == "bool":
+        return C.NumericLeaf(rng.random(n) < 0.5), T.BOOL
+    return C.NullLeaf(n), T.NULL
+
+
+@pytest.mark.parametrize("kinds", [
+    ("str",), ("option-str",), ("i64",), ("option-i64",), ("option-f64",),
+    ("bool",), ("null", "i64"), ("option-str", "option-f64", "bool", "str")],
+    ids="+".join)
+def test_device_key_signature_is_the_hosts(kinds):
+    """Byte for byte C.key_signature_matrix at the staged widths, and a
+    table of the host's rows takes every row on the device."""
+    import numpy as np
+
+    from tuplex_tpu.core import typesys as T
+    from tuplex_tpu.exec import aggexec as AE
+    from tuplex_tpu.runtime import columns as C
+
+    leaves, types = {}, []
+    for i, kind in enumerate(kinds):
+        leaves[str(i)], t = _sig_leaf(kind)
+        types.append(t)
+    schema = T.row_of(tuple(f"c{i}" for i in range(len(kinds))), types)
+    part = C.Partition(schema=schema, num_rows=40, leaves=leaves)
+    kidx = list(range(len(kinds)))
+    batch = C.stage_partition(part)
+    plan = AE._key_sig_plan(batch.arrays, schema, kidx)
+    assert all(w == 8 for kind, _p, w, _v in plan if kind == "str")
+    host = C.key_signature_matrix(part, kidx, reject_nan=False)
+    dev = np.asarray(AE._device_key_signature(batch.arrays, plan))
+    assert dev.shape == (1 + AE._sig_width(plan), batch.b)
+    assert (dev[0] == 1).all()
+    dev = [dev[1:, i].tobytes() for i in range(40)]
+    host = [host[i].tobytes() for i in range(40)]
+    # the same keys are equal, and they sort as the host's do (whose str
+    # pieces are 5 bytes wide where the staged ones are 8)
+    for i in range(40):
+        for j in range(40):
+            assert (dev[i] == dev[j]) == (host[i] == host[j])
+            assert (dev[i] < dev[j]) == (host[i] < host[j])
+    # an empty table takes every key from the first row that has it, as
+    # the host's factorization numbers them, and then holds every row
+    import jax.numpy as jnp
+
+    codes, uniq = AE._factorize_keys(part, kidx, np.ones(40, bool))
+    k_b = C.bucket_size(len(uniq), "pow2")
+    ok = np.zeros(batch.b, bool)
+    ok[:40] = True
+    sig = AE._device_key_signature(batch.arrays, plan)
+    table, first, lacking = AE._table_take_new_keys(
+        jnp.zeros((k_b, 1 + AE._sig_width(plan)), jnp.uint8), sig,
+        jnp.asarray(ok))
+    assert not np.asarray(lacking).any()
+    first = np.asarray(first)
+    assert sorted(first[first >= 0].tolist()) == sorted(uniq.tolist())
+    match = (np.asarray(table)[:, :, None]
+             == np.asarray(sig)[None, :, :40]).all(axis=1)
+    assert (match.sum(axis=0) == 1).all()
+    by_slot = {int(r): s for s, r in enumerate(first) if r >= 0}
+    assert (match.argmax(axis=0)
+            == [by_slot[int(uniq[c])] for c in codes]).all()
+    # half the slots: the table fills and says how many rows it lacks
+    if k_b > 8:
+        _t, first, lacking = AE._table_take_new_keys(
+            jnp.zeros((k_b // 2, 1 + AE._sig_width(plan)), jnp.uint8), sig,
+            jnp.asarray(ok))
+        assert (np.asarray(first) >= 0).all() and np.asarray(lacking).any()

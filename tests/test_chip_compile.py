@@ -237,15 +237,11 @@ def test_pallas_nfa_compiles_for_mosaic(one_chip, rows, width, pattern):
     _fits(c, f"pallas NFA {rows} x {width}")
 
 
-def test_q1_fold_and_q19_probe(tmp_path, one_chip, monkeypatch):
-    """TPC-H on the chip: Q1's fold expressions + per-key segment sums over
-    a full lineitem batch, and Q19's join probe (lower bound of 1M probe
-    keys in a sorted 40k-row build side)."""
-    import jax
-    import jax.numpy as jnp
-
+def _q1_fold_input(tmp_path):
+    """(aggregate op, its FoldSpec, a partition of the fold's input, that
+    partition staged): the fold's input is the filter stage's OUTPUT (typed
+    columns), so that stage runs here on the CPU first."""
     import tuplex_tpu
-    from tuplex_tpu.exec import aggexec, joinexec
     from tuplex_tpu.models import tpch
     from tuplex_tpu.plan import aggregates as A
     from tuplex_tpu.plan.physical import AggregateStage, plan_stages
@@ -258,11 +254,22 @@ def test_q1_fold_and_q19_probe(tmp_path, one_chip, monkeypatch):
     agg = next(s for s in stages if isinstance(s, AggregateStage))
     spec = A.recognize_fold(agg.op.aggregate_udf)
     assert spec is not None and spec.reducers == ["sum"] * 4
-    # the fold's input is the filter stage's OUTPUT (typed columns): run
-    # that stage here on the CPU, then trace the fold in its TPU branches
     part = ctx.backend.execute(
         stages[0], stages[0].source.load_partitions(ctx)).partitions[0]
-    batch = C.stage_partition(part)
+    return agg.op, spec, part, C.stage_partition(part)
+
+
+def test_q1_fold_and_q19_probe(tmp_path, one_chip, monkeypatch):
+    """TPC-H on the chip: Q1's fold expressions + per-key segment sums over
+    a full lineitem batch, and Q19's join probe (lower bound of 1M probe
+    keys in a sorted 40k-row build side)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tuplex_tpu.exec import aggexec, joinexec
+
+    _op, spec, part, batch = _q1_fold_input(tmp_path)
+    # trace the fold in its TPU branches
     from tuplex_tpu.runtime import jaxcfg
 
     monkeypatch.setattr(jaxcfg.jax, "default_backend", lambda: "tpu")
@@ -286,6 +293,50 @@ def test_q1_fold_and_q19_probe(tmp_path, one_chip, monkeypatch):
         jax.ShapeDtypeStruct((40000, 1), np.uint64,
                              sharding=one_chip)).compile()
     _fits(c, "Q19 probe 1M keys vs 40k build rows")
+
+
+# a q1 partition of the benchmark after the filter: 100,000-row chunks of
+# which 98.6% pass, in the q8 bucket above (13 x 8192)
+Q1_FOLD_ROWS = 106496
+
+
+@pytest.mark.parametrize("form", ["device-table", "host-codes"])
+def test_q1_bykey_fold_one_executable(tmp_path, one_chip, tpu_branches,
+                                      form):
+    """exec/aggexec's by-key fold as the chip compiles it, at the batch a
+    q1 partition has: the key table of 8 slots matched on the device with
+    masked float64 (float32-pair) and int64 reductions a slot, and the form
+    for more keys than the table's capacity (host-made codes, segment
+    reductions inside the same jit)."""
+    import time
+
+    import jax
+
+    from tuplex_tpu.exec import aggexec as AE
+
+    op, spec, part, batch = _q1_fold_input(tmp_path)
+    kidx = [part.schema.columns.index(c) for c in op.key_columns]
+    plan = AE._key_sig_plan(batch.arrays, part.schema, kidx)
+    assert plan is not None and all(kind == "str" for kind, *_ in plan)
+    arrays = _scaled(batch.arrays, Q1_FOLD_ROWS, one_chip)
+    if form == "device-table":
+        # two 1-character str keys: 8 bytes + 4 of length each, and the
+        # slot-taken byte
+        assert AE._sig_width(plan) == 24
+        fn = AE._make_bykey_fold(spec, part.schema, kidx)
+        key = jax.ShapeDtypeStruct((8, 25), np.uint8, sharding=one_chip)
+    else:
+        slots = 2 * AE._TABLE_MAX_SLOTS
+        fn = AE._make_bykey_fold(spec, part.schema, kidx, slots)
+        key = jax.ShapeDtypeStruct((Q1_FOLD_ROWS,), np.int32,
+                                   sharding=one_chip)
+    t0 = time.perf_counter()
+    c = jax.jit(fn).lower(arrays, key).compile()
+    print(f"\n[chip-compile] by-key fold ({form}) compiled in "
+          f"{time.perf_counter() - t0:.1f} s")
+    _fits(c, f"Q1 by-key fold, {form}, {Q1_FOLD_ROWS} rows")
+    text = c.as_text()
+    assert ("scatter" in text) == (form == "host-codes")
 
 
 def test_zillow_stage_row_sharded_on_four_chips(tmp_path, mesh4,

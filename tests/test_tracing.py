@@ -692,15 +692,43 @@ def test_pool_compile_names_the_job_that_submitted_it(
         - d["compile_failures"] == 0      # nothing left in flight
 
 
+def _sum_count(a, x):
+    return (a[0] + x["a"], a[1] + 1)
+
+
 def test_aggregate_job_yields_the_four_agg_children(ctx, trace_on,
                                                     tmp_path):
     p = str(tmp_path / "in.csv")
     _write_csv(p, 3000)
     got = (ctx.csv(p).aggregateByKey(
-        lambda a, b: (a[0] + b[0], a[1] + b[1]),
-        lambda a, x: (a[0] + x["a"], a[1] + 1), (0, 0), ["k"]).collect())
+        lambda a, b: (a[0] + b[0], a[1] + b[1]), _sum_count, (0, 0),
+        ["k"]).collect())
     assert sorted(got) == [("x", 1498500, 1000), ("y", 1499500, 1000),
                            ("z", 1500500, 1000)]
+    evs = tracing.events()
+    (agg,) = [e for e in evs if e["name"] == "agg:execute"]
+    kids = [e for e in evs if e.get("parent") == agg["id"]]
+    names = [e["name"] for e in kids]
+    # three keys fit the key table: one launch folds the partition, its
+    # groups matched on the device, and the host factorizes nothing
+    assert sorted(names) == ["agg:eval-exprs", "agg:host-merge",
+                             "agg:host-merge", "agg:segment-fold"], names
+    (sf,) = [e for e in kids if e["name"] == "agg:segment-fold"]
+    assert sf["args"] == {"slots": 8, "rows": 3000, "groups": 3,
+                          "path": "device-table"}
+    # the wrapper's self time is what its children leave over
+    covered = sum(e["dur"] for e in kids)
+    assert covered <= agg["dur"] + 1e-6
+    assert covered >= 0.5 * agg["dur"]
+    # more keys than the widest table holds: the host factorizes, and the
+    # four children are there
+    from tuplex_tpu.exec import aggexec as AE
+
+    tracing.clear()
+    got = (ctx.csv(p).aggregateByKey(
+        lambda a, b: (a[0] + b[0], a[1] + b[1]), _sum_count, (0, 0),
+        ["a"]).collect())
+    assert len(got) == 3000 > AE._TABLE_MAX_SLOTS
     evs = tracing.events()
     (agg,) = [e for e in evs if e["name"] == "agg:execute"]
     kids = [e for e in evs if e.get("parent") == agg["id"]]
@@ -709,11 +737,9 @@ def test_aggregate_job_yields_the_four_agg_children(ctx, trace_on,
               "agg:host-merge"):
         assert n in names, names
     fk = [e for e in kids if e["name"] == "agg:factorize-keys"][0]
-    assert fk["args"] == {"rows": 3000, "groups": 3}
-    # the wrapper's self time is what its children leave over
-    covered = sum(e["dur"] for e in kids)
-    assert covered <= agg["dur"] + 1e-6
-    assert covered >= 0.5 * agg["dur"]
+    assert fk["args"] == {"rows": 3000, "groups": 3000}
+    assert [e["args"]["path"] for e in kids
+            if e["name"] == "agg:segment-fold"][-1] == "host-codes"
 
 
 def test_stage_module_is_named_and_fingerprint_ignores_the_name(ctx):

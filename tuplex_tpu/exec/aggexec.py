@@ -16,6 +16,7 @@ interpreter exactly like other dual-mode work.
 from __future__ import annotations
 
 import time
+from dataclasses import dataclass
 from typing import Any, Optional
 
 import numpy as np
@@ -32,7 +33,7 @@ from .local import ExceptionRecord
 
 
 def _aot(fn, role: str, op, schema):
-    """Content-addressed compile for the scan-fold executables (the agg
+    """Content-addressed compile for the fold executables (the agg
     analog of the stage fns' compilequeue route: identical fold structures
     across jobs/processes reuse one executable). The HLO module reads
     `jit_tpx_<role>_<key8>`, keyed by the operator's identity (UDF
@@ -130,6 +131,7 @@ class AggregateExecutor:
         if by_key:
             kidx = [ps.columns.index(c) for c in op.key_columns] if ps else []
             groups: dict = {}
+            table = _KeyTable()
             scan_k = None
             if spec is None and ps is not None and not getattr(
                     self.backend, "interpret_only", False):
@@ -137,7 +139,7 @@ class AggregateExecutor:
             for part in partitions:
                 self.backend.mm.touch(part)
                 device_ok = spec is not None and self._device_fold_bykey(
-                    op, spec, part, kidx, groups, excs)
+                    op, spec, part, kidx, groups, excs, table)
                 if not device_ok and scan_k is not None:
                     device_ok = self._scan_fold_bykey(op, scan_k, part, kidx,
                                                       groups, excs)
@@ -416,7 +418,15 @@ class AggregateExecutor:
         out = tuple(partials) if not spec.scalar else partials[0]
         return out, sorted(set(bad))
 
-    def _device_fold_bykey(self, op, spec, part, kidx, groups, excs) -> bool:
+    def _device_fold_bykey(self, op, spec, part, kidx, groups, excs,
+                           table: "_KeyTable") -> bool:
+        """One partition of a recognized by-key fold, in ONE stored
+        executable: the fold expressions, the group of every row and the
+        reduction of every expression for every group. While the keys met
+        fit `_TABLE_MAX_SLOTS` the groups are the slots of `table`, matched
+        on the device, which also finds the keys the table lacks: no key
+        column leaves the chip and the host factorizes nothing. Above the
+        capacity the same fold takes host-made codes."""
         mesh = getattr(self.backend, "mesh", None)
         if mesh is not None:
             try:
@@ -424,72 +434,105 @@ class AggregateExecutor:
                                                     groups, excs, mesh)
             except NotCompilable:
                 return False
+        if not part.leaves and part.fallback:
+            return False     # all-fallback partition: interpreter
         n = part.num_rows
+        real = _real_mask(part)
+        # staging only (the handoff view where the stage left one, else the
+        # upload): the expressions run inside the fold's executable
+        with TR.span("agg:eval-exprs", "exec") as _sp:
+            _sp.set("rows", n)
+            batch = C.stage_partition(part, self.backend.bucket_mode)
+            table.fit(_key_sig_plan(batch.arrays, part.schema, kidx))
         try:
-            # staging, the eager expression ops and the fetch of the ok
-            # mask (which waits for them)
-            with TR.span("agg:eval-exprs", "exec") as _sp:
-                _sp.set("rows", n)
-                vals, ok_mask, err = self._eval_exprs(op, spec, part)
-                ok_host = np.asarray(ok_mask)
+            out = None
+            while table.live:
+                out = self._launch_bykey_fold(op, spec, part, kidx, batch,
+                                              table=table)
+                if not out.unmatched:
+                    break
+                out = None       # the table was full: a wider one, or none
+                table.grow()
+            if out is None:
+                with TR.span("agg:factorize-keys", "exec") as _sp:
+                    _sp.set("rows", n)
+                    codes, uniq_rows = _factorize_keys(part, kidx, real)
+                    if codes is None:
+                        return False
+                    _sp.set("groups", len(uniq_rows))
+                    # key columns only — see decode_key_tuples: a full
+                    # decode would force every lazy leaf to the host
+                    key_vals = C.decode_key_tuples(part, uniq_rows, kidx)
+                if len(uniq_rows):
+                    out = self._launch_bykey_fold(
+                        op, spec, part, kidx, batch,
+                        host=(codes, real, key_vals))
         except NotCompilable:
             return False
-        import jax.numpy as jnp
-        import jax.ops
-
-        ok_np = ok_host[:n] & _real_mask(part)
-        with TR.span("agg:factorize-keys", "exec") as _sp:
-            _sp.set("rows", n)
-            codes, uniq_rows = _factorize_keys(part, kidx, ok_np)
-            if codes is None:
-                return False
-            nseg = len(uniq_rows)
-            _sp.set("groups", nseg)
-            b = ok_host.shape[0]
-            codes_b = np.full(b, nseg, dtype=np.int32)  # padding -> dropped
-            codes_b[:n][ok_np] = codes
-        seg_partials = []
-        # eager segment reductions, one launch and one fetch a reducer
-        # (`jit_scatter-add` on the device: not wrapped in a jit here,
-        # ROADMAP S5)
-        with TR.span("agg:segment-fold", "exec") as _sp:
-            _sp.set("rows", n).set("groups", nseg)
-            for cv_data, reducer in zip(vals, spec.reducers):
-                is_float = cv_data.dtype.kind == "f"
-                ident = _identity(reducer, is_float)
-                masked = jnp.where(ok_mask, cv_data, ident)
-                if reducer == "sum":
-                    r = jax.ops.segment_sum(masked, codes_b,
-                                            num_segments=nseg + 1)
-                elif reducer == "min":
-                    r = jax.ops.segment_min(masked, codes_b,
-                                            num_segments=nseg + 1)
-                else:
-                    r = jax.ops.segment_max(masked, codes_b,
-                                            num_segments=nseg + 1)
-                seg_partials.append(np.asarray(r)[:nseg])
         with TR.span("agg:host-merge", "exec") as _sp:
-            _sp.set("rows", n).set("groups", nseg)
-            # merge per-key partials into the global dict (key columns only
-            # — see decode_key_tuples: full decode would force lazy leaves)
-            key_vals = C.decode_key_tuples(part, uniq_rows, kidx)
-            for si, row_i in enumerate(uniq_rows):
-                k = key_vals[si]
-                acc = groups.get(k, op.initial)
-                accs = list(acc) if isinstance(acc, tuple) else [acc]
-                merged = []
-                for j, reducer in enumerate(spec.reducers):
-                    v = seg_partials[j][si].item()
-                    merged.append(_combine_scalar(reducer, accs[j], v)
-                                  if reducer != "sum" else accs[j] + v)
-                groups[k] = tuple(merged) if isinstance(acc, tuple) \
-                    else merged[0]
-            # bad rows -> interpreter
-            bad = np.nonzero(~ok_np & _real_mask(part))[0].tolist()
-            bad += [i for i in part.fallback if i not in bad]
-            self._python_fold(op, part, sorted(set(bad)), groups, kidx,
-                              excs)
+            _sp.set("rows", n)
+            bad: list = []
+            if out is not None:
+                _sp.set("groups", int(np.count_nonzero(out.counts)))
+                for si, k in out.keys:
+                    if out.counts[si] == 0:
+                        continue  # every row of this key failed: no ghost
+                                  # group — the interpreter fold decides
+                    acc = groups.get(k, op.initial)
+                    accs = list(acc) if isinstance(acc, tuple) else [acc]
+                    merged = [_combine_scalar(reducer, accs[j],
+                                              out.partials[j][si].item())
+                              for j, reducer in enumerate(spec.reducers)]
+                    groups[k] = tuple(merged) if isinstance(acc, tuple) \
+                        else merged[0]
+                if out.n_bad:     # the only fetch that grows with the rows
+                    ok_np = np.asarray(out.ok)[:n]
+                    bad = np.nonzero(~ok_np & real)[0].tolist()
+            # bad and boxed rows -> interpreter
+            self._python_fold(op, part, sorted(set(bad) | set(part.fallback)),
+                              groups, kidx, excs)
         return True
+
+    def _launch_bykey_fold(self, op, spec, part, kidx, batch, table=None,
+                           host=None) -> "_FoldOut":
+        """Launch the by-key fold of one staged partition and fetch its
+        small results: against `table`, which takes the keys it lacked
+        (path "device-table"), or with what the host factorized, `host` =
+        (codes of the real rows, the real mask, the codes' python keys)
+        (path "host-codes")."""
+        import jax
+
+        from ..plan.physical import _op_identity
+
+        if host is None:
+            key, slots, nseg_b = table.rows, table.rows.shape[0], None
+        else:
+            codes, real, keys = host
+            slots = nseg_b = C.bucket_size(len(keys), "pow2")
+            key = np.full(batch.b, nseg_b, np.int32)   # padding -> dropped
+            key[:part.num_rows][real] = codes
+        with TR.span("agg:segment-fold", "exec") as _sp:
+            _sp.set("slots", slots)
+            fn = self.backend.jit_cache.get_or_build(
+                ("aggfold", _op_identity(op), part.schema.name, tuple(kidx),
+                 nseg_b),
+                lambda: _aot(_make_bykey_fold(spec, part.schema, kidx,
+                                              nseg_b),
+                             "aggfold", op, part.schema))
+            small, ok = fn(batch.arrays, key)
+            small = jax.device_get(small)
+            partials, counts, unmatched, n_bad = small[:4]
+            if host is None:
+                table.learn(part.schema, kidx, *small[4:])
+            # `rows` are the rows this launch folded: a table too narrow
+            # for the partition's keys folded none (it is launched again)
+            _sp.set("rows", 0 if unmatched else part.num_rows) \
+               .set("groups", int(np.count_nonzero(counts))) \
+               .set("path", "host-codes" if host is not None
+                    else "table-miss" if unmatched else "device-table")
+        return _FoldOut(partials, counts, int(unmatched), int(n_bad), ok,
+                        list(enumerate(keys)) if host is not None
+                        else [(si, table.keys[si]) for si in table.order])
 
     def _device_fold_bykey_mesh(self, op, spec, part, kidx, groups, excs,
                                 mesh) -> bool:
@@ -596,6 +639,261 @@ def _make_eval_exprs(spec: A.FoldSpec, schema):
         return datas, ok
 
     return eval_exprs
+
+
+# Widest key table of the by-key fold: up to this many keys the fold matches
+# rows against the table and reduces with masks (work K_b x B a reducer, no
+# scatter, no key on the host); above it the fold takes host-made codes and
+# reduces with segment_sum/min/max (work B a reducer, but a scatter the TPU
+# serializes, and a host sort of every row's signature). On the TPU the
+# masks win far beyond this; the number is held down by XLA:CPU, which runs
+# the same code and scatters fast. The arithmetic is in CHANGES.md (PR 27).
+_TABLE_MAX_SLOTS = 64
+
+
+@dataclass
+class _FoldOut:
+    """What one launch of the by-key fold hands back (host values but
+    `ok`, the [B] mask, which stays on the device until a row failed)."""
+    partials: tuple          # per reducer: [slots] in the expression's dtype
+    counts: np.ndarray       # [slots] ok rows
+    unmatched: int           # ok rows whose key no slot holds
+    n_bad: int               # normal rows the expressions failed on
+    ok: Any
+    keys: list               # (slot, python key) in signature order
+
+
+class _KeyTable:
+    """The canonical signatures (C.key_signature_matrix's form, at the
+    staged batch's str widths) of the keys one by-key aggregate has met,
+    as the `[K_b, 1 + W]` uint8 argument of the fold: byte 0 says a slot is
+    taken, so a free slot matches no row. The fold itself fills free slots
+    with the keys it meets (`learn` mirrors that on the host). The table
+    grows by pow2 buckets (each a shape of the fold) and goes dead (`live`
+    False: host-made codes from then on) past `_TABLE_MAX_SLOTS`, or where
+    a key column's signature cannot be built on the device."""
+
+    def __init__(self):
+        self.live = True
+        self.plan: Optional[tuple] = None
+        self.rows: Optional[np.ndarray] = None
+        self.keys: list = []             # slot -> python key tuple
+        self.order: list = []            # slots by signature, ascending
+
+    def fit(self, plan: Optional[tuple]) -> None:
+        """Adopt the partition's signature layout; another layout than the
+        table was built for (other str widths) starts it over."""
+        if plan is None:
+            self.live = False
+        elif plan != self.plan:
+            self.plan = plan
+            self.rows = np.zeros((8, 1 + _sig_width(plan)), np.uint8)
+            self.keys, self.order = [], []
+
+    def learn(self, schema, kidx, rows, first, key_arrays) -> None:
+        """Take over the table as a launch left it: `first[slot]` is the
+        row that brought a new slot its key (else -1), `key_arrays` that
+        row's key columns."""
+        new = np.nonzero(first >= 0)[0]
+        if not len(new):
+            return
+        arrs = {k: v[new] for k, v in key_arrays.items()}
+        leaves = {path: C.leaf_from_result_arrays(arrs, path, lt, len(new))
+                  for ci in kidx
+                  for path, lt in C.flatten_type(schema.types[ci], str(ci))}
+        mini = C.Partition(schema=schema, num_rows=len(new), leaves=leaves)
+        self.keys += C.decode_key_tuples(mini, range(len(new)), kidx)
+        self.rows = rows
+        self.order = sorted(range(len(self.keys)),
+                            key=lambda slot: rows[slot].tobytes())
+
+    def grow(self) -> None:
+        k_b = self.rows.shape[0]
+        if 2 * k_b > _TABLE_MAX_SLOTS:
+            self.live = False
+        else:
+            self.rows = C.pad_to(self.rows, 2 * k_b)
+
+
+def _key_sig_plan(arrays: dict, schema, kidx) -> Optional[tuple]:
+    """The pieces of a key signature as the staged arrays hold them, in
+    C.key_signature_matrix's order: (kind, leaf path, bytes, has valid)
+    per key column, or None where the device cannot build one (a tuple or
+    host-only column; a float on a device whose float64 is no IEEE binary64
+    and so has no bytes to compare). Reads keys, dtypes and shapes only."""
+    from ..runtime.jaxcfg import f64_is_f32_pair
+
+    plan = []
+    for ci in kidx:
+        leaves = C.flatten_type(schema.types[ci], str(ci))
+        if len(leaves) != 1:
+            return None
+        (path, lt), = leaves
+        valid = (path + "#valid") in arrays
+        if path in arrays:
+            a = arrays[path]
+            if np.ndim(a) != 1 or a.dtype.kind not in "biuf" \
+                    or (a.dtype.kind == "f" and f64_is_f32_pair()):
+                return None
+            plan.append(("num", path, a.dtype.itemsize, valid))
+        elif (path + "#bytes") in arrays:
+            plan.append(("str", path, arrays[path + "#bytes"].shape[1],
+                         valid))
+        elif not C.staged_keys_for_type(path, lt):
+            plan.append(("null", path, 1, False))    # layout-free
+        else:
+            return None
+    return tuple(plan) or None
+
+
+def _sig_width(plan: tuple) -> int:
+    """Bytes of one signature: each piece's own, its valid byte, and a
+    str's four length bytes."""
+    return sum(w + int(has_valid) + 4 * (kind == "str")
+               for kind, _path, w, has_valid in plan)
+
+
+def _device_key_signature(arrs: dict, plan: tuple):
+    """[1 + W, B] uint8 on the device, rows along the minor (lane)
+    dimension: a leading 1 (see _KeyTable), then byte for byte what
+    C.key_signature_matrix builds on the host (at the staged str widths),
+    transposed: equal signatures are equal python keys, and they sort as
+    the host's do, which is the order groups are emitted in."""
+    from ..runtime.jaxcfg import jnp, lax
+
+    b = arrs["#rowvalid"].shape[0]
+    pieces = [jnp.ones((1, b), jnp.uint8)]
+    for kind, path, w, has_valid in plan:
+        valid = arrs[path + "#valid"] if has_valid else None
+        if kind == "null":
+            pieces.append(jnp.zeros((1, b), jnp.uint8))
+            continue
+        if kind == "num":
+            x = arrs[path]
+            if valid is not None:
+                x = jnp.where(valid, x, jnp.zeros((), x.dtype))
+            if x.dtype.kind == "f":
+                # on the bits: a float compare would take a denormal for
+                # zero where the device flushes them, and numpy does not
+                x = lax.bitcast_convert_type(x, jnp.dtype(f"uint{8 * w}"))
+                x = jnp.where(x == jnp.asarray(1 << (8 * w - 1), x.dtype),
+                              jnp.zeros((), x.dtype), x)       # -0.0
+            pieces.append(_le_bytes(x, w))
+        else:
+            by, ln = arrs[path + "#bytes"].T, arrs[path + "#len"]
+            if valid is not None:
+                ln = jnp.where(valid, ln, 0)
+            live = jnp.arange(by.shape[0])[:, None] < ln[None, :]
+            pieces.append(jnp.where(live, by, jnp.uint8(0)))
+            pieces.append(_le_bytes(ln.astype(jnp.int32), 4))
+        if valid is not None:
+            pieces.append(valid.astype(jnp.uint8)[None, :])
+    return jnp.concatenate(pieces, axis=0)
+
+
+def _le_bytes(x, w: int):
+    """[B] bool or integers -> their [w, B] little-endian bytes, by shifts
+    (a device's emulated int64 has no byte view)."""
+    from ..runtime.jaxcfg import jnp
+
+    if w == 1:
+        return x.astype(jnp.uint8)[None, :]
+    shifts = (8 * jnp.arange(w)).astype(x.dtype)
+    return ((x[None, :] >> shifts[:, None]) & 0xFF).astype(jnp.uint8)
+
+
+def _table_take_new_keys(table, sig, ok):
+    """Fill the free slots of `table` [K_b, 1 + W] with the signatures of
+    the ok rows no slot holds, a key an iteration, each from the first row
+    that has it (so a partition without a new key iterates not once).
+    Returns (table, first [K_b]: that row for a slot taken here, else -1,
+    the ok rows still without a slot: the table is full)."""
+    from ..runtime.jaxcfg import jnp, lax
+
+    k_b = table.shape[0]
+
+    def holds(tab):
+        return jnp.any(jnp.all(tab[:, :, None] == sig[None, :, :], axis=1),
+                       axis=0)
+
+    def more(c):
+        _tab, _first, lacking, used = c
+        return (used < k_b) & jnp.any(lacking)
+
+    def take(c):
+        tab, first, lacking, used = c
+        row = jnp.argmax(lacking).astype(jnp.int32)
+        new = lax.dynamic_index_in_dim(sig, row, axis=1, keepdims=False)
+        tab = lax.dynamic_update_index_in_dim(tab, new, used, axis=0)
+        first = lax.dynamic_update_index_in_dim(first, row, used, axis=0)
+        lacking = lacking & ~jnp.all(sig == new[:, None], axis=0)
+        return tab, first, lacking, used + 1
+
+    used = jnp.sum(table[:, 0], dtype=jnp.int32)
+    tab, first, lacking, _ = lax.while_loop(
+        more, take,
+        (table, jnp.full((k_b,), -1, jnp.int32), ok & ~holds(table), used))
+    return tab, first, lacking
+
+
+def _make_bykey_fold(spec: A.FoldSpec, schema, kidx, nseg_b=None):
+    """The by-key fold of one staged partition as one traceable function:
+    fn(arrays, key) -> (small results, ok [B]), the small results being
+    (per reducer the [slots] partials in the expression's dtype, [slots]
+    counts of ok rows, ok rows no slot took, bad rows, ...).
+
+      nseg_b None: `key` is the key table (an ARGUMENT: no key is a
+        constant of the executable). The group of a row is the slot whose
+        signature its key columns give, free slots first taking the keys
+        the table lacks; every reduction is masked. Further small results:
+        the table as it is now, `first` and the key columns of the `first`
+        rows (see _KeyTable.learn).
+      nseg_b int: `key` is the host's code a row (>= nseg_b: no group);
+        every reduction is a segment reduction.
+    """
+    import jax
+
+    from ..parallel.collectives import _ident_arr
+    from ..runtime.jaxcfg import jnp
+
+    eval_exprs = _make_eval_exprs(spec, schema)
+    seg_reduce = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
+                  "max": jax.ops.segment_max}
+
+    def fn(arrays, key):
+        vals, ok = eval_exprs(arrays)
+        b = ok.shape[0]
+        vals = [jnp.broadcast_to(jnp.asarray(v), (b,)) for v in vals]
+        n_bad = jnp.sum(arrays["#rowvalid"] & ~ok, dtype=jnp.int32)
+        if nseg_b is not None:
+            partials = [
+                seg_reduce[red](jnp.where(ok, v, _ident_arr(red, v.dtype)),
+                                key, num_segments=nseg_b + 1)[:nseg_b]
+                for v, red in zip(vals, spec.reducers)]
+            counts = jax.ops.segment_sum(ok.astype(jnp.int32), key,
+                                         num_segments=nseg_b + 1)[:nseg_b]
+            return (tuple(partials), counts, jnp.zeros((), jnp.int32),
+                    n_bad), ok
+        plan = _key_sig_plan(arrays, schema, kidx)
+        sig = _device_key_signature(arrays, plan)              # [1+W, B]
+        table, first, lacking = _table_take_new_keys(key, sig, ok)
+        match = jnp.all(table[:, :, None] == sig[None, :, :], axis=1)
+        sel = match & ok[None, :]                              # [K_b, B]
+        partials = []
+        for v, red in zip(vals, spec.reducers):
+            m = jnp.where(sel, v[None, :], _ident_arr(red, v.dtype))
+            partials.append(getattr(m, red)(axis=1))
+        counts = jnp.sum(sel, axis=1, dtype=jnp.int32)
+        key_arrays = {
+            k: jnp.take(arrays[k], jnp.maximum(first, 0), axis=0)
+            for _kind, path, _w, _v in plan
+            for k in (path, path + "#bytes", path + "#len", path + "#valid")
+            if k in arrays}
+        return (tuple(partials), counts,
+                jnp.sum(lacking, dtype=jnp.int32), n_bad,
+                table, first, key_arrays), ok
+
+    return fn
 
 
 def _real_mask(part: C.Partition) -> np.ndarray:
