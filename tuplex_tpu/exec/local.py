@@ -47,6 +47,8 @@ def _get_outs(pending):
     if isinstance(pending, PackedOuts):
         return pending.to_host()      # notes its own d2h bytes
     with TR.span("d2h:leaf-fetch", "xfer") as _sp:
+        if _sp is not TR.NOOP:
+            _note_shards_fetched(_sp, pending)
         outs = jax.device_get(pending)
         try:
             vals = outs.values() if isinstance(outs, dict) else outs
@@ -58,6 +60,22 @@ def _get_outs(pending):
     return outs
 
 
+def _note_shards_fetched(sp, pending) -> None:
+    """`d2h:leaf-fetch` of row-sharded outputs (the mesh backend's): how
+    many devices the fetch gathers from and how many shards in all."""
+    vals = list(pending.values()) if isinstance(pending, dict) else pending
+    shards = devices = 0
+    for v in vals:
+        sh = getattr(v, "sharding", None)
+        if sh is None or len(sh.device_set) < 2:
+            continue
+        devices = max(devices, len(sh.device_set))
+        if not v.is_fully_replicated:
+            shards += len(v.addressable_shards)
+    if shards:
+        sp.set("shards", shards).set("devices", devices)
+
+
 def _cpu_device():
     """The host CPU device alongside an accelerator backend, or None."""
     import jax
@@ -66,6 +84,15 @@ def _cpu_device():
         return jax.local_devices(backend="cpu")[0]
     except Exception:
         return None
+
+
+def _host_cpu_beside_accelerator() -> bool:
+    """The default backend is an accelerator and the host CPU is a jax
+    device beside it: where a host-pinned executable (_CpuJit) is a
+    different place to run than the default one."""
+    import jax
+
+    return jax.default_backend() != "cpu" and _cpu_device() is not None
 
 
 class _CpuJit:
@@ -256,6 +283,12 @@ class LocalBackend:
     # batches across devices and keeps full-length outputs instead
     supports_compaction = True
     supports_fused_fold = True
+    # where `_jit_stage_fn` sends a batch (`resolve:general`'s `path`)
+    dispatch_path = "device"
+    # a small violation set may resolve on a host-CPU executable
+    # (_general_case_pass); a backend whose rows are not all in this
+    # process opts out
+    host_resolve = True
 
     def __init__(self, options):
         self.options = options
@@ -1189,8 +1222,7 @@ class LocalBackend:
                     # first-call trace failure re-enters here via
                     # _redispatch_plain and would otherwise double-count
                     # an upload that never happened
-                    leaf_h2d = sum(v.nbytes for v in batch.arrays.values()
-                                   if isinstance(v, np.ndarray))
+                    leaf_h2d = C.host_nbytes(batch.arrays)
                 _hsp.set("bytes", leaf_h2d)
             return self._dispatch_launch(part, device_fn, skey, use_comp,
                                          stage, packed, batch, t0,
@@ -1522,7 +1554,8 @@ class LocalBackend:
                 # and serve:slow-job all blame the same bucket
                 # (runtime/critpath)
                 self._general_case_pass(stage, part, fallback_idx, resolved,
-                                        device_codes, buffers=bufs)
+                                        device_codes, buffers=bufs,
+                                        span=_sp)
                 _sp.set("resolved", len(resolved))
             dt = time.perf_counter() - t0
             metrics["general_path_s"] = dt
@@ -1699,13 +1732,16 @@ class LocalBackend:
                            fallback_idx: set, resolved: dict,
                            device_codes: Optional[dict] = None,
                            local_jit: bool = False,
-                           buffers=None) -> None:
+                           buffers=None, span=TR.NOOP) -> None:
         """Compiled middle tier: re-run normal-case-violating rows through
         the stage fn traced under the GENERAL-CASE schema (Option/supertype
         widened decode). Rows it completes fold back like resolved python
         rows — but their compute stayed vectorized; only rows that STILL err
         reach the per-row interpreter (reference: StageBuilder.cc:1145
         generateResolveCodePath, ResolveTask.h resolve_f-before-interpreter).
+        `span` is the caller's `resolve:general`, which learns where the
+        batch ran (`path`), the rows it carried (`rows`, of those on offer),
+        its padded rows (`batch`) and `first_call`.
         """
         import jax
 
@@ -1737,14 +1773,19 @@ class LocalBackend:
             return
         # a small violation set on an accelerator backend resolves on the
         # HOST CPU executable instead: the fixed dispatch+transfer tax of
-        # the device round-trip (not measured on this machine) can dwarf
-        # the compute for a few thousand rows (reference contrast: resolve
-        # tasks share the driver's threads, ResolveTask.h:31-98)
+        # the device round-trip dwarfs the compute for a few thousand rows
+        # (on a four-chip v5e mesh 34.5 ms a partition at a 7,168-row
+        # batch, put and fetch leaf by leaf, against 15 ms on the host
+        # and 14 ms at 3,584; the routes meet at the option's default,
+        # 41 ms each at 16,384 rows: PERF.md section 6, PR 29; reference
+        # contrast: resolve tasks share the driver's threads,
+        # ResolveTask.h:31-98). Larger sets, and every set where the rows
+        # span processes (SPMD lockstep), take the backend's own dispatch
         host_resolve = (
-            not local_jit and type(self) is LocalBackend
+            not local_jit and self.host_resolve
             and len(cand) <= self.options.get_int(
                 "tuplex.tpu.hostResolveRows", 16384)
-            and jax.default_backend() != "cpu" and _cpu_device() is not None)
+            and _host_cpu_beside_accelerator())
         gckey = ("stagefn", gkey, "cpu") if host_resolve \
             else ("stagefn", gkey)
         try:
@@ -1767,13 +1808,33 @@ class LocalBackend:
         sub = C.gather_partition(part, np.arange(k, dtype=np.int64), idx, k)
         sub.fallback = {}
         sub.normal_mask = None
-        batch = C.stage_partition(sub, self.bucket_mode)
+        # the batch's shape follows from the partition's own staging and
+        # not from the rows that deviated: the string widths are the
+        # partition's (gather_partition keeps them), the rows pad to
+        # general_batch_size (padded rows carry #rowvalid=False), so a new
+        # file of the same distribution finds its executable stored
+        batch = C.stage_partition(
+            sub, self.bucket_mode, force_b=C.general_batch_size(
+                k, part.num_rows, self.bucket_mode))
         cache_key = gckey
         spec = batch.spec()
         first_call = not self.jit_cache.was_traced(cache_key, spec)
+        path = "host-cpu" if host_resolve \
+            else "device" if local_jit else self.dispatch_path
+        if span is not TR.NOOP:
+            # `rows`: what the batch carries, not the rows on offer
+            span.set("path", path).set("rows", k).set("batch", batch.b) \
+                .set("first_call", int(first_call))
         try:
             outs = gfn(batch.arrays)
             self.jit_cache.note_traced(cache_key, spec)
+            if not host_resolve and not isinstance(gfn, PackedStageFn):
+                # per-leaf staging uploads the tier's batch, as the fast
+                # path's in _dispatch_partition ("leaf_stage"); a packed
+                # dispatch notes its own single buffer and the host-CPU
+                # executable uploads nothing
+                xferstats.note_h2d(C.host_nbytes(batch.arrays),
+                                   tag="general_stage")
         except Exception as e:
             if not first_call:
                 raise

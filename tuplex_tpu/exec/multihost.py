@@ -36,6 +36,7 @@ class MultiHostBackend(LocalBackend):
     # out_specs don't carry; the mesh fold path (psum over ICI) handles
     # aggregation instead
     supports_fused_fold = False
+    dispatch_path = "mesh"
 
     def __init__(self, options):
         super().__init__(options)
@@ -46,6 +47,10 @@ class MultiHostBackend(LocalBackend):
         self.mesh = M.make_mesh(n)
         self.n_devices = n
         self._mesh_epoch = 0    # bumped on elastic shrink
+        # in one process a small violation set leaves the mesh for the
+        # host-CPU executable, as on one chip; across processes every
+        # dispatch stays SPMD
+        self.host_resolve = jax.process_count() == 1
         self.shard_layout: dict = {}    # see _note_shards
         # span streams key their pid lane by the HOST (jax process index)
         # so per-host dumps merge into one driver timeline without
@@ -320,7 +325,7 @@ class MultiHostBackend(LocalBackend):
                     _gsp.set("rows", len(fb_set)).set("tier", "host-local")
                     self._general_case_pass(stage, part, fb_set,
                                             resolved_local, device_codes=dc,
-                                            local_jit=True)
+                                            local_jit=True, span=_gsp)
             except Exception as e:
                 from ..utils.logging import get_logger
 

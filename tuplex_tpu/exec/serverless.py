@@ -353,6 +353,7 @@ class ServerlessBackend(LocalBackend):
     # writes its own part file from columnar buffers (reference: Lambda
     # tasks writing S3 output.part-N, AWSLambdaBackend.cc:410-430)
     supports_sink_pushdown = True
+    host_resolve = False
 
     def __init__(self, options):
         super().__init__(options)

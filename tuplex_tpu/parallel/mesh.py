@@ -14,6 +14,8 @@ from typing import Optional
 
 import numpy as np
 
+from ..runtime import tracing as TR
+from ..runtime.columns import host_nbytes
 from ..runtime.jaxcfg import jax, jnp
 
 DATA_AXIS = "data"
@@ -54,8 +56,6 @@ def shard_layout(x) -> list:
 def _named_mesh_fn(fn, raw_fn, tag: str):
     """`jit_tpx_mesh_<key8>`: the replicated-output wrapper of a stage fn,
     under the key8 its raw fn was named with (plan/physical)."""
-    from ..runtime import tracing as TR
-
     return TR.name_fn(fn, "mesh", TR.fn_key8(raw_fn, tag))
 
 
@@ -91,8 +91,16 @@ def shard_stage_fn(raw_fn, mesh, axis: str = DATA_AXIS, salt: str = "",
                       deadline=deadline)
 
         def sharded(arrays):
-            placed = {k: jax.device_put(v, shard if np.ndim(v) else repl)
-                      for k, v in arrays.items()}
+            # the row-sharded placement, leaf by leaf: the upload of a
+            # mesh dispatch (`xferstats` counts its bytes where the caller
+            # staged them: exec/local._dispatch_launch, _general_case_pass)
+            with TR.span("h2d:mesh-put", "xfer") as _sp:
+                placed = {k: jax.device_put(v, shard if np.ndim(v) else repl)
+                          for k, v in arrays.items()}
+                if _sp is not TR.NOOP:
+                    _sp.set("bytes", host_nbytes(arrays)) \
+                        .set("leaves", len(arrays)) \
+                        .set("devices", int(mesh.devices.size))
             outs = jfn(placed)
             if on_dispatch is not None:
                 on_dispatch(placed, outs)
@@ -199,10 +207,12 @@ def pad_batch_for_mesh(arrays: dict, n_devices: int) -> dict:
     if target == b:
         return arrays
     out = {}
-    for k, v in arrays.items():
-        if np.ndim(v) == 0:             # scalars (e.g. '#seed') replicate
-            out[k] = v
-            continue
-        pad = [(0, target - b)] + [(0, 0)] * (v.ndim - 1)
-        out[k] = np.pad(np.asarray(v), pad)
+    with TR.span("mesh:pad-batch", "xfer") as _sp:
+        _sp.set("rows", b).set("batch", target)
+        for k, v in arrays.items():
+            if np.ndim(v) == 0:         # scalars (e.g. '#seed') replicate
+                out[k] = v
+                continue
+            pad = [(0, target - b)] + [(0, 0)] * (v.ndim - 1)
+            out[k] = np.pad(np.asarray(v), pad)
     return out

@@ -564,6 +564,27 @@ def bucket_size(n: int, mode: str = "q8", minimum: int = 8) -> int:
     return ((n + q - 1) // q) * q
 
 
+def general_batch_size(k: int, part_rows: int, mode: str = "q8") -> int:
+    """Padded rows of the general tier's batch for `k` deviant rows of a
+    partition of `part_rows` rows: a function of the partition's OWN staged
+    bucket, never of how many rows deviated. The floor is 1/32 of that
+    bucket (3,584 rows for a 111,111-row Zillow partition, of which
+    1,402-1,572 reach the tier, 1.3-1.4%: a file twice as dirty still
+    meets the floor) and the size doubles from there up to the bucket
+    itself, so one partition size meets at most six shapes and a file of
+    the same distribution the floor alone. Sized by `bucket_size(k)`
+    instead, those rows landed on one to three q8 steps by the seed, each
+    another executable to load or, in a new file's first job, to compile
+    (PERF.md section 6, PR 29)."""
+    if mode == "exact" or k <= 0:
+        return max(k, 1)
+    b = bucket_size(part_rows, mode)
+    rows = max(8, b >> 5)
+    while rows < k:
+        rows <<= 1
+    return min(rows, b)
+
+
 def pad_to(arr: np.ndarray, n: int, axis: int = 0) -> np.ndarray:
     cur = arr.shape[axis]
     if cur >= n:
@@ -597,6 +618,12 @@ class DeviceBatch:
         return tuple(sorted(
             (k, v.shape, str(v.dtype)) for k, v in self.arrays.items()
         ))
+
+
+def host_nbytes(arrays: dict) -> int:
+    """Bytes of a staged batch that still live on the host: what a
+    per-leaf dispatch uploads (a leaf already on the device costs none)."""
+    return sum(v.nbytes for v in arrays.values() if isinstance(v, np.ndarray))
 
 
 def _leaf_keys(path: str, leaf):
