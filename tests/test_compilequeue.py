@@ -413,3 +413,254 @@ def test_compile_seconds_in_context_metrics(fresh_cache):
     assert "compile_s" in as_dict and "stage_compiles" in as_dict
     if ctx.metrics.stageCompileCount():
         assert total > 0
+
+
+# ---------------------------------------------------------------------------
+# one identity a stage, from job to job (counts and text, no timing)
+# ---------------------------------------------------------------------------
+
+def _mixed_csv(path, n=1500):
+    rows = ["x" + str(i) if i % 11 == 0 else str(i) for i in range(n)]
+    with open(path, "w") as f:
+        f.write("v,k\n" + "\n".join(f"{r},{i % 3}"
+                                   for i, r in enumerate(rows)) + "\n")
+    return str(path)
+
+
+def _stage_and_avals(ctx, path):
+    """Stage 0 of the mixed-column pipeline, built anew (new operators,
+    higher counter ids), with its dispatch avals."""
+    from tuplex_tpu.api.dataset import _source_partitions
+    from tuplex_tpu.compiler import stagefn as SF
+    from tuplex_tpu.plan.physical import plan_stages
+
+    ds = ctx.csv(path).map(lambda x: (len(str(x["v"])), x["k"] + 1))
+    st = plan_stages(ds._op, ctx.options_store)[0]
+    part = _source_partitions(ctx, st, lazy=False)[0]
+    return st, part.schema, SF.partition_avals(part, "q8")
+
+
+@pytest.mark.parametrize("general", [False, True],
+                         ids=["fast-tier", "general-tier"])
+def test_rebuilt_pipeline_traces_to_one_fingerprint(fresh_cache, tmp_path,
+                                                    general):
+    """The '#err' lattice names operators by position, so the same
+    pipeline built twice in one process — and rebuilt from its serialized
+    spec — is one jaxpr, one fingerprint, one stored executable."""
+    import jax
+
+    from tuplex_tpu.exec.serverless import rebuild_stage, serialize_stage
+
+    ctx = tuplex_tpu.Context()
+    path = _mixed_csv(tmp_path / "m.csv")
+    fps, ids = [], []
+    for _ in range(2):
+        st, schema, avals = _stage_and_avals(ctx, path)
+        rb = rebuild_stage(serialize_stage(st), ctx.options_store,
+                           files=list(st.source.files))
+        for s in (st, rb):
+            traced = jax.jit(s.build_device_fn(
+                schema, general=general)).trace(avals)
+            fps.append(CQ.fingerprint_traced(traced))
+            ids.append(tuple(op.id for op in s.ops))
+    assert len(set(ids)) == 4, ids      # four builds, four sets of ids
+    assert len(set(fps)) == 1, fps
+    ctx.close()
+
+
+def _q1_like(ctx, path):
+    def fold(a, x):
+        return (a[0] + x["v"], a[1] + 1)
+
+    return (ctx.csv(path).filter(lambda x: x["d"] <= "1998-06-01")
+            .aggregateByKey(lambda a, b: (a[0] + b[0], a[1] + b[1]),
+                            fold, (0, 0), ["k", "j"]))
+
+
+def test_closed_loop_compiles_its_stage_once(fresh_cache, tmp_path,
+                                             monkeypatch):
+    """q1's shape (filter + aggregateByKey, stage 0 built packed=False
+    under the device handoff), the pipeline rebuilt for every collect():
+    after the first job the compile plane does nothing at all."""
+    monkeypatch.setenv("TUPLEX_DEVICE_HANDOFF", "1")
+    monkeypatch.setenv("TUPLEX_COMPILE_ISOLATION", "thread")
+    path = str(tmp_path / "li.csv")
+    with open(path, "w") as f:
+        f.write("k,j,d,v\n")
+        for i in range(6000):
+            f.write(f"{'abc'[i % 3]},{'xy'[i % 2]},"
+                    f"1998-0{1 + i % 9}-01,{i}\n")
+    ctx = tuplex_tpu.Context()
+    s0 = CQ.snapshot()
+    want = sorted(_q1_like(ctx, path).collect())
+    assert len(want) == 6
+    s1 = CQ.snapshot()
+    for _ in range(4):
+        assert sorted(_q1_like(ctx, path).collect()) == want
+    later = CQ.delta(s1)
+    for k in ("compile_starts", "aot_misses", "traces",
+              "prewarm_submitted", "stage_compiles"):
+        assert later[k] == 0, (k, later)
+    assert later["prewarm_skipped"] >= 4, later
+    assert CQ.pending_info()["inflight"] == 0
+    whole = CQ.delta(s0)
+    assert whole["prewarm_used"] == whole["prewarm_submitted"], whole
+    ctx.close()
+
+
+def _inv(x):
+    return 1000 // x["a"]
+
+
+def test_second_jobs_exceptions_name_its_own_operators(fresh_cache):
+    """Job 2 of a Context runs job 1's executable (one stage key), and
+    its compiled-path exceptions (off the lattice) as well as its
+    interpreter-path ones carry job 2's operator ids; a resolver attached
+    in job 2 fires."""
+    ctx = tuplex_tpu.Context()
+    # 0 raises ZeroDivisionError on the device; the str rows are boxed
+    # at ingest and raise TypeError in the interpreter
+    data = [(i % 7,) if i % 50 else ("s",) for i in range(1, 3001)]
+
+    def build():
+        return ctx.parallelize(data, columns=["a"]).map(_inv)
+
+    seen = []
+    for _ in range(2):
+        snap = CQ.snapshot()
+        ds = build()
+        got = ds.collect()
+        assert len(got) == sum(1 for (a,) in data if a not in (0, "s"))
+        recs = ds._last_exceptions
+        names = {r.exc_name for r in recs}
+        assert names == {"ZeroDivisionError", "TypeError"}, names
+        assert {r.op_id for r in recs} == {ds._op.id}, \
+            ({r.op_id for r in recs}, ds._op.id)
+        seen.append((ds._op.id, CQ.delta(snap)["traces"]))
+    assert seen[0][0] != seen[1][0]
+    assert seen[1][1] == 0, seen        # job 2 traced nothing: one stage
+    for _ in range(2):
+        fixed = build().resolve(ZeroDivisionError, lambda x: -1)
+        out = fixed.collect()
+        assert out.count(-1) == sum(1 for (a,) in data if a == 0)
+        assert fixed.exception_counts() == {
+            "TypeError": sum(1 for (a,) in data if a == "s")}
+    ctx.close()
+
+
+def test_speculative_joiners_hold_no_pool_worker(fresh_cache, monkeypatch):
+    """Eight speculative submissions of one fingerprint whose compile is
+    in flight take no pool worker: a ninth, different compile starts at
+    once, and all eight settle with the owner."""
+    import threading
+
+    import jax
+    import numpy as np
+
+    monkeypatch.setenv("TUPLEX_COMPILE_ISOLATION", "thread")
+    gate, entered = threading.Event(), threading.Event()
+    real = CQ._compile_lowered
+
+    def held(lowered):
+        if "tpx_slow" in lowered.as_text()[:400]:
+            entered.set()
+            assert gate.wait(120)
+        return real(lowered)
+
+    monkeypatch.setattr(CQ, "_compile_lowered", held)
+
+    def tpx_slow(x):
+        return x * 3 + 1
+
+    def other(x):
+        return x - 7
+
+    aval = jax.ShapeDtypeStruct((64,), np.int64)
+    try:
+        owner = CQ.submit_compile(tpx_slow, (aval,), deadline_s=0)
+        assert entered.wait(120)
+        snap = CQ.snapshot()
+        joiners = [CQ.submit_compile(tpx_slow, (aval,), deadline_s=0,
+                                     prewarm=True) for _ in range(8)]
+        ninth = CQ.submit_compile(other, (aval,), deadline_s=0)
+        assert ninth.result(timeout=120) is not None
+        assert not owner.done() and not any(j.done() for j in joiners)
+        d = CQ.delta(snap)
+        assert d["prewarm_skipped"] == 8 and d["stage_compiles"] == 1, d
+    finally:
+        gate.set()
+    exe = owner.result(timeout=120)
+    assert all(j.result(timeout=120) is exe for j in joiners)
+
+
+def test_cold_plan_overlaps_stage_one_with_stage_zero(fresh_cache,
+                                                      monkeypatch):
+    """On a store that lacks stage 1, its compile is in flight (on the
+    pool) before stage 0's last partition is collected — and stage 0's
+    own executable is nobody's speculation."""
+    import threading
+
+    from tuplex_tpu.exec.local import LocalBackend
+
+    monkeypatch.setenv("TUPLEX_COMPILE_ISOLATION", "thread")
+    events: list = []
+    real = CQ._compile_lowered
+
+    def slow(lowered):
+        events.append(("compile", threading.current_thread().name))
+        time.sleep(0.3)
+        return real(lowered)
+
+    monkeypatch.setattr(CQ, "_compile_lowered", slow)
+    real_collect = LocalBackend._collect_partition
+
+    def collect(self, stage, part, *a, **kw):
+        out = real_collect(self, stage, part, *a, **kw)
+        events.append(("collect", stage.source is not None))
+        return out
+
+    monkeypatch.setattr(LocalBackend, "_collect_partition", collect)
+    ctx = tuplex_tpu.Context({"tuplex.tpu.maxStageOps": 1,
+                              "tuplex.partitionSize": "16KB",
+                              "tuplex.tpu.compileDeadlineS": 0})
+    data = list(range(8192))
+    snap = CQ.snapshot()
+    assert ctx.parallelize(data).map(m1).map(m2).collect() \
+        == [m2(m1(x)) for x in data]
+    last0 = max(i for i, e in enumerate(events) if e == ("collect", True))
+    assert sum(1 for e in events[:last0] if e == ("collect", True)) >= 1
+    pool = [i for i, e in enumerate(events)
+            if e[0] == "compile" and e[1].startswith("tpx-compile-")]
+    assert pool and pool[0] < last0, events
+    d = CQ.delta(snap)
+    assert d["prewarm_submitted"] >= 1, d
+    assert d["prewarm_used"] == d["prewarm_submitted"], d
+    ctx.close()
+
+
+def test_driver_asks_the_backend_before_it_submits(fresh_cache):
+    """What dispatch already traced (same build key, same batch spec) is
+    nobody's to speculate: with the pool off for the job itself, a walk
+    of the rebuilt plan afterwards finds every stage traced, stage 1 at
+    the avals it predicts from stage 0's, and submits nothing."""
+    from tuplex_tpu.api.dataset import _source_partitions
+    from tuplex_tpu.plan.physical import plan_stages
+
+    ctx = tuplex_tpu.Context({"tuplex.tpu.maxStageOps": 1,
+                              "tuplex.tpu.parallelCompile": False})
+    data = list(range(5000))
+
+    def build():
+        return ctx.parallelize(data).map(m1).map(m2)
+
+    assert build().collect() == [m2(m1(x)) for x in data]
+    stages = plan_stages(build()._op, ctx.options_store)
+    assert len(stages) == 2
+    parts = _source_partitions(ctx, stages[0], lazy=False)
+    snap = CQ.snapshot()
+    assert ctx.backend._precompile_driver(stages, parts) == []
+    d = CQ.delta(snap)
+    assert d["prewarm_submitted"] == 0 and d["pool_jobs"] == 0, d
+    # both stages, once for each distinct bucket of the partitions
+    assert d["prewarm_skipped"] >= 2 and d["prewarm_skipped"] % 2 == 0, d
+    ctx.close()

@@ -414,7 +414,9 @@ def test_precompile_driver_covers_packed_stages(tmp_path, monkeypatch):
           .map(lambda x: (x["a"] + 1, x["s"])))
     st = plan_stages(ds._op, c.options_store)[0]
     parts = _source_partitions(c, st, lazy=False)
-    futs = c.backend._precompile_driver([st], parts[0])
+    # every distinct bucket of the partitions in hand: the short tail
+    # stages into a smaller wire buffer than the four full partitions
+    futs = c.backend._precompile_driver([st], parts)
     assert futs, "no prewarm future submitted for the packed stage"
     for f in futs:
         f.result(timeout=300)

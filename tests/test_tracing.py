@@ -661,13 +661,17 @@ def test_lazy_source_reads_on_the_prefetch_thread_name_the_job(
 
 
 def test_pool_compile_names_the_job_that_submitted_it(
-        ctx, trace_on, tmp_path):
+        trace_on, tmp_path):
+    import tuplex_tpu
     from tuplex_tpu.exec import compilequeue as CQ
 
     p = str(tmp_path / "in.csv")
     _write_csv(p, 1500)
     s0 = CQ.snapshot()
-    ctx.csv(p).map(lambda x: x["a"] * 7 + 3).collect()
+    # three stages (decode, map, map): stage 0 is left to its own
+    # dispatch, the later ones are the pool's to compile while it runs
+    ctx = tuplex_tpu.Context({"tuplex.tpu.maxStageOps": 1})
+    ctx.csv(p).map(lambda x: x["a"] * 7 + 3).map(lambda v: v - 11).collect()
     deadline = time.time() + 120          # the speculative compile's spans
     while time.time() < deadline:         # close on the pool, after the job
         evs = tracing.events()
@@ -679,13 +683,14 @@ def test_pool_compile_names_the_job_that_submitted_it(
     (job,) = [e for e in evs if e["name"] == "job"]
     (pre,) = [e for e in evs if e["name"] == "compile:precompile-plan"]
     assert pre["parent"] == job["id"]
-    assert pre["args"] == {"stages": 1, "submitted": 1}
+    # one chain (one bucket) went to the pool: stage 1 is not traced yet
+    assert pre["args"] == {"stages": 3, "submitted": 1, "skipped": 0}
     assert pool, "no compile:trace span recorded on the pool"
     for e in pool:
         assert e["tid"] != job["tid"]
         assert e["parent"] == pre["id"] and e["job"] == job["id"]
     d = CQ.delta(s0)
-    assert d["prewarm_submitted"] >= 1
+    assert d["prewarm_submitted"] == 2 and d["prewarm_skipped"] == 1
     assert 0 <= d["prewarm_used"] <= d["prewarm_submitted"]
     assert d["compile_starts"] >= d["stage_compiles"]
     assert d["compile_starts"] - d["stage_compiles"] \

@@ -366,19 +366,19 @@ class DataSet:
                         partitions = _source_partitions(
                             self._context, stage, lazy=lazy)
                         if si == 0 and not lazy:
-                            # ahead-of-time compile of the WHOLE plan on
-                            # the pool: stage i+1's (predicted-spec)
-                            # compile overlaps stage i's execution
-                            # (exec/compilequeue; remote XLA compiles are
-                            # minutes, not the reference's milliseconds)
+                            # ahead-of-time compile of the plan's later
+                            # stages on the pool: stage i+1's
+                            # (predicted-spec) compile overlaps stage i's
+                            # execution (exec/compilequeue); the backend
+                            # sets `submitted` and `skipped` where its
+                            # driver ran (neither: it never did)
                             pre = getattr(backend, "precompile_plan", None)
                             if pre is not None:
                                 with TR.span("compile:precompile-plan",
                                              "compile") as _psp:
                                     _psp.set("stages", len(stages))
                                     try:
-                                        _psp.set("submitted", int(bool(
-                                            pre(stages, partitions))))
+                                        pre(stages, partitions, span=_psp)
                                     except Exception:
                                         pass
                     # device handoff: tell the backend WHO consumes this

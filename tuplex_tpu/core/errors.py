@@ -101,12 +101,16 @@ def code_for_name(name: str):
     return ExceptionCode.__members__.get(name.upper()) if name else None
 
 
-# Packed device-lattice layout: exception-class code in the low byte,
-# logical-operator id above it. One int32 per row carries both — a second
-# per-row operator lattice measured a 20x kLoop recompute pathology on
-# XLA-CPU. Operator ids are process-global and unbounded; ids that would
-# overflow the 23 bits left in an int32 pack as 0 ("unknown operator") —
-# attribution degrades, correctness (the class code) never does.
+# Packed device-lattice layout: exception-class code in the low byte, the
+# operator above it. One int32 per row carries both — a second per-row
+# operator lattice measured a 20x kLoop recompute pathology on XLA-CPU.
+# On the device the operator is its 1-based POSITION in its stage (a
+# stage's jaxpr must not depend on the session's operator counter); the
+# host maps it onto the current job's `op.id` as the lattice is fetched
+# (plan/physical.TransformStage.op_ids_of_lattice), in the same layout. A
+# value that would overflow the 23 bits left in an int32 packs as 0
+# ("unknown operator") — attribution degrades, correctness (the class
+# code) never does.
 _OP_ID_LIMIT = 1 << 23
 
 

@@ -74,7 +74,12 @@ STATS: dict[str, Any] = {
     # (in process or in a child); stage_compiles counts the ends that
     # succeeded, compile_failures the ones that raised, were killed or
     # handed nothing back — starts less both is what is still running.
-    "prewarm_submitted": 0, "prewarm_used": 0,
+    # prewarm_skipped = speculative submissions DROPPED: by the driver
+    # on asking (the backend already traced that stage at those avals,
+    # or the driver speculated it earlier in this process: nothing is
+    # traced or queued), or by the pool after its trace (the fingerprint
+    # is held or pending: no worker waits for it).
+    "prewarm_submitted": 0, "prewarm_used": 0, "prewarm_skipped": 0,
     "compile_starts": 0, "compile_failures": 0,
     # pre-submission jaxpr vetting (compiler/graphlint): hazards_found =
     # fresh vetoes from a live analysis, hazards_avoided = every compile
@@ -172,7 +177,15 @@ class _DaemonPool:
                 TR.set_stream(stream)
             try:
                 with TR.adopt(cause):
-                    fut.set_result(fn(*args, **kwargs))
+                    res = fn(*args, **kwargs)
+                if isinstance(res, Future):
+                    # the job found its work in someone else's hands (a
+                    # speculative compile whose fingerprint is pending):
+                    # its future follows theirs and this worker is free
+                    res.add_done_callback(
+                        lambda done, fut=fut: _follow(done, fut))
+                else:
+                    fut.set_result(res)
             except BaseException as e:  # noqa: BLE001 - future carries it
                 fut.set_exception(e)
             finally:
@@ -184,6 +197,15 @@ class _DaemonPool:
         self._q.put((fut, fn, args, kwargs, TR.current_stream(),
                      TR.handoff()))
         return fut
+
+
+def _follow(done: Future, fut: Future) -> None:
+    """Settle `fut` as `done` was settled."""
+    e = done.exception()
+    if e is not None:
+        fut.set_exception(e)
+    else:
+        fut.set_result(done.result())
 
 
 def snapshot() -> dict:
@@ -1114,6 +1136,13 @@ def default_deadline_s() -> float:
         return 0.0
 
 
+def note_prewarm_skipped(n: int = 1) -> None:
+    """`n` speculative submissions were dropped (STATS, above)."""
+    if n:
+        with _LOCK:
+            STATS["prewarm_skipped"] += n
+
+
 def _prewarm_owns(fp: str) -> None:
     """A prewarm came to OWN this fingerprint: it will load or compile
     it."""
@@ -1141,7 +1170,9 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
     compiled executable for it, via — in order — the in-process fingerprint
     store, the on-disk AOT artifact cache, or an actual XLA compile (counted,
     timed, tuner-fed, persisted to disk). `prewarm` marks a speculative
-    call (the precompile driver's): see `_lookup_satisfied`.
+    call (the precompile driver's): see `_lookup_satisfied`. Such a call
+    never waits for a compile in flight: it returns the pending Future
+    itself, which the pool lets its own future follow.
 
     Trace-time exceptions (NotCompilable, emitter rejections) propagate to
     the caller exactly as they would from ``jax.jit(fn)(args)`` — the local
@@ -1199,6 +1230,10 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
                     _PENDING[fp] = fut
                     _PENDING_T[fp] = time.monotonic()
                     break
+        if prewarm and (cached is not None or fut is not None):
+            note_prewarm_skipped()      # held or pending: nothing to do
+            if cached is None:
+                return fut              # only a dispatch blocks on it
         if cached is not None:
             _lookup_satisfied(fp, prewarm)
             xferstats.bump("cache_hits", 1, tag="dedup")
@@ -1547,6 +1582,14 @@ class AotJit:
                 name = "jit_" + getattr(self._fn, "__name__", "")
             self._modnames[id(entry)] = name
         return name
+
+    def warm(self, *avals) -> Future:
+        """Queue this fn's compile for `avals` on the pool, speculatively,
+        under the very arguments a call would compile it with."""
+        return submit_compile(
+            self._fn, avals, donate_argnums=self._donate, salt=self._salt,
+            tag=self._tag, n_ops=self._n_ops, deadline_s=self._deadline,
+            prewarm=True)
 
     def _plain(self):
         if self._jit is None:

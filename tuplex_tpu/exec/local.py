@@ -302,6 +302,10 @@ class LocalBackend:
         # stages whose per-boundary dispatch cost was already sampled into
         # the split-tuner model (one clean sample per stage)
         self._boundary_sampled: set[str] = set()
+        # what the precompile driver walked in this process: (build-cache
+        # key, batch spec) -> the next stage's predicted avals, None where
+        # the chain stops (_speculate)
+        self._speculated: dict = {}
         from ..runtime.spill import MemoryManager
 
         self.mm = MemoryManager(
@@ -354,18 +358,54 @@ class LocalBackend:
                        tag=tag, n_ops=n_ops, deadline=deadline)
 
     # ------------------------------------------------------------------
-    def precompile_plan(self, stages, partitions):
-        """Kick off ahead-of-time compilation of the whole plan on the
-        compile pool (exec/compilequeue); returns the driver's Future, or
-        None where nothing was submitted. Speculative and asynchronous:
-        stage avals are PREDICTED by chaining abstract shape evaluation
-        from the first source partition, so stage i+1 (and i+2, ...)
-        compiles while stage i executes; a wrong prediction only wastes a
-        background compile — dispatch always verifies by content address.
-        The reference compiles a stage in the milliseconds before its first
-        task (LocalBackend.cc:865); remote XLA compiles are minutes, so
-        here the plan's compiles must all be in flight before stage 0's
-        first batch lands."""
+    def _stage_build_args(self, stage, in_schema, intermediate):
+        """(skey, use_comp, packed): which variant of `stage`'s fast-path
+        fn a dispatch builds and keeps its bookkeeping under. One
+        definition for `_run_stage_tier` and the precompile driver: a
+        speculative compile is only worth its seconds where it is for the
+        very function, under the very key, that dispatch looks up.
+        `intermediate` is False or the consumer kind (execute_any)."""
+        skey = stage.key() + "/" + (in_schema.name if in_schema else "") \
+            + self.fn_cache_salt()
+        use_comp = (self.supports_compaction
+                    and self.options.get_bool(
+                        "tuplex.tpu.filterCompaction", True)
+                    and stage.key() not in self._compaction_off)
+        # intermediate stages keep per-leaf dict outputs so the device-
+        # resident handoff can gather from them; every other stage packs
+        # its transfers into one buffer per direction. `intermediate` is
+        # False or the consumer kind ("stage"/"join"/"agg" — round 5 only
+        # plain stages qualified; joins and aggregates round-tripped every
+        # boundary, VERDICT §2)
+        packed = True
+        if intermediate:
+            from ..runtime.jaxcfg import device_handoff_enabled
+
+            packed = not device_handoff_enabled(
+                intermediate if isinstance(intermediate, str) else "stage")
+        return skey, use_comp, packed
+
+    def precompile_plan(self, stages, partitions, span=TR.NOOP):
+        """Ahead-of-time compilation of the plan's LATER stages on the
+        compile pool (exec/compilequeue), so stage i+1 compiles while
+        stage i runs; returns the driver's Future, or None where nothing
+        went to the pool. Speculative: stage avals are PREDICTED by
+        chaining abstract shape evaluation from the source partitions in
+        hand (one chain a distinct bucket: the short tail too), and
+        dispatch verifies by content address, so a wrong prediction only
+        wastes a background compile. Stage 0 is left to its own dispatch,
+        which follows this call at once and loads or compiles the same
+        executable itself. The reference compiles a stage in the
+        milliseconds before its first task (LocalBackend.cc:865); an XLA
+        compile is seconds to minutes, which is what the overlap is for.
+
+        The driver ASKS before it traces (`_speculate`): a stage this
+        backend already traced at those avals, or speculated earlier in
+        this process, costs the pool nothing, so from its second job on a
+        closed loop of one plan submits nothing. `span` (the caller's
+        `compile:precompile-plan`) learns `submitted`, the chains handed
+        to the pool, and `skipped`, the speculative submissions dropped
+        on asking."""
         from . import compilequeue as CQ
 
         if type(self) is not LocalBackend:
@@ -374,103 +414,156 @@ class LocalBackend:
                 or not self.options.get_bool(
                     "tuplex.tpu.parallelCompile", True):
             return None
-        first = partitions[0] if isinstance(partitions, list) \
-            and partitions else None
-        if first is None:
+        if not (isinstance(partitions, list) and partitions):
+            return None
+        try:
+            batches = self._distinct_batches(partitions)
+        except Exception:
+            return None
+        todo, skipped = [], 0
+        for avals, schema in batches:
+            _, n, answered = self._speculate(stages, avals, schema,
+                                             own_dispatch=True,
+                                             ask_only=True)
+            if answered:
+                skipped += n
+            else:
+                todo.append((avals, schema))
+        CQ.note_prewarm_skipped(skipped)
+        span.set("submitted", len(todo)).set("skipped", skipped)
+        if not todo:
             return None
         # the pool hands the submitting span over (TR.handoff/adopt): the
         # driver's compiles name `compile:precompile-plan` and its job
         return CQ.pool().submit(self._precompile_driver, list(stages),
-                                first)
+                                todo, True)
 
-    def _precompile_driver(self, stages, first_part):
-        """Walk the plan predicting each stage's dispatch avals and submit
-        pool compiles. Returns the submitted futures (tests drive this
-        synchronously). Prediction stops where shapes become
-        data-dependent: pipeline breakers, filters/limits (output row
-        count), compacted outputs, host-repacked wire layouts."""
+    def _distinct_batches(self, partitions) -> list:
+        """(avals, schema) of each distinct dispatch batch among
+        `partitions`, in order: the full bucket and the short tail's."""
         from ..compiler import stagefn as SF
 
-        try:
-            avals = SF.partition_avals(first_part, self.bucket_mode)
-            schema = first_part.schema
-        except Exception:
-            return []
-        return self._precompile_avals(stages, avals, schema)
+        seen: dict = {}
+        for part in partitions:
+            avals = SF.partition_avals(part, self.bucket_mode)
+            if avals is not None:
+                seen.setdefault(_avals_spec(avals), (avals, part.schema))
+        return list(seen.values())
+
+    def _precompile_driver(self, stages, batches, own_dispatch=False):
+        """The pool's half of `precompile_plan`: walk the plan from each
+        of `batches` ((avals, schema) pairs; tests hand in partitions, or
+        one), tracing where it must, and submit pool compiles. Returns
+        the submitted futures (tests drive this synchronously, stage 0
+        included)."""
+        from . import compilequeue as CQ
+
+        if not isinstance(batches, list):
+            batches = [batches]
+        if batches and not isinstance(batches[0], tuple):
+            try:
+                batches = self._distinct_batches(batches)
+            except Exception:
+                return []
+        futs: list = []
+        for avals, schema in batches:
+            f, skipped, _ = self._speculate(stages, avals, schema,
+                                            own_dispatch=own_dispatch)
+            CQ.note_prewarm_skipped(skipped)
+            futs.extend(f)
+        return futs
 
     def _precompile_avals(self, stages, avals, schema):
-        """The aval-driven half of :meth:`_precompile_driver`, callable
-        without a live partition: the respecialization controller
-        (serve/respec) stores each tenant's stage-0 dispatch avals and
-        replays them here — inside a compilequeue ``background_lane()``
-        — to compile a candidate stage set ahead of its canary with zero
-        foreground partitions in hand."""
+        """`_precompile_driver` without a live partition: the
+        respecialization controller (serve/respec) stores each tenant's
+        stage-0 dispatch avals and replays them here — inside a
+        compilequeue ``background_lane()`` — to compile a candidate stage
+        set ahead of its canary with zero foreground partitions in hand
+        (so stage 0 is speculated too: no dispatch is about to)."""
+        return self._precompile_driver(stages, [(avals, schema)])
+
+    def _speculate(self, stages, avals, schema, own_dispatch=False,
+                   ask_only=False):
+        """Walk the plan from one stage-0 batch (`avals`), predicting each
+        stage's dispatch avals, and submit a pool compile for every stage
+        nobody holds yet. Returns (futures, skipped, answered).
+
+        Asked first, for each stage: did this backend's dispatch already
+        trace that function at those avals (`jit_cache.was_traced`), or
+        did an earlier walk of this backend speculate it
+        (`_speculated`, which also remembers the next stage's avals)? Then
+        nothing is built, traced or queued for it: `skipped` counts it,
+        as it counts stage 0 under `own_dispatch` (its dispatch follows
+        at once, so it is walked for its output avals only).
+        `ask_only` stops at the first stage that would need a trace
+        (`answered` False: the walk belongs on the pool); otherwise the
+        stage is built with the arguments dispatch builds it with
+        (`_stage_build_args`, `_jit_stage_fn`) and its own `warm` queues
+        the compile.
+
+        Prediction stops where shapes become data-dependent: pipeline
+        breakers, filters/limits (output row count), compacted outputs,
+        host-repacked wire layouts."""
         from ..compiler import stagefn as SF
         from ..plan import logical as L
         from ..plan.physical import TransformStage, consumer_kind
-        from ..runtime.jaxcfg import (device_handoff_enabled,
-                                      donation_enabled, jax)
-        from ..runtime.packing import packing_enabled
-        from . import compilequeue as CQ
+        from ..runtime.jaxcfg import jax
 
         futs: list = []
-        donate = donation_enabled() and self.options.get_bool(
-            "tuplex.tpu.donateBuffers", True)
+        skipped = 0
         for si, stage in enumerate(stages):
             if avals is None or not isinstance(stage, TransformStage) \
                     or stage.force_interpret \
                     or getattr(stage, "cpu_compile", False):
                 break
-            skey = stage.key() + "/" + schema.name + self.fn_cache_salt()
+            skey, use_comp, packed = self._stage_build_args(
+                stage, schema, consumer_kind(stages, si))
             if skey in self._not_compilable:
                 break
-            use_comp = (self.supports_compaction
-                        and self.options.get_bool(
-                            "tuplex.tpu.filterCompaction", True)
-                        and stage.key() not in self._compaction_off)
-            consumer = consumer_kind(stages, si)
-            packed = True
-            if consumer:
-                packed = not device_handoff_enabled(consumer)
-            try:
-                raw = stage.build_device_fn(
-                    schema, compaction=use_comp,
-                    fused_fold=self.supports_fused_fold)
-                out = jax.eval_shape(raw, avals)
-            except Exception:
-                break
-            deadline = self.options.get_float(
-                "tuplex.tpu.compileDeadlineS", 0.0)
-            if packed and type(self) is LocalBackend and packing_enabled():
-                # packed-wire stage: the dispatched fn is the wire-layout
-                # closure, not `raw` — predict its buffer spec from the
-                # leaf avals (PackedStageFn.warm) so the packed executable
-                # prewarms in the AOT cache instead of compiling at first
-                # dispatch (ROADMAP compile-hardening item d)
-                try:
-                    pfn = PackedStageFn(raw, donate, tag=stage.key(),
-                                        n_ops=len(stage.ops),
-                                        deadline=deadline)
-                    f = pfn.warm(avals)
-                    if f is not None:
-                        futs.append(f)
-                except Exception:   # prewarm is speculative by contract
-                    pass
-            else:
-                futs.append(CQ.submit_compile(
-                    raw, (avals,), donate_argnums=(0,) if donate else (),
-                    salt=self.fn_cache_salt(), tag=stage.key(),
-                    n_ops=len(stage.ops), deadline_s=deadline,
-                    prewarm=True))
-            if stage.limit >= 0 or any(
-                    isinstance(op, L.FilterOperator) for op in stage.ops):
-                break        # output row count is data-dependent
-            avals = SF.restage_avals(out, self.bucket_mode)
             nxt = stages[si + 1] if si + 1 < len(stages) else None
-            if not isinstance(nxt, TransformStage):
+            # whether the next stage's avals follow from this one's
+            chains_on = isinstance(nxt, TransformStage) \
+                and stage.limit < 0 and not any(
+                    isinstance(op, L.FilterOperator) for op in stage.ops)
+            cache_key = ("stagefn", skey, use_comp, packed)
+            memo_key = (cache_key, _avals_spec(avals))
+            known = memo_key in self._speculated
+            # nothing to submit: stage 0's own dispatch is about to load or
+            # compile this, or dispatch traced it, or a walk speculated it
+            drop = (own_dispatch and si == 0) or known \
+                or self.jit_cache.was_traced(*memo_key)
+            skipped += drop
+            if known:
+                nxt_avals = self._speculated[memo_key]
+            elif drop and not chains_on:
+                break                   # and nothing to learn by tracing it
+            elif ask_only:
+                return futs, skipped, False
+            else:
+                try:
+                    raw = stage.build_device_fn(
+                        schema, compaction=use_comp,
+                        fused_fold=self.supports_fused_fold)
+                    out = jax.eval_shape(raw, avals)
+                except Exception:
+                    break
+                if not drop:
+                    try:
+                        warm = getattr(self._jit_stage_fn(
+                            raw, packed=packed, tag=stage.key(),
+                            n_ops=len(stage.ops)), "warm", None)
+                        f = warm(avals) if warm is not None else None
+                        if f is not None:
+                            futs.append(f)
+                    except Exception:   # prewarm is speculative by contract
+                        pass
+                nxt_avals = SF.restage_avals(out, self.bucket_mode) \
+                    if chains_on else None
+                self._speculated[memo_key] = nxt_avals
+            if not chains_on:
                 break
-            schema = nxt.input_schema
-        return futs
+            avals, schema = nxt_avals, nxt.input_schema
+        return futs, skipped, True
 
     # ------------------------------------------------------------------
     def execute_any(self, stage, partitions, context,
@@ -665,24 +758,9 @@ class LocalBackend:
             EX.capture_baseline(stage)
         device_fn = None
         in_schema = first_part.schema if first_part is not None else None
-        skey = stage.key() + "/" + (in_schema.name if in_schema else "") \
-            + self.fn_cache_salt()
-        use_comp = (self.supports_compaction
-                    and self.options.get_bool(
-                        "tuplex.tpu.filterCompaction", True)
-                    and stage.key() not in self._compaction_off)
-        # intermediate stages keep per-leaf dict outputs so the device-
-        # resident handoff can gather from them; every other stage packs
-        # its transfers into one buffer per direction. `intermediate` is
-        # False or the consumer kind ("stage"/"join"/"agg" — round 5 only
-        # plain stages qualified; joins and aggregates round-tripped every
-        # boundary, VERDICT §2)
+        skey, use_comp, packed = self._stage_build_args(stage, in_schema,
+                                                        intermediate)
         consumer = intermediate if isinstance(intermediate, str) else "stage"
-        packed = True
-        if intermediate:
-            from ..runtime.jaxcfg import device_handoff_enabled as _dh
-
-            packed = not _dh(consumer)
         if tier != "interpreter" and not self.interpret_only \
                 and skey not in self._not_compilable \
                 and in_schema is not None:
@@ -1503,12 +1581,14 @@ class LocalBackend:
             err_rows = rowvalid & (err != 0)
             err_idx = np.nonzero(err_rows)[0]
             fallback_idx.update(err_idx.tolist())
-            # packed lattice value: class code | operator id << 8. Read by
+            # packed lattice value: class code | operator << 8, the
+            # operator as its position in the stage on the device and as
+            # THIS job's operator id from here on. Read by
             # the no-resolver exact exit below AND the general-tier gate: a
             # row whose fast-path code is already an exact Python class
             # decoded fine under the normal case — the general re-run cannot
             # change its outcome, so it skips that tier either way.
-            codes = err[err_idx]
+            codes = stage.op_ids_of_lattice(err[err_idx])
             device_codes.update(
                 zip(err_idx.tolist(), unpack_device_codes(codes)))
             bufs.add_many(err_idx, codes)
@@ -1853,7 +1933,7 @@ class LocalBackend:
             # the general tier's verdict supersedes the fast path's: its
             # supertype decode removes normal-case artifacts
             bad_j = np.nonzero(~ok)[0]
-            codes = err[bad_j]
+            codes = stage.op_ids_of_lattice(err[bad_j])
             device_codes.update(
                 zip(idx[bad_j].tolist(), unpack_device_codes(codes)))
         if not ok.any():
@@ -1982,6 +2062,13 @@ class LocalBackend:
             outp.normal_mask = normal_mask
             outp.fallback = fallback
         return outp
+
+
+def _avals_spec(avals: dict) -> tuple:
+    """`Batch.spec()` of the batch these avals describe (JitCache's
+    traced-spec bookkeeping)."""
+    return tuple(sorted((k, tuple(v.shape), str(v.dtype))
+                        for k, v in avals.items()))
 
 
 def _prefetch_iter(it, depth: int):

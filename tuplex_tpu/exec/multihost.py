@@ -317,7 +317,8 @@ class MultiHostBackend(LocalBackend):
             if err is not None:
                 import numpy as _np
 
-                codes = _np.asarray(err)[_np.asarray(local_fb) + lo]
+                codes = stage.op_ids_of_lattice(
+                    _np.asarray(err)[_np.asarray(local_fb) + lo])
                 dc = dict(zip(local_fb, unpack_device_codes(codes)))
             t1 = time.perf_counter()
             try:

@@ -296,15 +296,10 @@ def rebuild_stage(spec: dict, options, files: Optional[list] = None):
         # re-speculate (different file subsets could sniff differently)
         op._schema_cache = schema          # UDFOperator slot
         op._schema = schema                # structural-op convention
-        # DETERMINISTIC stage-local ids: the emitter bakes `code |
-        # op_id << 8` literals into the kernel lattice, so ids from the
-        # session-global counter would give every rebuilt job a unique
-        # jaxpr and defeat the content-addressed executable dedup the
-        # job service depends on (N isomorphic tenants ~ 1 compile set).
-        # Ids only need to be unique WITHIN the stage: resolver matching
-        # and the python pipeline are positional, and nothing maps ids
-        # globally back to operators on the rebuild side.
-        op.id = i + 1
+        # ids stay the session counter's: the kernel's error lattice
+        # names an operator by its position in the stage
+        # (TransformStage.build_device_fn), so a rebuilt job traces to
+        # the jaxpr, and the stored executable, of every isomorphic one
         ops.append(op)
         parent = op
 

@@ -425,6 +425,23 @@ class TransformStage:
             pipe = cache[key] = build_python_pipeline(self.ops, key)
         return pipe
 
+    def op_ids_of_lattice(self, packed):
+        """Host side of the '#err' lattice. The device packs `class |
+        position << 8` (build_device_fn: an operator's 1-based position
+        in `self.ops`); this returns `class | op.id << 8` over THIS
+        stage's operators, which is what ResolveBuffers, excprof and an
+        ExceptionRecord read — so an executable traced for an earlier
+        job's identical stage reports under the current job's operator
+        ids. Position 0, or one past the stage, stays 0 (unknown
+        operator). int64: an operator id is unbounded on the host."""
+        import numpy as np
+
+        table = np.array([0] + [op.id for op in self.ops], dtype=np.int64)
+        packed = np.asarray(packed).astype(np.int64)
+        pos = packed >> 8
+        pos[(pos < 0) | (pos >= len(table))] = 0
+        return (packed & 0xFF) | (table[pos] << 8)
+
     def key(self) -> str:
         """Cache key for the jit'd executable: operator chain + UDF sources +
         captured globals + input schema (specialization contract of the
@@ -523,12 +540,18 @@ class TransformStage:
             from ..runtime.columns import user_columns
 
             names = user_columns(schema)
+            # the '#err' lattice names an operator by its 1-based position
+            # in `self.ops`, never by `op.id` (the session's counter): the
+            # jaxpr of a stage is then the same text in every job, process
+            # and path, and the host maps positions back onto the CURRENT
+            # job's operators (`op_ids_of_lattice`)
+            position = {id(op): i + 1 for i, op in enumerate(self.ops)}
             rowidx = None          # [B'] original positions after compaction
             full_err = None        # [b] error codes incl. compacted-away rows
             overflow = None
             bcur = b
             for op in ops:
-                ctx.cur_op = op.id
+                ctx.cur_op = position[id(op)]
                 row, keep, names = _emit_op(ctx, op, row, keep, names,
                                             general=general,
                                             speculate=self.speculate_branches)

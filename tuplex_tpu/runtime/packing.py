@@ -93,8 +93,8 @@ def _wire_dtype(k: str, dtype, arrays, check_values: bool = False) -> str:
     * A '#len' column is bounded by its sibling byte matrix's padded
       width, so it narrows to u16 (or u8 when the width fits a byte) and
       re-widens on arrival. ('#err' is NOT narrowed: it packs
-      class|op_id<<8 and operator ids come from a session-global counter,
-      so values exceed u16.)
+      class|position<<8, and a stage of 256 operators or more exceeds
+      u16.)
     * '#rowidx' values are bounded by the padded INPUT size (sentinel
       included), visible statically as '#err'.shape — u16 when it fits.
 
@@ -658,6 +658,10 @@ class PackedStageFn:
         avals alone. (None, None, None) when nothing is packable. Shared
         by ``warm`` and the chip-compile test, so both lower exactly what
         dispatch would."""
+        entry, buf_aval, ex_avals = self._entry_for(avals)
+        return entry and entry[2], buf_aval, ex_avals
+
+    def _entry_for(self, avals: dict):
         spec, total = _host_spec(avals, check_values=False)
         if not spec:
             return None, None, None
@@ -672,7 +676,7 @@ class PackedStageFn:
         ex_avals = {k: jax.ShapeDtypeStruct(tuple(v.shape),
                                             np.dtype(v.dtype))
                     for k, v in extras.items()}
-        return entry[2], buf_aval, ex_avals
+        return entry, buf_aval, ex_avals
 
     def warm(self, avals: dict):
         """Ahead-of-time compile against PREDICTED avals (the precompile
@@ -683,16 +687,11 @@ class PackedStageFn:
         Returns the pool Future, or None when the layout has no packable
         leaves. Speculative by construction: a value-dependent '#len'
         narrowing miss only wastes one background compile."""
-        from ..exec import compilequeue as CQ
-
-        traced, buf_aval, ex_avals = self.traced_for(avals)
-        if traced is None:
-            return None
-        return CQ.submit_compile(
-            traced, (buf_aval, ex_avals),
-            donate_argnums=(0,) if self._donate else (), salt="pack",
-            tag=self._tag, n_ops=self._n_ops, deadline_s=self._deadline,
-            prewarm=True)
+        entry, buf_aval, ex_avals = self._entry_for(avals)
+        # the per-layout AotJit dispatch calls: one set of compile
+        # arguments for both sides (a bare jit, TUPLEX_AOT_JIT=0, has none)
+        warm = getattr(entry and entry[0], "warm", None)
+        return warm(buf_aval, ex_avals) if warm is not None else None
 
     def note_async_defect(self) -> bool:
         """Forward the async deserialize-defect verdict (see
