@@ -216,9 +216,6 @@ def check_records(label: str, recs: list, planned, failure_log: list,
             raise SmokeFailure(f"{label}: {stage_name(i, planned)} was "
                                f"routed off the device at plan time: "
                                f"{st.route_reason}")
-        if getattr(st, "cpu_compile", False):
-            raise SmokeFailure(f"{label}: {stage_name(i, planned)} is "
-                               f"marked cpu_compile (host-CPU executable)")
     fast = 0.0
     seen = interp = compiles = 0
     for i, m in enumerate(recs):
@@ -250,7 +247,7 @@ def check_records(label: str, recs: list, planned, failure_log: list,
     for fp, ex in CQ.executable_devices().items():
         # host-pinned executables ("/cpupin": the small-batch host resolve
         # policy) are on the host CPU by design; a STAGE compiled there is
-        # caught above (cpu_compile / the 'cpu-compiled' tier)
+        # caught above (the 'cpu-compiled' tier)
         if "/cpupin" not in ex["salt"] and \
                 any(p != platform for p, _ in ex["devices"]):
             raise SmokeFailure(f"{label}: executable {fp[:12]} was built "
@@ -306,8 +303,8 @@ def run_twice(ctx, label: str, build, compare, platform: str,
         if run == "warm":
             rec["stages"] = [type(s).__name__ for s in planned]
             rec["split"] = [
-                {"n_ops": d.n_ops, "k": d.k, "degrade": d.degrade,
-                 "fitted": d.fitted, "reason": d.reason}
+                {"n_ops": d.n_ops, "k": d.k, "over_budget": d.over_budget,
+                 "reason": d.reason}
                 for d in (getattr(s, "split_decision", None)
                           for s in planned) if d is not None]
             rec["tiers"] = [m.get("tier") for m in recs]
@@ -338,7 +335,6 @@ def setup_record(dev, n_devices: int, compile_deadline: float) -> dict:
     import jax
 
     import tuplex_tpu.native as native
-    from tuplex_tpu.plan import splittuner
     from tuplex_tpu.runtime import jaxcfg
 
     return {"phase": "setup",
@@ -349,8 +345,7 @@ def setup_record(dev, n_devices: int, compile_deadline: float) -> dict:
             "native": "built" if native.get() is not None
             else "python fallback",
             "xla_cache_dir": jax.config.jax_compilation_cache_dir,
-            "aot_cache_dir": jaxcfg.aot_cache_dir(),
-            "compile_model_dir": splittuner._model_dir()}
+            "aot_cache_dir": jaxcfg.aot_cache_dir()}
 
 
 def require_device(rehearse: bool, chips: int):

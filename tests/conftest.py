@@ -5,9 +5,8 @@ test/core/TestUtils.h:68,154 — tiny memory options, forced spills) using the
 JAX host-platform device-count trick so multi-chip code paths execute in CI
 without TPUs (SURVEY.md §4).
 
-Everything that decides what gets compiled (XLA's persistent cache, the AOT
-executable store, the split tuner's compile model) is pointed at a
-per-session temporary directory BEFORE the framework is imported, so no test
+Everything a run keeps of its compiles (XLA's persistent cache, the AOT
+executable store) is pointed at a per-session temporary directory BEFORE the framework is imported, so no test
 reads what another run — or the checkout's own `.tuplex_cache/` — left
 behind. Child processes that tests spawn inherit the same directories.
 """
@@ -28,7 +27,6 @@ _STATE = tempfile.mkdtemp(prefix="tuplex_test_state_")
 atexit.register(shutil.rmtree, _STATE, ignore_errors=True)
 os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(_STATE, "xla")
 os.environ["TUPLEX_AOT_CACHE"] = os.path.join(_STATE, "aot")
-os.environ["TUPLEX_COMPILE_MODEL_DIR"] = os.path.join(_STATE, "compile_model")
 
 import pytest  # noqa: E402
 

@@ -71,14 +71,10 @@ def _no_persistent_cache():
 def tpu_branches(monkeypatch):
     """Every ``jax.default_backend()`` read in the framework answers "tpu"
     (fusion_barriers_enabled, _nfa_impl, mxu_gather_override,
-    donation_enabled, packing_enabled, the split tuner's platform)."""
-    from tuplex_tpu.plan import splittuner
+    donation_enabled, packing_enabled, the planner's platform)."""
     from tuplex_tpu.runtime import jaxcfg
 
     monkeypatch.setattr(jaxcfg.jax, "default_backend", lambda: "tpu")
-    splittuner.reset_models()
-    yield
-    splittuner.reset_models()
 
 
 def _fits(compiled, label: str) -> int:
@@ -129,7 +125,7 @@ def _zillow_stage(tmp_path):
               if isinstance(s, TransformStage)]
     assert len(stages) == 1, "zillow must plan as ONE fused stage"
     stage = stages[0]
-    assert not stage.cpu_compile and not stage.force_interpret
+    assert not stage.force_interpret
     part = stage.source.load_partitions(ctx)[0]
     return stage, part.schema, C.stage_partition(part)
 
@@ -159,7 +155,7 @@ def test_zillow_stage_real_bucket_tpu_branches(tmp_path, one_chip,
     assert jaxcfg.donation_enabled() and packing.packing_enabled()
     stage, schema, batch = _zillow_stage(tmp_path)
     assert stage.split_decision is not None \
-        and stage.split_decision.k == 1 and not stage.split_decision.degrade
+        and stage.split_decision.k == 1 and not stage.split_decision.over_budget
     fn = stage.build_device_fn(schema, compaction=True, fused_fold=True)
     traced, buf, extras = packing.PackedStageFn(fn, donate=True).traced_for(
         _scaled(batch.arrays, DEVICE_BATCH, None))
@@ -377,3 +373,66 @@ def test_parse_f64_integer_path(one_chip, monkeypatch):
         jax.ShapeDtypeStruct((DEVICE_BATCH,), np.int32,
                              sharding=one_chip)).compile()
     _fits(c, "parse_f64 (integer path) 1M x 16")
+
+
+_PIPELINE_P = """
+import json, sys
+sys.path.insert(0, {repo!r})
+import tuplex_tpu
+from tuplex_tpu.exec import compilequeue as CQ
+from tuplex_tpu.plan.physical import TransformStage, plan_stages
+c = tuplex_tpu.Context()
+which = sys.argv[1]
+data = list(range(512))
+if which == "P":
+    pipes = [c.parallelize(data).map(lambda x: x + 1).map(lambda x: x * 2)
+             .map(lambda x: x - 3).map(lambda x: x * x)]
+else:
+    pipes = [c.parallelize(data).map(lambda x: x + 7),
+             c.parallelize(data).map(lambda x: x * 5).map(lambda x: x - 1),
+             c.parallelize(data).map(lambda x: x + 2).map(lambda x: x * 3)
+             .map(lambda x: x - 4).map(lambda x: x + 5).map(lambda x: x * 6)
+             .map(lambda x: x - 7)]
+keys, out = [], []
+for ds in pipes:
+    keys.append([(len(s.ops), s.key()) for s in
+                 plan_stages(ds._op, c.options_store)
+                 if isinstance(s, TransformStage)])
+    out.append(ds.collect()[:3])
+c.close()
+print(json.dumps({{"keys": keys, "out": out,
+                  "stage_compiles": CQ.STATS["stage_compiles"]}}))
+"""
+
+
+def test_plan_and_stored_executables_survive_other_pipelines(tmp_path):
+    """Process A runs pipeline P (four operators) in a fresh state
+    directory, process B three pipelines of one, two and six operators,
+    process C runs P again: the same stage keys as in A, and no compile —
+    what B compiled reaches no later plan. The children hold XLA:CPU (a
+    child could not load libtpu beside this file's tests)."""
+    import json
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    script = tmp_path / "pipelines.py"
+    script.write_text(_PIPELINE_P.format(repo=repo))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               TUPLEX_COMPILE_ISOLATION="thread",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "xla"),
+               TUPLEX_AOT_CACHE=str(tmp_path / "aot"))
+
+    def run(which):
+        r = subprocess.run([sys.executable, str(script), which],
+                           capture_output=True, text=True, env=env,
+                           cwd=str(tmp_path), timeout=600)
+        assert r.returncode == 0, r.stderr[-2000:]
+        return json.loads(r.stdout.splitlines()[-1])
+
+    a, b, c = run("P"), run("others"), run("P")
+    assert [[n for n, _ in keys] for keys in a["keys"]] == [[4]]
+    assert [[n for n, _ in keys] for keys in b["keys"]] == [[1], [2], [6]]
+    assert a["stage_compiles"] == 1 and b["stage_compiles"] == 3
+    assert c["keys"] == a["keys"] and c["out"] == a["out"] == [[1, 1, 9]]
+    assert c["stage_compiles"] == 0

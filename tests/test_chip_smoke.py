@@ -151,11 +151,10 @@ def test_warm_compile_and_interpreter_share_are_refused():
 
 _CACHE_PROBE = ("import jax, tuplex_tpu, json; "
                 "from tuplex_tpu.runtime import jaxcfg; "
-                "from tuplex_tpu.plan import splittuner; "
                 "print(json.dumps([jax.config.jax_compilation_cache_dir, "
-                "jaxcfg.aot_cache_dir(), splittuner._model_dir()]))")
+                "jaxcfg.aot_cache_dir()]))")
 _CACHE_VARS = ("JAX_COMPILATION_CACHE_DIR", "TUPLEX_AOT_CACHE",
-               "TUPLEX_COMPILE_MODEL_DIR", "TUPLEX_COMPILE_CACHE")
+               "TUPLEX_COMPILE_CACHE")
 
 
 def test_jax_cache_dir_from_the_environment_is_left_alone(tmp_path):
@@ -168,7 +167,7 @@ def test_jax_cache_dir_from_the_environment_is_left_alone(tmp_path):
 
 
 def test_caches_default_to_fixed_dirs_inside_the_checkout(tmp_path):
-    """Unset, all three stores are fixed siblings under the checkout —
+    """Unset, both stores are fixed siblings under the checkout —
     not under ~ (HOME points at an empty dir here and must stay empty),
     and the same in every process (the path is part of jax's cache key)."""
     home = tmp_path / "home"
@@ -181,22 +180,20 @@ def test_caches_default_to_fixed_dirs_inside_the_checkout(tmp_path):
         outs.append(json.loads(r.stdout.splitlines()[-1]))
     root = os.path.join(REPO, ".tuplex_cache")
     assert outs[0] == outs[1] == [os.path.join(root, "xla"),
-                                  os.path.join(root, "aot"),
-                                  os.path.join(root, "compile_model")]
+                                  os.path.join(root, "aot")]
     assert list(home.iterdir()) == []
     # and git ignores it
     with open(os.path.join(REPO, ".gitignore")) as fp:
         assert ".tuplex_cache/" in fp.read().split()
 
 
-def test_aot_and_model_dir_variables_still_work(tmp_path):
+def test_aot_dir_variable_still_works(tmp_path):
     r = _run(["-c", _CACHE_PROBE],
              {"TUPLEX_AOT_CACHE": str(tmp_path / "a"),
-              "TUPLEX_COMPILE_MODEL_DIR": str(tmp_path / "m"),
               "TUPLEX_COMPILE_CACHE": str(tmp_path / "gone")},
              drop=_CACHE_VARS)
     assert r.returncode == 0, r.stderr[-2000:]
-    xla, aot, model = json.loads(r.stdout.splitlines()[-1])
-    assert aot == str(tmp_path / "a") and model == str(tmp_path / "m")
+    xla, aot = json.loads(r.stdout.splitlines()[-1])
+    assert aot == str(tmp_path / "a")
     assert not (tmp_path / "gone").exists()     # that variable is gone
     assert xla == os.path.join(REPO, ".tuplex_cache", "xla")

@@ -2,8 +2,8 @@
 StageCost harvest + sidecar persistence (incl. the 2-process AOT
 round-trip: analysis present, zero compiles), measured dispatch time in
 stage metrics, Prometheus exposition schema for the new families, the
-split tuner's measured device-cost feature, the zero-alloc disabled
-path, and the zillow smoke (scripts/devprof_smoke.py) tier-1 wiring."""
+zero-alloc disabled path, and the zillow smoke (scripts/devprof_smoke.py)
+tier-1 wiring."""
 
 import json
 import os

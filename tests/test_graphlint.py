@@ -174,26 +174,31 @@ def test_compile_hazard_is_a_compile_timeout():
 # construct-weighted split planning (plan/splittuner, satellite 1)
 # ---------------------------------------------------------------------------
 
-# the synthetic platform's op-count curve, handed in explicitly: the tuner
-# carries no curve for a platform it has not observed (and would keep every
-# stage fused, hazard costs or not)
+# the synthetic platform's op-count curve, patched into the constants: a
+# platform without a curve keeps every stage fused, hazard costs or not
 TESTONLY_CURVE = (20.0, 1.5, 1.8)
 
 
-def test_scatter_heavy_splits_differently_than_elementwise():
+@pytest.fixture()
+def testonly(monkeypatch):
     from tuplex_tpu.plan import splittuner as ST
 
-    model = ST.CompileModel("testonly", path="",
-                            default_curve=TESTONLY_CURVE)
+    monkeypatch.setitem(ST.CURVES, "testonly", TESTONLY_CURVE)
+    return "testonly"
+
+
+def test_scatter_heavy_splits_differently_than_elementwise(testonly):
+    from tuplex_tpu.plan import splittuner as ST
+
     # budget above the op-count curve's fused prediction for 12 ops, so
     # the construct mix — not the curve — decides the split
-    budget = 2.0 * model.predict(12)
+    budget = 2.0 * ST.predict(testonly, 12)
     # equal op count, wildly different construct mix: 12 elementwise ops
     # stay fused, 12 scatter-heavy ops (hazard cost >> budget per op)
     # must split — op-count-only planning cannot tell them apart
-    elementwise = ST.plan_split(12, budget, model, prefer_fusion=True,
+    elementwise = ST.plan_split(12, budget, testonly, prefer_fusion=True,
                                 op_costs=[0.01] * 12)
-    scatter_heavy = ST.plan_split(12, budget, model, prefer_fusion=True,
+    scatter_heavy = ST.plan_split(12, budget, testonly, prefer_fusion=True,
                                   op_costs=[budget / 2.5] * 12)
     assert elementwise.k == 1
     assert scatter_heavy.k > 1
@@ -205,38 +210,17 @@ def test_scatter_heavy_splits_differently_than_elementwise():
     assert 0 < len(scatter_heavy.boundaries) == scatter_heavy.k - 1
 
 
-def test_hazard_split_bounds_worst_segment():
+def test_hazard_split_bounds_worst_segment(testonly):
     from tuplex_tpu.plan import splittuner as ST
 
-    model = ST.CompileModel("testonly", path="",
-                            default_curve=TESTONLY_CURVE)
     costs = [1.0, 1.0, 20.0, 1.0, 1.0, 1.0]
-    dec = ST.plan_split(6, 25.0, model, prefer_fusion=True,
+    dec = ST.plan_split(6, 25.0, testonly, prefer_fusion=True,
                         op_costs=costs)
     # worst single segment must fit the per-segment budget
     if dec.k > 1 and dec.boundaries:
         cuts = [0] + list(dec.boundaries) + [6]
         worst = max(sum(costs[a:b]) for a, b in zip(cuts, cuts[1:]))
         assert worst <= 25.0
-
-
-def test_family_weights_feed_the_model(tmp_path, monkeypatch):
-    monkeypatch.setenv("TUPLEX_COMPILE_MODEL_DIR", str(tmp_path))
-    from tuplex_tpu.plan import splittuner as ST
-
-    model = ST.CompileModel("testonly", path="")
-    seeded, fitted = model.family_weights()
-    assert not fitted and seeded == GL.FAMILY_WEIGHTS
-    # scatter-dominated observations drag the scatter weight up
-    for i in range(8):
-        model.record_compile(4, 10.0, families={"scatter": 40 + i,
-                                                "elementwise": 10})
-        model.record_compile(4, 0.1, families={"elementwise": 60 + i})
-    got, fitted = model.family_weights()
-    assert fitted
-    assert got["scatter"] > got["elementwise"]
-    assert model.census_cost({"scatter": 40}) > \
-        model.census_cost({"elementwise": 40})
 
 
 # ---------------------------------------------------------------------------

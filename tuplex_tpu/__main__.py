@@ -28,7 +28,7 @@ wedge-severity jaxpr finding exists.
 
 `compilestats` imports the script with actions stubbed out (no stage
 executes, nothing compiles), plans each action, and prints per-stage op
-counts, predicted compile seconds from the split tuner's measured curve,
+counts, predicted compile seconds where the platform has a cost curve,
 and which stages the content-addressed compile cache would dedup into one
 executable (utils/compilestats.py).
 """
@@ -56,7 +56,8 @@ def main(argv=None) -> int:
         help="per-stage op counts, predicted compile seconds, dedup groups")
     cs.add_argument("script", help="path to a python pipeline script")
     cs.add_argument("--platform", default=None,
-                    help="compile-model platform (default: jax backend)")
+                    help="platform of the compile-cost curve (default: jax "
+                         "backend)")
     ex = sub.add_parser(
         "excstats",
         help="exception-plane readout from the job history: per-stage x "

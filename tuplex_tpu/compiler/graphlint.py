@@ -16,9 +16,8 @@ stage's ClosedJaxpr (post-trace, pre-``lowered.compile()``) producing a
   32-bit inputs) and implicit-broadcast blowup findings,
 * scatter/gather/one-hot/concat **compaction-chain** detection, and
 * a weighted hazard score (predicted XLA:CPU compile seconds) with
-  per-construct weights calibrated against measured compile times —
-  the same observations plan/splittuner.py fits its op-count power law
-  to, broken down by primitive family instead of op count alone.
+  per-construct weights calibrated against measured compile times,
+  broken down by primitive family instead of op count alone.
 
 The load-bearing output is the ``wedge``-severity rule. Round 17
 bisected the flights airport build-side stage (3 ops / 2.2k eqns,
@@ -133,9 +132,8 @@ def apply_options(options) -> None:
 # least-squares over the round-17 stage corpus (19 stages, forked
 # compiles, probe shapes): clean stages run ~1.5-2.5 ms/eqn flat, with
 # gather/sort/scatter/while carrying the residual above the flat rate.
-# These seed splittuner's per-family residual fit (see
-# CompileModel.family_weights) and are intentionally conservative — the
-# score exists to rank and to veto, not to schedule.
+# They are intentionally conservative — the score exists to rank and to
+# veto, not to schedule.
 FAMILY_WEIGHTS = {
     "scatter": 0.060,
     "gather": 0.012,
@@ -253,7 +251,7 @@ class GraphReport:
         return self.peak_fixed_bytes + self.peak_row_bytes * max(rows, 0)
 
     def op_costs(self) -> list:
-        """Per-op hazard costs for splittuner's split-point placement:
+        """Per-op hazard costs for plan_split's split-point placement:
         the census-weighted cost spread uniformly over the stage's ops
         (the jaxpr does not delimit op boundaries, so the spread is the
         least-surprising sound choice; a wedge finding concentrates its

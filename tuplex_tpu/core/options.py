@@ -268,10 +268,11 @@ DEFAULTS: dict[str, str] = {
     "tuplex.tpu.jitCacheSize": "128",
     "tuplex.tpu.profileDir": "",            # jax.profiler trace per action
     "tuplex.tpu.compileBudgetS": "480",     # ceiling on a stage's predicted
-                                            # compile seconds: the split
-                                            # tuner (plan/splittuner.py)
-                                            # splits finer or degrades to a
-                                            # host-CPU compile to stay under
+                                            # compile seconds: where the
+                                            # platform has a compile-cost
+                                            # curve (plan/splittuner.py:
+                                            # XLA:CPU), the planner splits
+                                            # the stage finer to stay under
     "tuplex.tpu.compileDeadlineS": "300",   # hard ceiling per stage
                                             # compile, DEFAULT ON: the
                                             # compile runs in a killable

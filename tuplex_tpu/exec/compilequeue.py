@@ -605,10 +605,10 @@ def _graphlint_vet(traced, fp: str, tag: str, n_ops: int):
     finding or a hazard score past ``tuplex.tpu.hazardThreshold`` writes
     the content-addressed ``.hazard`` marker and raises CompileHazard so
     the stage degrades tier-by-tier WITHOUT ever submitting the doomed
-    compile. Returns the GraphReport (or None when the gate is off) for
-    census-tagged tuner feedback. Called only when no artifact exists —
-    an executable that compiled fine before outranks any static verdict,
-    same contract as the `.timeout` negative cache."""
+    compile. Returns the GraphReport (or None when the gate is off).
+    Called only when no artifact exists — an executable that compiled
+    fine before outranks any static verdict, same contract as the
+    `.timeout` negative cache."""
     from ..compiler import graphlint as GL
 
     if not GL.enabled():
@@ -893,8 +893,7 @@ def _kill_child(pid: int) -> None:
         pass
 
 
-def _compile_in_subprocess(fp: str, lowered, deadline_s: float,
-                           n_ops: int):
+def _compile_in_subprocess(fp: str, lowered, deadline_s: float):
     """Compile `lowered` in a killable forked child. Returns the compiled
     executable (deserialized from the artifact the child stored), None if
     the child failed for a non-deadline reason (caller falls back to the
@@ -952,7 +951,6 @@ def _compile_in_subprocess(fp: str, lowered, deadline_s: float,
     try:
         deadline = t0 + deadline_s if deadline_s and deadline_s > 0 \
             else None
-        next_censor = t0 + _CENSOR_INTERVAL_S
         next_cpu_check = t0 + 1.0
         last_cpu = 0.0
         last_progress_t = t0
@@ -984,24 +982,9 @@ def _compile_in_subprocess(fp: str, lowered, deadline_s: float,
                     STATS["deadline_timeouts"] += 1
                     STATS["compiles_killed"] += 1
                 _note_deadline_exceeded(fp)
-                if n_ops > 0:
-                    try:    # a killed compile still teaches the tuner
-                        from ..plan.splittuner import model_for
-
-                        model_for().record_running(n_ops, now - t0)
-                    except Exception:
-                        pass
                 raise CompileTimeout(
                     f"stage compile exceeded the {deadline_s:g}s "
                     f"deadline ({fp[:12]}…); compile child killed")
-            if n_ops > 0 and now >= next_censor:
-                next_censor += _CENSOR_INTERVAL_S
-                try:        # censored lower-bound obs, like the watchdog
-                    from ..plan.splittuner import model_for
-
-                    model_for().record_running(n_ops, now - t0)
-                except Exception:
-                    pass
             # fast compiles deserve a tight poll; long ones a cheap one
             time.sleep(min(0.05, max(0.002, (now - t0) / 20.0)))
         if not (os.WIFEXITED(status) and os.WEXITSTATUS(status) == 0):
@@ -1029,40 +1012,6 @@ def _compile_in_subprocess(fp: str, lowered, deadline_s: float,
                 os.remove(ephemeral)
             except OSError:
                 pass
-
-
-_CENSOR_INTERVAL_S = 60.0
-
-
-def _compile_with_watchdog(lowered, n_ops: int):
-    """Compile, and while the compile runs feed the split tuner CENSORED
-    lower-bound observations (n_ops, seconds-so-far) every minute. A
-    compile that wedges or is killed mid-flight — the flights 43-op
-    XLA:CPU blowup ran >20 min before being killed — thereby still
-    teaches the model it is expensive; finished compiles are exactly the
-    ones the observation set would otherwise be biased toward."""
-    if n_ops <= 0:
-        return _counted_compile(lowered)
-    stop = threading.Event()
-    t0 = time.perf_counter()
-
-    def watch():
-        while not stop.wait(_CENSOR_INTERVAL_S):
-            try:
-                from ..plan.splittuner import model_for
-
-                model_for().record_running(
-                    n_ops, time.perf_counter() - t0)
-            except Exception:   # pragma: no cover - model is best-effort
-                return
-
-    t = threading.Thread(target=watch, daemon=True,
-                         name="tpx-compile-watchdog")
-    t.start()
-    try:
-        return _counted_compile(lowered)
-    finally:
-        stop.set()
 
 
 def _bump(name: str) -> None:
@@ -1098,8 +1047,7 @@ def _note_devprof(tag: str, fp: str, compiled) -> None:
         pass
 
 
-def _note_compile(tag: str, dt: float, n_ops: int,
-                  families: Optional[dict] = None) -> None:
+def _note_compile(tag: str, dt: float) -> None:
     with _LOCK:
         STATS["stage_compiles"] += 1
         STATS["compile_s"] += dt
@@ -1107,16 +1055,6 @@ def _note_compile(tag: str, dt: float, n_ops: int,
         rec[0] += dt
         rec[1] += 1
     xferstats.bump("stage_compiles", 1, tag=tag or None)
-    if n_ops > 0:
-        try:     # feed the measured point into the stage-split tuner curve
-            from ..plan.splittuner import model_for
-
-            # `families` (graphlint's primitive-family census of the
-            # vetted jaxpr) rides along so the tuner can fit per-family
-            # compile-cost terms alongside the op-count power law
-            model_for().record_compile(n_ops, dt, families=families)
-        except Exception:   # pragma: no cover - the model is best-effort
-            pass
 
 
 def default_deadline_s() -> float:
@@ -1169,7 +1107,7 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
     """Trace `fn` against `args` (avals or concrete arrays) and return a
     compiled executable for it, via — in order — the in-process fingerprint
     store, the on-disk AOT artifact cache, or an actual XLA compile (counted,
-    timed, tuner-fed, persisted to disk). `prewarm` marks a speculative
+    timed, persisted to disk). `prewarm` marks a speculative
     call (the precompile driver's): see `_lookup_satisfied`. Such a call
     never waits for a compile in flight: it returns the pending Future
     itself, which the pool lets its own future follow.
@@ -1211,8 +1149,8 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
                .set("cache", "unaddressable")
             with _FORK_GATE:               # native lower: see the gate
                 lowered = traced.lower()
-            compiled = _compile_with_watchdog(lowered, n_ops)
-        _note_compile(tag, time.perf_counter() - t0, n_ops)
+            compiled = _counted_compile(lowered)
+        _note_compile(tag, time.perf_counter() - t0)
         _note_devprof(tag, "", compiled)   # tag-only: no content address
         return compiled
 
@@ -1268,7 +1206,6 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
         except Exception:
             continue    # their attempt failed; try to own it ourselves
 
-    gl_report = None        # graphlint report of the vetted trace, if any
     if prewarm:
         _prewarm_owns(fp)
 
@@ -1298,9 +1235,8 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
         with TR.span("compile:xla", "compile") as _sp:
             _sp.set("tag", tag[:16]).set("n_ops", n_ops) \
                .set("cache", "miss").set("fp", fp[:12])
-            compiled = _compile_with_watchdog(lowered, n_ops)
-        _note_compile(tag, time.perf_counter() - t0, n_ops,
-                      families=gl_report.families if gl_report else None)
+            compiled = _counted_compile(lowered)
+        _note_compile(tag, time.perf_counter() - t0)
         if aot_cache_enabled():
             try:
                 with _FORK_GATE:   # native serialize: see the gate
@@ -1371,7 +1307,7 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
             # artifact or in-process hit never reaches here). A veto
             # raises CompileHazard — same tier ladder as a killed
             # compile, zero kills.
-            gl_report = _graphlint_vet(traced, fp, tag, n_ops)
+            _graphlint_vet(traced, fp, tag, n_ops)
         if compiled is None:
             if deadline_s and deadline_s > 0:
                 # a known deserialize defect also rules out the FORK
@@ -1395,15 +1331,12 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
                         _bump("compile_starts")   # in the child
                         try:
                             compiled = _compile_in_subprocess(
-                                fp, lowered, deadline_s, n_ops)
+                                fp, lowered, deadline_s)
                         finally:
                             if compiled is None:   # killed, died, no handback
                                 _bump("compile_failures")
                     if compiled is not None:
-                        _note_compile(tag, time.perf_counter() - t0,
-                                      n_ops,
-                                      families=gl_report.families
-                                      if gl_report else None)
+                        _note_compile(tag, time.perf_counter() - t0)
                         with _LOCK:
                             STATS["subprocess_compiles"] += 1
                             _DESER.add(fp)   # handback = deserialized

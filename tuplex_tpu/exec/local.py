@@ -99,8 +99,8 @@ class _CpuJit:
     """jit pinned to the host CPU backend: numpy args placed (and the
     executable compiled) on the CPU device regardless of the default
     accelerator — used for small resolve batches where the device
-    round-trip tax exceeds the compute, and for compile-budget-degraded
-    stages (plan/splittuner).
+    round-trip tax exceeds the compute, and for the deadline ladder's
+    'cpu' tier.
 
     Per-input-spec compilation routes through exec/compilequeue's
     ``compile_traced`` (traced/lowered/compiled INSIDE the cpu
@@ -299,9 +299,6 @@ class LocalBackend:
         # stages whose sample-estimated compaction bucket overflowed: re-run
         # and remember to build without compaction from then on
         self._compaction_off: set[str] = set()
-        # stages whose per-boundary dispatch cost was already sampled into
-        # the split-tuner model (one clean sample per stage)
-        self._boundary_sampled: set[str] = set()
         # what the precompile driver walked in this process: (build-cache
         # key, batch spec) -> the next stage's predicted avals, None where
         # the chain stops (_speculate)
@@ -341,7 +338,8 @@ class LocalBackend:
         content-addressed store dedups isomorphic stages in-process and
         reuses serialized executables across processes; `tag` attributes
         compile seconds to the owning stage (metrics 'compile_s') and
-        `n_ops` feeds the stage-split tuner's measured curve."""
+        `n_ops` is the stage's operator count for graphlint's vetting and
+        the ``compile:*`` spans."""
         from ..runtime.jaxcfg import donation_enabled
         from ..runtime.packing import PackedStageFn, packing_enabled
         from .compilequeue import aot_jit
@@ -513,8 +511,7 @@ class LocalBackend:
         skipped = 0
         for si, stage in enumerate(stages):
             if avals is None or not isinstance(stage, TransformStage) \
-                    or stage.force_interpret \
-                    or getattr(stage, "cpu_compile", False):
+                    or stage.force_interpret:
                 break
             skey, use_comp, packed = self._stage_build_args(
                 stage, schema, consumer_kind(stages, si))
@@ -1216,11 +1213,8 @@ class LocalBackend:
         retries without it (an opt-in optimization must never demote the
         stage to the interpreter); only a plain build failure does that.
         ``force_cpu`` is the deadline-degrade 'cpu' tier: pin the compile
-        to the host CPU backend regardless of the stage's plan-time
-        ``cpu_compile`` flag (same mechanism as the split tuner's
-        compile-budget degrade)."""
-        cpu_pin = (force_cpu or getattr(stage, "cpu_compile", False)) and \
-            _cpu_device() is not None
+        to the host CPU backend."""
+        cpu_pin = force_cpu and _cpu_device() is not None
         if cpu_pin:
             from ..runtime.jaxcfg import jax as _jax
 
@@ -1231,9 +1225,8 @@ class LocalBackend:
                     in_schema, compaction=use_comp,
                     fused_fold=self.supports_fused_fold)
                 if cpu_pin:
-                    # compile-budget degrade (plan/splittuner) or the
-                    # deadline-degrade 'cpu' tier: the stage compiles on
-                    # the host CPU backend instead — device transfers
+                    # the deadline-degrade 'cpu' tier: the stage compiles
+                    # on the host CPU backend instead — device transfers
                     # still happen at the stage boundary, only the
                     # compute stays host-side. _CpuJit routes the compile
                     # through compilequeue.compile_traced (traced under
@@ -1321,7 +1314,7 @@ class LocalBackend:
             # The devprof gate is read ONCE: another thread flipping it
             # mid-dispatch (a new Context's apply_options) must not pair
             # a zero t_dev with a later record (a perf_counter-epoch
-            # "sample" would poison the histograms and the tuner feed).
+            # "sample" would poison the histograms).
             dp_on = DP.enabled() and stage is not None
             t_dev = time.perf_counter() if dp_on else 0.0
             with TR.span("dispatch:launch", "exec") as _lsp:
@@ -1335,13 +1328,6 @@ class LocalBackend:
                     _lsp.set("module",
                              getattr(device_fn, "last_module", None)) \
                         .set("first_call", int(first_call))
-            # the async-return stamp: everything up to here is staging +
-            # H2D + launch; the split tuner's BOUNDARY sample below must
-            # use this, not a post-block stamp — with devprof on, the
-            # block absorbs the stage's whole device execution and one
-            # such sample persisted into the compile model would inflate
-            # boundary_cost() ~1000x and weld every plan to k=1
-            t_ret = time.perf_counter()
             if dp_on:
                 # measured device time: wait for this dispatch's device
                 # work (is_ready polling — see devprof.block_ready) and
@@ -1359,23 +1345,6 @@ class LocalBackend:
             if leaf_h2d:
                 xferstats.note_h2d(leaf_h2d, tag="leaf_stage")
             self.jit_cache.note_traced(cache_key, spec)
-            if not first_call and stage is not None \
-                    and stage.source is None \
-                    and stage.key() not in self._boundary_sampled:
-                # measured per-boundary dispatch tax (re-stage + H2D +
-                # launch of a stage fed by a previous stage): one sample
-                # per stage feeds the split tuner's boundary-cost side.
-                # Only an ALREADY-TRACED spec qualifies (first_call spans
-                # the inline XLA compile — seconds to minutes — and a
-                # single poisoned sample would become the model's median,
-                # steering the tuner back to mega-fused stages).
-                self._boundary_sampled.add(stage.key())
-                try:
-                    from ..plan.splittuner import model_for
-
-                    model_for().record_boundary(t_ret - t0)
-                except Exception:
-                    pass
         except NotCompilable:
             # surfaces at TRACE time (first call): drop compaction first if
             # it was on (it may be the culprit) and re-dispatch THIS

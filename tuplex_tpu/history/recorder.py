@@ -100,22 +100,16 @@ class JobRecorder:
         """LIVE event: a stage began executing (reference: the driver posts
         task/stage updates to the history server DURING the job,
         HistoryServerConnector.cc:102-198 — not only at completion).
-        Carries the fused op count and the split tuner's predicted compile
-        seconds so a dashboard watcher can tell a long compile from a hung
-        stage BEFORE the stage completes."""
+        Carries the fused op count and the plan's predicted compile
+        seconds (where the platform has a curve) so a dashboard watcher
+        can tell a long compile from a hung stage BEFORE the stage
+        completes."""
         rec = {"event": "stage_start", "no": self._stage_no + 1,
                "kind": type(stage).__name__}
         ops = getattr(stage, "ops", None)
         if ops:
             rec["n_ops"] = len(ops)
             pred = getattr(stage, "predicted_compile_s", None)
-            if pred is None:
-                try:
-                    from ..plan.splittuner import model_for
-
-                    pred = model_for().predict(len(ops))
-                except Exception:
-                    pred = None
             if pred is not None:
                 rec["predicted_compile_s"] = round(float(pred), 3)
             # plan-time resolve-tier pick (plan/physical.ResolvePlan):

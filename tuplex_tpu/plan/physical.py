@@ -141,11 +141,10 @@ class TransformStage:
 
     force_interpret = False   # set on segments around non-compilable ops
     route_reason = ""         # why force_interpret was set (analyzer verdict)
-    cpu_compile = False       # compile-budget degrade (plan/splittuner):
-                              # build the stage fn on the host CPU backend
-    split_decision = None     # splittuner.SplitDecision when the tuner ran
-    predicted_compile_s = None  # tuner-predicted compile seconds for THIS
-                                # stage/segment (history + compilestats)
+    split_decision = None     # splittuner.SplitDecision of _split_oversize
+    predicted_compile_s = None  # predicted compile seconds for THIS stage/
+                                # segment where the platform has a curve
+                                # (history + compilestats)
     fold_op = None            # AggregateOperator whose pattern fold is fused
                               # into this stage's device fn (plan_stages)
     speculate_branches = True  # prune if/else arms the sample never took
@@ -1517,22 +1516,21 @@ def stage_fingerprint_prevet(stage: TransformStage):
 
 def _split_oversize(stage: TransformStage, options,
                     report=None) -> list:
-    """Split a very large fused stage into balanced sub-stages on
-    accelerator backends. Compile time scales superlinearly with graph
-    size; two half-size executables can compile far faster and the
-    intermediate rides the device-resident handoff. CPU keeps maximal
-    fusion (stage boundaries cost real memcpys there) unless the
-    predicted compile blows the budget. A platform with no observed
-    compiles has no curve and is never split or degraded
-    (plan/splittuner.py).
+    """Split a very large fused stage into balanced sub-stages. Compile
+    time scales superlinearly with graph size; two half-size executables
+    can compile far faster and the intermediate rides the device-resident
+    handoff.
 
-    The split point is MEASURED, not hardcoded (plan/splittuner.py): the
-    per-platform compile-seconds-vs-op-count curve (fed by every actual
-    compile) is balanced against the observed per-boundary dispatch tax,
-    under the ``tuplex.tpu.compileBudgetS`` ceiling; a stage whose finest
-    split still blows the budget degrades to a host-CPU compile with
-    device transfer. An explicit ``tuplex.tpu.maxStageOps`` (>0) overrides
-    the tuner; =0 disables splitting entirely."""
+    The split follows from the stage, the options and the platform alone
+    (plan/splittuner.py): on XLA:CPU the constant compile-cost curve keeps
+    the stage fused unless its predicted compile blows
+    ``tuplex.tpu.compileBudgetS``, then takes the fewest segments that fit
+    (the least-bad split where none does); a platform without a curve —
+    every accelerator — keeps the stage fused. A hazard score past
+    graphlint's veto line cuts at cost-balanced points instead, and the
+    static peak-memory vetting below may tighten the cap. An explicit
+    ``tuplex.tpu.maxStageOps`` (>0) overrides the curve; =0 disables
+    splitting entirely."""
     max_ops = 0
     if options is not None:
         max_ops = options.get_int("tuplex.tpu.maxStageOps", -1)
@@ -1540,12 +1538,12 @@ def _split_oversize(stage: TransformStage, options,
     dec = None
     if report is None:
         report = getattr(stage, "graph_report", None)
-    if max_ops < 0:       # auto: ask the tuner
+    if max_ops < 0:       # auto: ask the cost function
         from ..runtime.jaxcfg import jax
 
         from . import splittuner as ST
 
-        on_cpu = jax.default_backend() == "cpu"
+        platform = jax.default_backend()
         budget = options.get_float(
             "tuplex.tpu.compileBudgetS", 480.0) if options is not None \
             else 480.0
@@ -1555,52 +1553,37 @@ def _split_oversize(stage: TransformStage, options,
         # split isolates the hazardous span instead of balancing op
         # counts (the compile plane would otherwise veto the whole
         # stage, satellite: "split around the hazardous eqn span")
-        hazard_budget = None
+        op_costs = None
         if report is not None and not report.wedge and n > 1:
             from ..compiler import graphlint as GL
 
             threshold = GL.hazard_threshold()
             if threshold > 0 and report.hazard_score > threshold:
-                hazard_budget = threshold
+                budget, op_costs = threshold, report.op_costs()
         # CPU prefers fusion (boundaries are real memcpys, compiles are
         # usually cheap) and splits ONLY when the predicted compile blows
         # the budget — flights' 43-op mega-fusion ran >20 min at >120 GB
-        # on XLA:CPU. Accelerators cost-minimize across the whole curve
-        # once compiles have been observed there (no curve, no split).
+        # on XLA:CPU.
         from ..runtime import tracing as TR
 
         with TR.span("plan:split-tune", "plan") as _sp:
-            if hazard_budget is not None:
-                dec = ST.plan_split(n, hazard_budget, ST.model_for(),
-                                    prefer_fusion=on_cpu,
-                                    op_costs=report.op_costs())
-            else:
-                dec = ST.plan_split(n, budget, ST.model_for(),
-                                    prefer_fusion=on_cpu)
+            dec = ST.plan_split(n, budget, platform,
+                                prefer_fusion=platform == "cpu",
+                                op_costs=op_costs)
             if _sp is not TR.NOOP:
-                # the tuner's verdict rides the span so a trace shows WHY
-                # a plan split (or degraded) without digging through logs
+                # the verdict rides the span so a trace shows WHY a plan
+                # split without digging through logs
                 _sp.set("n_ops", n).set("k", dec.k) \
-                   .set("degrade", bool(dec.degrade)) \
+                   .set("over_budget", bool(dec.over_budget)) \
                    .set("predicted_compile_s",
                         round(float(dec.predicted_compile_s or 0.0), 3))
         stage.split_decision = dec
         stage.predicted_compile_s = dec.predicted_compile_s
-        if dec.k > 1 or dec.degrade:
+        if dec.k > 1 or dec.over_budget:
             ST.log_decision(dec)
-        if dec.degrade and not on_cpu:
-            # budget-degraded stages compile on the HOST CPU, where
-            # fusion is cheap and every extra boundary costs a real
-            # device transfer — so keep the stage fused rather than
-            # applying the accelerator split, and predict off the CPU
-            # curve
-            stage.cpu_compile = True
-            stage.predicted_compile_s = ST.model_for("cpu").predict(n)
-            max_ops = 0
-        else:
-            # on CPU a degrade verdict has nowhere cheaper to go — take
-            # the least-bad split and proceed
-            max_ops = dec.per if dec.k > 1 else 0
+        # an over-budget verdict has nowhere cheaper to go — take the
+        # least-bad split and proceed
+        max_ops = dec.per if dec.k > 1 else 0
     # static peak-memory vetting (compiler/graphlint): a stage whose
     # intermediates STATICALLY exceed the MemoryManager budget at the
     # runtime batch size must not reach the device — it would OOM-spill
@@ -1642,7 +1625,7 @@ def _split_oversize(stage: TransformStage, options,
     per = math.ceil(n / k)
     # chunk boundaries must not separate an op from its trailing
     # Resolve/Ignore guards. A hazard-mode split decision carries COST-
-    # balanced cut points (splittuner boundaries) — honored as long as
+    # balanced cut points (SplitDecision.boundaries) — honored as long as
     # nothing tightened the op cap after the decision was made.
     cuts = list(dec.boundaries) if (dec is not None and dec.boundaries
                                     and max_ops == dec.per) else None
@@ -1672,12 +1655,9 @@ def _split_oversize(stage: TransformStage, options,
             seg = TransformStage(None, ops_run, input_schema=schema,
                                  input_op=ops_run[0])
         seg.speculate_branches = stage.speculate_branches
-        seg.cpu_compile = stage.cpu_compile
         if dec is not None:
-            from . import splittuner as ST
-
             seg.split_decision = dec
-            seg.predicted_compile_s = ST.model_for().predict(len(ops_run))
+            seg.predicted_compile_s = ST.predict(platform, len(ops_run))
         for op in ops_run:
             if not isinstance(op, (L.ResolveOperator, L.IgnoreOperator)):
                 schema = op.schema()

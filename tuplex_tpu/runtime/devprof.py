@@ -20,9 +20,7 @@ is exactly the signal a cost-based plan optimizer needs. Three pieces:
   stage, split cold (first call: includes the compile/AOT-load wait) vs
   warm. Samples land in telemetry histograms
   (``device_dispatch_seconds{stage,state}``) and a per-stage accumulator
-  consumed into stage metrics; the warm median also feeds the split
-  tuner's per-boundary cost model (plan/splittuner.record_device_dispatch)
-  — the first REAL device-cost feature in the split decision.
+  consumed into stage metrics.
 * **roofline** — a small per-platform peak table (TPU generations from
   published specs; CPU a labeled estimate) turns flops/bytes/seconds
   into achieved FLOP/s, achieved bytes/s, arithmetic intensity and
@@ -295,7 +293,6 @@ STAGE_LABEL_LEN = 16
 # having whichever job finishes first steal the others' report.
 _DISP: dict[tuple, dict] = {}
 _WARM_KEEP = 64                     # bounded warm-sample window per stage
-_tuner_fed: set = set()             # tags already fed to the split tuner
 
 
 def block_ready(outs) -> None:
@@ -506,8 +503,7 @@ def stage_report(tag: str, mm_budget: int = 0,
     compile/load-inclusive, so it UNDERSTATES utilization — warm runs
     self-correct it), and hbm_budget_frac when the MemoryManager budget
     is known. Also updates the bounded exposition snapshot (telemetry
-    /metrics gauges) and feeds the warm median to the split tuner once
-    per stage per process."""
+    /metrics gauges)."""
     if not _enabled or not tag:
         return None
     with _LOCK:
@@ -522,14 +518,6 @@ def stage_report(tag: str, mm_budget: int = 0,
     }
     warm = sorted(acc["warm"])
     warm_med = warm[len(warm) // 2] if warm else 0.0
-    if warm_med > 0 and tag not in _tuner_fed:
-        _tuner_fed.add(tag)
-        try:        # the first real device-cost feature in the tuner
-            from ..plan.splittuner import model_for
-
-            model_for().record_device_dispatch(warm_med)
-        except Exception:   # pragma: no cover - model is best-effort
-            pass
     if cost is not None:
         rep["flops"] = cost.flops * acc["n"]
         rep["device_bytes"] = cost.bytes_accessed * acc["n"]
@@ -649,7 +637,6 @@ def clear() -> None:
         _BY_TAG.clear()
         _DISP.clear()
         _REPORTS.clear()
-    _tuner_fed.clear()
     _index_known.clear()
     _index_last_write = 0.0
     _peaks_cache = None

@@ -33,11 +33,10 @@ def _host_tag() -> str:
     return tag
 
 
-# Everything that decides what gets compiled (XLA's persistent cache, the
-# AOT executable store, the split tuner's compile model) lives under ONE
-# fixed directory inside the checkout, never under ~ or a per-run name:
-# the path is part of jax's cache key, and a run must be reproducible
-# from the tree it ran in.
+# Everything a run keeps of its compiles (XLA's persistent cache, the AOT
+# executable store) lives under ONE fixed directory inside the checkout,
+# never under ~ or a per-run name: the path is part of jax's cache key,
+# and a run must be reproducible from the tree it ran in.
 STATE_ROOT = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".tuplex_cache")

@@ -597,7 +597,7 @@ class PackedStageFn:
         self._varlen = varlen_wire_enabled()
         self._fns: dict = {}
         self._tag = tag          # compile-seconds attribution (stage key)
-        self._n_ops = n_ops      # feeds the stage-split tuner curve
+        self._n_ops = n_ops      # graphlint's vetting, compile:* spans
         self._deadline = deadline   # compile deadline (CompileTimeout)
         self._last_fn = None     # the per-layout fn the last call launched
 

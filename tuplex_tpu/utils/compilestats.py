@@ -4,8 +4,8 @@ forecast.
 Runs the pipeline script with every DataSet ACTION stubbed out (collect/
 take/show/tocsv/... capture the plan and return empty), plans each captured
 action, and prints per stage: fused op count, jaxpr equation count, the
-split tuner's predicted compile seconds (plan/splittuner.py — the measured
-per-platform curve), and which stages would share one executable under the
+predicted compile seconds where the platform has a compile-cost curve
+(plan/splittuner.py), and which stages would share one executable under the
 content-addressed compile cache (exec/compilequeue.py fingerprints).
 
 Unlike `lint` (purely syntactic, never imports the script), compilestats
@@ -57,9 +57,10 @@ def _capture_plans(script: str) -> list:
     return captured
 
 
-def _stage_rows(stages, model) -> tuple[list, dict]:
+def _stage_rows(stages, platform: str) -> tuple[list, dict]:
     """Per-stage stat rows + fingerprint groups for one plan."""
     from ..plan.physical import TransformStage, stage_fingerprint
+    from ..plan.splittuner import predict
     from .planviz import stage_eqn_count
 
     rows = []
@@ -72,13 +73,12 @@ def _stage_rows(stages, model) -> tuple[list, dict]:
         n_ops = len(st.ops)
         row = {"i": i, "kind": kind, "n_ops": n_ops,
                "key": st.key(),
-               "interpreter": bool(st.force_interpret),
-               "cpu_compile": bool(getattr(st, "cpu_compile", False))}
+               "interpreter": bool(st.force_interpret)}
         if not st.force_interpret:
             row["eqns"] = stage_eqn_count(st)
             pred = getattr(st, "predicted_compile_s", None)
             row["predicted_s"] = float(pred) if pred is not None \
-                else model.predict(n_ops)
+                else predict(platform, n_ops)
             fp = stage_fingerprint(st)
             if fp is not None:
                 row["fp"] = fp
@@ -197,9 +197,10 @@ def lint_jaxprs(script: str, stream=None) -> tuple[int, int]:
 
 
 def main(script: str, platform: Optional[str] = None) -> int:
+    from ..plan import splittuner as ST
     from ..plan.physical import plan_stages
-    from ..plan.splittuner import model_for
     from ..runtime import devprof
+    from ..runtime.jaxcfg import jax
 
     try:
         captured = _capture_plans(script)
@@ -214,15 +215,12 @@ def main(script: str, platform: Optional[str] = None) -> int:
               "(collect/take/show/tocsv/...)", file=sys.stderr)
         return 1
 
-    model = model_for(platform)
-    (_, _, curve_c), fitted = model.curve()
-    dev_cost = model.device_dispatch_cost()
-    print(f"compile model: platform={model.platform} "
-          f"{'measured curve' if fitted else 'default curve'} "
-          f"(exponent {curve_c:.2f}), "
-          f"boundary cost {model.boundary_cost() * 1e3:.1f} ms"
-          + (f", device dispatch {dev_cost * 1e3:.1f} ms (measured)"
-             if dev_cost > 0 else ""))
+    platform = platform or jax.default_backend()
+    curve = ST.CURVES.get(platform)
+    print(f"compile-cost curve: platform={platform} "
+          + (f"exponent {curve[2]:.2f}, boundary cost "
+             f"{ST.BOUNDARY_S[platform] * 1e3:.1f} ms" if curve
+             else "none (stages stay fused, no compile predicted)"))
     cost_index = devprof.load_stage_index()
     rc = 0
     for pi, (action, sink, options) in enumerate(captured):
@@ -233,7 +231,7 @@ def main(script: str, platform: Optional[str] = None) -> int:
             print(f"  planning failed: {type(e).__name__}: {e}")
             rc = 1
             continue
-        rows, dedup = _stage_rows(stages, model)
+        rows, dedup = _stage_rows(stages, platform)
         total = 0.0
         for row in rows:
             head = f"  stage {row['i']} [{row['kind']}]"
@@ -245,8 +243,6 @@ def main(script: str, platform: Optional[str] = None) -> int:
                 bits.append(f"{row['eqns']} jaxpr eqns")
             if row.get("interpreter"):
                 bits.append("interpreter segment (no compile)")
-            elif row.get("cpu_compile"):
-                bits.append("host-CPU compile (budget degrade)")
             if row.get("predicted_s") is not None \
                     and not row.get("interpreter"):
                 bits.append(f"predicted compile {row['predicted_s']:.1f}s")
@@ -286,6 +282,8 @@ def main(script: str, platform: Optional[str] = None) -> int:
             else:
                 print("    group cost: no record yet (stages never ran "
                       "with devprof on)")
+        if curve is None:
+            continue
         budget = options.get_float("tuplex.tpu.compileBudgetS", 480.0)
         line = (f"  predicted compile total: {total:.1f}s serial"
                 + (f", {total - saved:.1f}s after dedup" if saved else ""))
