@@ -436,7 +436,7 @@ def _stage_and_avals(ctx, path):
 
     ds = ctx.csv(path).map(lambda x: (len(str(x["v"])), x["k"] + 1))
     st = plan_stages(ds._op, ctx.options_store)[0]
-    part = _source_partitions(ctx, st, lazy=False)[0]
+    part = next(iter(_source_partitions(ctx, st, lazy=False)))
     return st, part.schema, SF.partition_avals(part, "q8")
 
 

@@ -438,15 +438,24 @@ class DataSet:
 
 
 def _source_partitions(context, stage, lazy: bool = False):
-    """Materialize the stage source into columnar partitions, inside an
-    `ingest` span (children: `ingest:read-csv`, `ingest:to-partition`,
-    `ingest:harmonize`).
+    """The stage source as columnar partitions, inside `ingest` spans.
 
-    `lazy=True` returns a GENERATOR (no dataset-wide harmonization): used by
+    A source that can state its partitions' shapes before it builds them
+    (`stream_partitions`: the CSV source) is read and planned here, in one
+    `ingest` span (children: `ingest:read-csv`, `ingest:plan-shapes`;
+    `streamed` true), and comes back as a `C.PartitionStream`: each pull
+    cuts one partition at its final shape in an `ingest` span of its own
+    (child: `ingest:to-partition`), on the thread that pulls — the
+    backend's prefetch thread for all but the first. Any other source is
+    materialized to a list in the one span and padded to one width set
+    afterwards (`ingest:harmonize`).
+
+    `lazy=True` returns a GENERATOR (no dataset-wide widths): used by
     take(n) so the backend can stop consuming once the limit is met — each
     pull of the generator is then one `ingest` span. Lazy batches may have
     differing str widths — worst case a few extra jit retraces, which a
     take() of a handful of rows never hits."""
+    from ..runtime import columns as C
     from ..runtime import tracing as TR
 
     if lazy:
@@ -454,6 +463,11 @@ def _source_partitions(context, stage, lazy: bool = False):
                         "ingest", "io")
     with TR.span("ingest", "io") as _sp:
         parts = _load_source(context, stage, lazy=False)
+        if isinstance(parts, C.PartitionStream):
+            _sp.set("streamed", True).set("partitions", len(parts.rows)) \
+               .set("rows", sum(parts.rows))
+            return C.PartitionStream(parts.template, parts.rows,
+                                     TR.pulls(parts, "ingest", "io"))
         _sp.set("partitions", len(parts)) \
            .set("rows", sum(p.num_rows for p in parts))
     return parts
@@ -493,6 +507,10 @@ def _load_source(context, stage, lazy: bool):
             else {}
         if lazy and hasattr(src, "iter_partitions"):
             return src.iter_partitions(context, **kwargs)
+        if not lazy and hasattr(src, "stream_partitions"):
+            stream = src.stream_partitions(context, **kwargs)
+            if stream is not None:
+                return stream   # at one width set already: nothing to pad
         parts = src.load_partitions(context, **kwargs)
         if lazy:
             return iter(parts)

@@ -32,19 +32,20 @@ def require_traceable(ops, speculate: bool = True) -> None:
                 f"({rep.loc(f)})")
 
 
-def partition_avals(part, bucket_mode: str = "q8"):
+def partition_avals(part, bucket_mode: str = "q8", rows=None):
     """Abstract (ShapeDtypeStruct) mirror of ``columns.stage_partition``
     for `part` — the exact avals its dispatch batch will have, computed
     without copying a byte. Feeds the ahead-of-time compile pool
     (exec/compilequeue): compiling against these avals means the real
     dispatch finds its executable already built. None when a leaf has no
-    device layout."""
+    device layout. `rows` stands in for ``part.num_rows`` where `part` is
+    a `PartitionStream`'s template and the partition is not built yet."""
     import numpy as np
 
     from ..runtime import columns as C
     from ..runtime.jaxcfg import jax
 
-    b = C.bucket_size(part.num_rows, bucket_mode)
+    b = C.bucket_size(part.num_rows if rows is None else rows, bucket_mode)
     avals: dict = {}
     for path, leaf in part.leaves.items():
         ks = C._leaf_keys(path, leaf)

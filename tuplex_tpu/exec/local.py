@@ -412,7 +412,9 @@ class LocalBackend:
                 or not self.options.get_bool(
                     "tuplex.tpu.parallelCompile", True):
             return None
-        if not (isinstance(partitions, list) and partitions):
+        planned = partitions.rows \
+            if isinstance(partitions, C.PartitionStream) else partitions
+        if not (isinstance(planned, list) and planned):
             return None
         try:
             batches = self._distinct_batches(partitions)
@@ -438,12 +440,17 @@ class LocalBackend:
 
     def _distinct_batches(self, partitions) -> list:
         """(avals, schema) of each distinct dispatch batch among
-        `partitions`, in order: the full bucket and the short tail's."""
+        `partitions`, in order: the full bucket and the short tail's. A
+        `PartitionStream` answers from its planned shapes, nothing built."""
         from ..compiler import stagefn as SF
 
+        if isinstance(partitions, C.PartitionStream):
+            shapes = [(partitions.template, m) for m in partitions.rows]
+        else:
+            shapes = [(part, part.num_rows) for part in partitions]
         seen: dict = {}
-        for part in partitions:
-            avals = SF.partition_avals(part, self.bucket_mode)
+        for part, rows in shapes:
+            avals = SF.partition_avals(part, self.bucket_mode, rows=rows)
             if avals is not None:
                 seen.setdefault(_avals_spec(avals), (avals, part.schema))
         return list(seen.values())

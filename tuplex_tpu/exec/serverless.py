@@ -802,7 +802,8 @@ class ServerlessBackend(LocalBackend):
 
         if tspec.get("files") is not None:
             sub = _clone_stage_for_files(stage, tspec["files"])
-            parts = _source_partitions(context, sub, lazy=False)
+            # the failed task's whole share, as the worker would hold it
+            parts = list(_source_partitions(context, sub, lazy=False))
             res = LocalBackend.execute(self, sub, parts)
         else:
             src = TuplexFileSourceOperator(self.options, tspec["indir"])

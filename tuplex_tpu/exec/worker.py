@@ -114,7 +114,8 @@ def run_task(req_path: str, backends=None) -> dict:
     else:
         from ..api.dataset import _source_partitions
 
-        partitions = _source_partitions(ctx, stage, lazy=False)
+        # a task's whole share, as `load_partitions` hands it over
+        partitions = list(_source_partitions(ctx, stage, lazy=False))
 
     result = backend.execute(stage, partitions)
 

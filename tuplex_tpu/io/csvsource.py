@@ -11,6 +11,13 @@ parsing fused INTO the compiled pipeline) for the TPU model:
     normal-case type at tuplex.normalcaseThreshold)
   * bulk read: pyarrow.csv (Arrow C++, multithreaded) with ALL columns read
     as strings — structural parsing only, no type conversion on host
+  * shapes first: a whole read (`stream_partitions`) plans every column's
+    dataset-wide byte width off the Arrow offsets and every partition's
+    rows BEFORE one partition is built, then cuts each partition at that
+    shape when the stage pulls it — on the backend's prefetch thread,
+    beside the chip; no pad pass follows (one executable per stage needs
+    one width set; `take(n)` streams record batches instead and pays a
+    retrace where widths differ)
   * type decoding runs ON DEVICE inside the fused stage function
     (DecodeOperator → parse_i64/parse_f64 kernels + null-value matching);
     cells that fail to parse raise into the error lattice and re-run on the
@@ -280,13 +287,65 @@ class CSVSourceOperator(L.LogicalOperator):
             sharded = self._load_host_sharded(context, projection)
             if sharded is not None:
                 return sharded
-        parts: list[C.Partition] = []
-        offset = 0
-        for path in self.files:
-            for p in self._read_file(context, path, offset, projection):
-                parts.append(p)
-                offset += p.num_rows
-        return parts
+        return list(self._planned_stream(context, projection))
+
+    def stream_partitions(self, context, projection=None):
+        """The whole read as a `C.PartitionStream`: every file is read and
+        the partitions' shapes are planned HERE (`ingest:read-csv`,
+        `ingest:plan-shapes`); each partition is cut when the stream is
+        pulled (`ingest:to-partition`), at its final width. None where this
+        process reads only its byte range of the file (host-sharded): that
+        block's widths are agreed across hosts, after it is built."""
+        if self._host_sharded(context):
+            return None
+        return self._planned_stream(context, projection)
+
+    def _planned_stream(self, context, projection=None) -> C.PartitionStream:
+        stat = self.stat
+        out_columns = list(projection) if projection else stat.columns
+        raw_schema = T.row_of(out_columns,
+                              [T.option(T.STR)] * len(out_columns))
+        proj_idx = [stat.columns.index(c) for c in out_columns]
+        max_w = context.options_store.get_int("tuplex.tpu.maxStrBytes", 4096)
+        reads = [(path, *self._read_table(path, projection))
+                 for path in self.files]
+        with TR.span("ingest:plan-shapes", "io") as _sp:
+            # the dataset-wide width of every column, off the Arrow offsets:
+            # what harmonize_partitions finds once every partition is built
+            widths = _planned_widths([t for _, t, _ in reads], max_w)
+            cuts: list = []     # a file: (table, scanned, bad_rows, cap, sizes)
+            rows: list[int] = []
+            for path, table, bad_rows in reads:
+                cap = _csv_rows_per_partition(context, table)
+                scanned = None
+                if bad_rows:
+                    # Arrow's InvalidRow.number is None in this version, so
+                    # recover each bad row's original position with one
+                    # lenient python-csv scan (dirty path only) and splice it
+                    # back at its slot as a boxed fallback row — keeps
+                    # merge-in-order exact for malformed rows like the
+                    # reference (advisor finding, round 1).
+                    scanned = _scan_bad_records(path, stat)
+                    if len(scanned) != len(bad_rows):
+                        scanned = None
+                if scanned is not None:
+                    sizes = _chunk_sizes(table.num_rows + len(scanned), cap)
+                    rows += sizes
+                else:
+                    # position recovery failed (python csv disagreed with
+                    # Arrow about which rows are malformed): the bad rows
+                    # trail as one partition — output order for them
+                    # diverges from the reference
+                    sizes = _chunk_sizes(table.num_rows, cap)
+                    rows += sizes + ([len(bad_rows)] if bad_rows else [])
+                cuts.append((table, scanned, bad_rows, cap, sizes))
+            _sp.set("columns", len(widths)).set("partitions", len(rows)) \
+               .set("widths", widths)
+        template = _table_to_partition(reads[0][1].slice(0, 0), raw_schema,
+                                       max_w, 0, widths)
+        del reads
+        return C.PartitionStream(template, rows, _cut_partitions(
+            cuts, stat, raw_schema, proj_idx, max_w, widths))
 
     def _load_host_sharded(self, context, projection=None):
         """ONE host-block partition from this process's byte range of the
@@ -435,13 +494,13 @@ class CSVSourceOperator(L.LogicalOperator):
                 offset += p.num_rows
                 yield p
 
-    def _read_file(self, context, path: str, base_index: int,
-                   projection=None):
+    def _read_table(self, path: str, projection=None):
+        """One file as an Arrow table of string columns, and the rows Arrow
+        refused for their cell count ((number, text) pairs)."""
         import pyarrow as pa
         import pyarrow.csv as pacsv
 
         stat = self.stat
-        k = stat.num_columns
         bad_rows: list[tuple[int, str]] = []
 
         def on_invalid(row):
@@ -468,46 +527,60 @@ class CSVSourceOperator(L.LogicalOperator):
             column_types={c: pa.string() for c in stat.columns},
             include_columns=list(projection) if projection else None,
             strings_can_be_null=False)
-        out_columns = list(projection) if projection else stat.columns
-        raw_schema = T.row_of(out_columns,
-                              [T.option(T.STR)] * len(out_columns))
         with TR.span("ingest:read-csv", "io") as _sp:
             table = pacsv.read_csv(_csv_input(path), read_options=read_opts,
                                    parse_options=parse_opts,
                                    convert_options=conv_opts)
             _note_read(_sp, path, table.num_rows + len(bad_rows),
-                       len(out_columns))
+                       table.num_columns)
+        return table, bad_rows
 
-        max_w = context.options_store.get_int("tuplex.tpu.maxStrBytes", 4096)
-        rows_per_part = _csv_rows_per_partition(context, table)
+
+def _planned_widths(tables: list, max_w: int) -> list[int]:
+    """The byte width of every column's leaf over ALL of `tables`, from one
+    pass over the Arrow offsets: the widest cell, capped at `max_w` (a
+    longer cell boxes its row), in its q8 bucket."""
+    import pyarrow as pa
+
+    widest = [1] * tables[0].num_columns
+    for table in tables:
+        for ci, col in enumerate(table.columns):
+            dt = np.int64 if pa.types.is_large_string(col.type) else np.int32
+            for chunk in col.chunks:
+                if len(chunk):
+                    offs = np.frombuffer(chunk.buffers()[1], dtype=dt)[
+                        chunk.offset: chunk.offset + len(chunk) + 1]
+                    widest[ci] = max(widest[ci],
+                                     int((offs[1:] - offs[:-1]).max()))
+    return [C.bucket_size(max(min(w, max_w), 1), minimum=8) for w in widest]
+
+
+def _cut_partitions(cuts: list, stat: "CSVStatistic", raw_schema: T.RowType,
+                    proj_idx: list, max_w: int, widths: list):
+    """The partitions `_planned_stream` planned, each built when pulled and
+    at the planned `widths`; a file's table is let go with its last one."""
+    base = 0
+    while cuts:
+        table, scanned, bad_rows, cap, sizes = cuts.pop(0)
         n = table.num_rows
-        proj_idx = [stat.columns.index(c) for c in out_columns]
-        if bad_rows:
-            # Arrow's InvalidRow.number is None in this version, so recover
-            # each bad row's original position with one lenient python-csv
-            # scan (dirty path only) and splice it back at its slot as a
-            # boxed fallback row — keeps merge-in-order exact for malformed
-            # rows like the reference (advisor finding, round 1).
-            scanned = _scan_bad_records(path, stat)
-            if len(scanned) == len(bad_rows):
-                yield from TR.pulls(_spliced_partitions(
-                    table, scanned, raw_schema, proj_idx, max_w,
-                    rows_per_part, base_index), "ingest:to-partition", "io")
-                return
+        if scanned is not None:
+            yield from TR.pulls(_spliced_partitions(
+                table, scanned, raw_schema, proj_idx, max_w, cap, base,
+                widths), "ingest:to-partition", "io")
+            base += n + len(scanned)
+            continue
         start = 0
-        for m in _chunk_sizes(n, rows_per_part):
+        for m in sizes:
             with TR.span("ingest:to-partition", "io") as _sp:
                 _sp.set("rows", m)
                 part = _table_to_partition(table.slice(start, m), raw_schema,
-                                           max_w, base_index + start)
+                                           max_w, base + start, widths)
             yield part
             start += m
-        # position recovery failed (python csv disagreed with Arrow about
-        # which rows are malformed): append bad rows as one trailing
-        # partition — output order for them diverges from the reference
         if bad_rows:
             yield _bad_rows_partition(bad_rows, stat, proj_idx, raw_schema,
-                                      base_index + n)
+                                      base + n, widths, max_w)
+        base += n + len(bad_rows)
 
 
 def _note_read(sp, path: str, rows: int, columns: int) -> None:
@@ -526,9 +599,12 @@ def _note_read(sp, path: str, rows: int, columns: int) -> None:
 
 def _bad_rows_partition(bad_rows: list, stat: "CSVStatistic",
                         proj_idx: list, raw_schema: T.RowType,
-                        start_index: int) -> C.Partition:
+                        start_index: int, widths=None,
+                        max_w: int = 0) -> C.Partition:
     """Trailing partition of leniently re-parsed structurally-bad rows
-    (shared by the eager fallback and streaming paths)."""
+    (shared by the eager fallback and streaming paths). With `widths` (a
+    planned stream's, and its `max_w`) the leaves take those widths, and a
+    cell wider than its column's boxes its row."""
     vals = []
     for _, text in bad_rows:
         try:
@@ -539,7 +615,14 @@ def _bad_rows_partition(bad_rows: list, stat: "CSVStatistic",
             cells = [text]
         vals.append(tuple(cells[i] if i < len(cells) else None
                           for i in proj_idx))
-    return C.build_partition(vals, raw_schema, start_index=start_index)
+    if widths is None:
+        return C.build_partition(vals, raw_schema, start_index=start_index)
+    import pyarrow as pa
+
+    table = pa.table([pa.array([v[ci] for v in vals], pa.string())
+                      for ci in range(len(proj_idx))],
+                     names=[str(ci) for ci in range(len(proj_idx))])
+    return _table_to_partition(table, raw_schema, max_w, start_index, widths)
 
 
 def _scan_bad_records(path: str, stat: "CSVStatistic", text=None,
@@ -571,10 +654,11 @@ def _scan_bad_records(path: str, stat: "CSVStatistic", text=None,
 
 def _spliced_partitions(table, scanned: list, raw_schema: T.RowType,
                         proj_idx: list[int], max_w: int, rows_per_part: int,
-                        base_index: int):
+                        base_index: int, widths=None):
     """Partitions over the ORIGINAL row-ordinal space: surviving Arrow rows
     keep their true slots, structurally-bad rows occupy theirs as boxed
-    fallback slots (normal_mask False -> interpreter path)."""
+    fallback slots (normal_mask False -> interpreter path). `widths`: the
+    planned leaf widths (`_table_to_partition`)."""
     n = table.num_rows
     nb = len(scanned)
     bad_ord = np.asarray([o for o, _ in scanned], dtype=np.int64)
@@ -589,7 +673,8 @@ def _spliced_partitions(table, scanned: list, raw_schema: T.RowType,
         j0, j1 = np.searchsorted(surv, [start, start + m])
         bi0, bi1 = np.searchsorted(bad_ord, [start, start + m])
         tp = _table_to_partition(table.slice(int(j0), int(j1 - j0)),
-                                 raw_schema, max_w, base_index + start)
+                                 raw_schema, max_w, base_index + start,
+                                 widths)
         if bi1 == bi0:
             yield tp  # no bad slots here: chunk is contiguous, j1-j0 == m
         else:
@@ -639,46 +724,96 @@ def _chunk_sizes(total: int, cap: int) -> list[int]:
 
 
 def _table_to_partition(table, schema: T.RowType, max_w: int,
-                        start_index: int) -> C.Partition:
+                        start_index: int, widths=None) -> C.Partition:
     """Arrow string columns -> fixed-width byte-matrix leaves, vectorized.
 
     Over-long cells (>{max_w}B) force their row to the boxed fallback path.
+    `widths` (one a column) builds each leaf at that width directly, so
+    that nothing pads it later; a cell wider than its column's width boxes
+    its row too. Without it a leaf is as wide as the slice's widest cell.
     """
     n = table.num_rows
+    cut = _native_leaves(table, n, max_w, widths) if widths and n else None
+    leaves, too_long_rows = cut if cut is not None else \
+        _leaves_by_column(table, n, max_w, widths)
+    part = C.Partition(schema=schema, num_rows=n, leaves=leaves,
+                       start_index=start_index)
+    if too_long_rows is not None and too_long_rows.any():
+        fallback = {}
+        for i in np.nonzero(too_long_rows)[0].tolist():
+            fallback[i] = tuple(col[i].as_py() for col in table.columns)
+        part.normal_mask = ~too_long_rows
+        part.fallback = fallback
+    return part
+
+
+def _leaves_by_column(table, n: int, max_w: int, widths):
+    """(leaves, the rows a too-long cell boxes) of an Arrow table slice,
+    a column at a time: contiguous large_string first, then
+    `C.arrow_string_to_leaf` (native loop or numpy gather)."""
+    import pyarrow as pa
+
     leaves: dict[str, C.Leaf] = {}
     too_long_rows = np.zeros(n, dtype=np.bool_)
-    col_arrays = []
     for ci in range(table.num_columns):
         arr = table.column(ci).combine_chunks()
-        col_arrays.append(arr)
-
-    for ci, arr in enumerate(col_arrays):
-        import pyarrow as pa
-
         if isinstance(arr, pa.ChunkedArray):
             arr = arr.combine_chunks()
         arr = arr.cast(pa.large_string())
         valid = np.ones(n, dtype=np.bool_)
         if arr.null_count:
             valid = np.asarray(arr.is_valid())
-        leaf, full_lens = C.arrow_string_to_leaf(arr, n, max_w, valid,
-                                                 return_full_lens=True)
+        leaf, full_lens = C.arrow_string_to_leaf(
+            arr, n, max_w, valid, return_full_lens=True,
+            width=widths[ci] if widths else 0)
         # rows with over-long cells keep their slot but box via fallback
-        too_long_rows |= full_lens > max_w
+        too_long_rows |= full_lens > (min(max_w, widths[ci]) if widths
+                                      else max_w)
         leaves[str(ci)] = leaf
+    return leaves, too_long_rows
 
-    part = C.Partition(schema=schema, num_rows=n, leaves=leaves,
-                       start_index=start_index)
-    if too_long_rows.any():
-        mask = ~too_long_rows
-        fallback = {}
-        for i in np.nonzero(too_long_rows)[0].tolist():
-            fallback[i] = tuple(
-                (a[i].as_py() if a[i].is_valid else None)
-                for a in col_arrays)
-        part.normal_mask = mask
-        part.fallback = fallback
-    return part
+
+def _native_leaves(table, n: int, max_w: int, widths):
+    """Every leaf of an Arrow table slice at its planned width from ONE
+    native call (`cut_strings`), straight off the chunks the slice touches:
+    no `combine_chunks`, no offsets cast, and the interpreter lock let go
+    once a partition — on the prefetch thread each such handoff queues
+    behind the job thread for up to a switch interval, and the per-leaf
+    route makes four a leaf (numpy lets the lock go too, to fill or
+    reduce an array of this size: the validity masks are made without
+    it). Returns (leaves, the rows a too-long cell boxes or None for no
+    such row), or None where the per-leaf route has to do it (no native
+    module, a column with nulls or of another type)."""
+    import pyarrow as pa
+
+    from ..native import get as _native_get
+
+    nat = _native_get()
+    if nat is None or not hasattr(nat, "cut_strings"):
+        return None
+    cols = []
+    for col, w in zip(table.columns, widths):
+        large = pa.types.is_large_string(col.type)
+        if col.null_count or not (large or pa.types.is_string(col.type)):
+            return None
+        pieces = []
+        for chunk in col.chunks:
+            if len(chunk):
+                bufs = chunk.buffers()
+                pieces.append((bufs[2] if bufs[2] else b"", bufs[1],
+                               chunk.offset, len(chunk), large))
+        cols.append((pieces, w))
+    mats, over = nat.cut_strings(cols, n, max_w)
+    # a buffer a leaf, as the per-leaf route leaves them: one buffer a
+    # partition with the leaves as views of it read 22% for q19 where this
+    # reads 29% (staging from the views is dearer; PERF.md, PR 32)
+    leaves = {str(ci): C.StrLeaf(
+        np.frombuffer(mat, dtype=np.uint8).reshape(n, w),
+        np.frombuffer(lens, dtype=np.int32),
+        np.frombuffer(bytearray(b"\x01") * n, dtype=np.bool_))
+        for ci, ((mat, lens), w) in enumerate(zip(mats, widths))}
+    return leaves, None if over is None else \
+        np.frombuffer(over, dtype=np.bool_)
 
 
 class TextSourceOperator(L.LogicalOperator):

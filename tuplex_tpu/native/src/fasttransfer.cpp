@@ -201,10 +201,14 @@ static PyObject *encode_str(PyObject *, PyObject *arg) {
 // int32 lens + unclamped int64 lens. The hot half of CSV/ORC ingestion
 // (python fallback: runtime/columns.py arrow_string_to_leaf's fancy-index
 // gather builds an [n, w] index matrix first — this is one pass of memcpy).
+// `width` > 0 is the matrix's width, decided by the caller before any slice
+// was built (a CSV source's planned width); 0 derives it from the slice,
+// w = min(widest cell, maxw). A cell is clamped to min(w, maxw) either way.
 static PyObject *offsets_to_matrix(PyObject *, PyObject *args) {
   Py_buffer data, offs;
-  Py_ssize_t n, aoff, maxw;
-  if (!PyArg_ParseTuple(args, "y*y*nnn", &data, &offs, &n, &aoff, &maxw))
+  Py_ssize_t n, aoff, maxw, width = 0;
+  if (!PyArg_ParseTuple(args, "y*y*nnn|n", &data, &offs, &n, &aoff, &maxw,
+                        &width))
     return nullptr;
   if (maxw < 0) maxw = 0;  // python fallback: w = min(max_len, maxw) >= 0
   if (offs.len < static_cast<Py_ssize_t>((aoff + n + 1) * 8) ||
@@ -215,12 +219,16 @@ static PyObject *offsets_to_matrix(PyObject *, PyObject *args) {
     return nullptr;
   }
   const int64_t *off = reinterpret_cast<const int64_t *>(offs.buf) + aoff;
-  int64_t wmax = 1;
-  for (Py_ssize_t i = 0; i < n; i++) {
-    int64_t li = off[i + 1] - off[i];
-    if (li > wmax) wmax = li;
+  Py_ssize_t w = width;
+  if (w <= 0) {
+    int64_t wmax = 1;
+    for (Py_ssize_t i = 0; i < n; i++) {
+      int64_t li = off[i + 1] - off[i];
+      if (li > wmax) wmax = li;
+    }
+    w = static_cast<Py_ssize_t>(wmax < maxw ? wmax : maxw);
   }
-  Py_ssize_t w = static_cast<Py_ssize_t>(wmax < maxw ? wmax : maxw);
+  const int64_t cap = w < maxw ? w : maxw;
   PyObject *mat = PyBytes_FromStringAndSize(nullptr, n * w);
   PyObject *lens_b = PyBytes_FromStringAndSize(nullptr, n * 4);
   PyObject *full_b = PyBytes_FromStringAndSize(nullptr, n * 8);
@@ -246,7 +254,7 @@ static PyObject *offsets_to_matrix(PyObject *, PyObject *args) {
       ok = false;
       break;
     }
-    int64_t c = li < w ? li : w;
+    int64_t c = li < cap ? li : cap;
     memcpy(m + i * w, src + start, static_cast<size_t>(c));
     lp[i] = static_cast<int32_t>(c);
     fp[i] = li;
@@ -262,6 +270,151 @@ static PyObject *offsets_to_matrix(PyObject *, PyObject *args) {
     return nullptr;
   }
   return Py_BuildValue("(NNNn)", mat, lens_b, full_b, w);
+}
+
+// One partition's string leaves in ONE call: every column of an Arrow table
+// slice -> its zero-padded [n, width] byte matrix + clamped int32 lens, the
+// interpreter lock released once around all of it. A thread that cuts
+// partitions beside a busy job thread (the source prefetch) pays one lock
+// handoff a partition this way, not three a leaf (`offsets_to_matrix` after
+// pyarrow's combine_chunks and cast, each of which lets the lock go and
+// queues for it again).
+//   columns: sequence of (pieces, width); pieces: sequence of
+//            (data, offsets, first, count, large) — one per chunk the slice
+//            touches: the chunk's data and offsets buffers (int64 offsets
+//            where `large`, else int32), its first cell and cell count;
+//            the counts of a column add up to n
+//   returns ([(mat_bytes, lens_bytes), ...], over_bytes): over[i] = 1 where
+//            a cell of row i is longer than min(width, maxw) (its row
+//            boxes); None in its place where no cell is
+struct CutPiece {
+  Py_buffer data, offs;
+  Py_ssize_t first, count;
+  int large;
+};
+
+static PyObject *cut_strings(PyObject *, PyObject *args) {
+  PyObject *cols_obj;
+  Py_ssize_t n, maxw;
+  if (!PyArg_ParseTuple(args, "Onn", &cols_obj, &n, &maxw)) return nullptr;
+  if (maxw < 0) maxw = 0;
+  PyObject *cols = PySequence_Fast(cols_obj, "columns must be a sequence");
+  if (!cols) return nullptr;
+  const Py_ssize_t k = PySequence_Fast_GET_SIZE(cols);
+  std::vector<CutPiece> pieces;             // every column's, in order
+  std::vector<Py_ssize_t> n_pieces(k), widths(k);
+  PyObject *out = PyList_New(0);
+  PyObject *over = n >= 0 ? PyBytes_FromStringAndSize(nullptr, n) : nullptr;
+  bool ok = out && over;
+  for (Py_ssize_t ci = 0; ok && ci < k; ci++) {
+    PyObject *pcs_obj;
+    Py_ssize_t w;
+    if (!PyArg_ParseTuple(PySequence_Fast_GET_ITEM(cols, ci), "On", &pcs_obj,
+                          &w)) {
+      ok = false;
+      break;
+    }
+    PyObject *pcs = PySequence_Fast(pcs_obj, "pieces must be a sequence");
+    if (!pcs) {
+      ok = false;
+      break;
+    }
+    Py_ssize_t rows = 0;
+    n_pieces[ci] = PySequence_Fast_GET_SIZE(pcs);
+    widths[ci] = w;
+    for (Py_ssize_t pi = 0; ok && pi < n_pieces[ci]; pi++) {
+      CutPiece p;
+      if (!PyArg_ParseTuple(PySequence_Fast_GET_ITEM(pcs, pi), "y*y*nnp",
+                            &p.data, &p.offs, &p.first, &p.count, &p.large)) {
+        ok = false;
+        break;
+      }
+      pieces.push_back(p);
+      const Py_ssize_t osz = p.large ? 8 : 4;
+      if (p.first < 0 || p.count < 0 ||
+          p.offs.len < (p.first + p.count + 1) * osz) {
+        PyErr_SetString(PyExc_ValueError, "offsets buffer too small");
+        ok = false;
+      }
+      rows += p.count;
+    }
+    Py_DECREF(pcs);
+    if (ok && (rows != n || w < 1)) {
+      PyErr_SetString(PyExc_ValueError,
+                      "a column's pieces must hold n cells, at a width >= 1");
+      ok = false;
+    }
+    if (ok) {
+      PyObject *mat = PyBytes_FromStringAndSize(nullptr, n * w);
+      PyObject *lens = PyBytes_FromStringAndSize(nullptr, n * 4);
+      PyObject *pair = (mat && lens) ? PyTuple_Pack(2, mat, lens) : nullptr;
+      Py_XDECREF(mat);
+      Py_XDECREF(lens);
+      if (!pair || PyList_Append(out, pair) < 0) ok = false;
+      Py_XDECREF(pair);
+    }
+  }
+  Py_DECREF(cols);
+  if (ok) {
+    // the buffers' addresses, taken with the lock held
+    std::vector<char *> mats(k);
+    std::vector<int32_t *> lens(k);
+    for (Py_ssize_t ci = 0; ci < k; ci++) {
+      PyObject *pair = PyList_GET_ITEM(out, ci);
+      mats[ci] = PyBytes_AS_STRING(PyTuple_GET_ITEM(pair, 0));
+      lens[ci] = reinterpret_cast<int32_t *>(
+          PyBytes_AS_STRING(PyTuple_GET_ITEM(pair, 1)));
+    }
+    char *ov = PyBytes_AS_STRING(over);
+    bool any_over = false;
+    Py_BEGIN_ALLOW_THREADS;
+    memset(ov, 0, static_cast<size_t>(n));
+    size_t at = 0;
+    for (Py_ssize_t ci = 0; ok && ci < k; ci++) {
+      const Py_ssize_t w = widths[ci];
+      const int64_t cap = w < maxw ? w : maxw;
+      char *m = mats[ci];
+      int32_t *lp = lens[ci];
+      memset(m, 0, static_cast<size_t>(n * w));
+      Py_ssize_t row = 0;
+      for (Py_ssize_t pi = 0; ok && pi < n_pieces[ci]; pi++, at++) {
+        const CutPiece &p = pieces[at];
+        const char *src = reinterpret_cast<const char *>(p.data.buf);
+        const int64_t *o64 = reinterpret_cast<const int64_t *>(p.offs.buf);
+        const int32_t *o32 = reinterpret_cast<const int32_t *>(p.offs.buf);
+        for (Py_ssize_t i = p.first; i < p.first + p.count; i++, row++) {
+          const int64_t start = p.large ? o64[i] : o32[i];
+          const int64_t li = (p.large ? o64[i + 1] : o32[i + 1]) - start;
+          if (start < 0 || li < 0 || start + li > p.data.len) {
+            ok = false;
+            break;
+          }
+          const int64_t c = li < cap ? li : cap;
+          memcpy(m + row * w, src + start, static_cast<size_t>(c));
+          lp[row] = static_cast<int32_t>(c);
+          if (li > cap) ov[row] = 1, any_over = true;
+        }
+      }
+    }
+    Py_END_ALLOW_THREADS;
+    if (!ok) PyErr_SetString(PyExc_ValueError, "offsets out of data bounds");
+    if (ok && !any_over) {
+      Py_DECREF(over);
+      over = Py_NewRef(Py_None);
+    }
+  }
+  for (auto &p : pieces) {
+    PyBuffer_Release(&p.data);
+    PyBuffer_Release(&p.offs);
+  }
+  if (!ok) {
+    Py_XDECREF(out);
+    Py_XDECREF(over);
+    if (!PyErr_Occurred())
+      PyErr_SetString(PyExc_ValueError, "cut_strings: bad arguments");
+    return nullptr;
+  }
+  return Py_BuildValue("(NN)", out, over);
 }
 
 static PyObject *decode_str(PyObject *, PyObject *args) {
@@ -624,6 +777,8 @@ static PyMethodDef Methods[] = {
     {"encode_str", encode_str, METH_O, "bulk encode str column"},
     {"offsets_to_matrix", offsets_to_matrix, METH_VARARGS,
      "arrow offsets+data -> padded byte matrix"},
+    {"cut_strings", cut_strings, METH_VARARGS,
+     "an arrow table slice's string columns -> padded byte matrices"},
     {"decode_str", decode_str, METH_VARARGS, "bulk decode str column"},
     {"decode_columns", decode_columns, METH_VARARGS,
      "typed column buffers -> list of row tuples"},

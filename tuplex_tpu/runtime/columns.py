@@ -459,6 +459,25 @@ class Partition:
         return total
 
 
+class PartitionStream:
+    """Partitions whose shapes were decided before one of them was built.
+
+    `template` is the zero-row partition at the planned schema and leaf
+    widths, `rows` the row count of every partition to come, in order:
+    what a compile needs to know of a dispatch batch, with no batch built
+    (`compiler/stagefn.partition_avals(template, mode, rows=m)`). Iterating
+    builds the partitions, one a pull, once."""
+
+    def __init__(self, template: Partition, rows: Sequence[int],
+                 parts: Iterable[Partition]):
+        self.template = template
+        self.rows = list(rows)
+        self._parts = parts
+
+    def __iter__(self):
+        return iter(self._parts)
+
+
 def build_partition(
     values: Sequence[Any],
     schema: T.RowType,
@@ -1327,11 +1346,14 @@ def _partition_with_fallback(schema: T.RowType, n: int, leaves: dict,
 
 def arrow_string_to_leaf(arr, n: int, max_w: int,
                          valid: Optional[np.ndarray] = None,
-                         return_full_lens: bool = False):
+                         return_full_lens: bool = False, width: int = 0):
     """Arrow large_string array -> fixed-width byte-matrix leaf (vectorized
     offsets gather; shared by the CSV and ORC sources). With
     return_full_lens, also returns the UNCLAMPED byte lengths so callers can
-    detect over-long cells without re-reading the buffers."""
+    detect over-long cells without re-reading the buffers. `width` > 0 is
+    the matrix's width, decided before this slice was looked at (a CSV
+    source's planned width: no pad pass afterwards); 0 derives it from the
+    slice's widest cell. A cell is clamped to min(width, max_w)."""
     buffers = arr.buffers()
     from ..native import get as _native_get
 
@@ -1339,7 +1361,7 @@ def arrow_string_to_leaf(arr, n: int, max_w: int,
     if nat is not None and hasattr(nat, "offsets_to_matrix") and n:
         mat_b, lens_b, full_b, w = nat.offsets_to_matrix(
             buffers[2] if buffers[2] else b"", buffers[1], n, arr.offset,
-            max_w)
+            max_w, width)
         mat = np.frombuffer(mat_b, dtype=np.uint8).reshape(n, w)
         leaf = StrLeaf(mat, np.frombuffer(lens_b, dtype=np.int32), valid)
         if return_full_lens:
@@ -1351,12 +1373,14 @@ def arrow_string_to_leaf(arr, n: int, max_w: int,
         else np.zeros(0, np.uint8)
     starts = offsets[:-1]
     lens = (offsets[1:] - starts).astype(np.int64)
-    w = int(min(max(int(lens.max()) if n else 1, 1), max_w))
+    w = width if width > 0 else \
+        int(min(max(int(lens.max()) if n else 1, 1), max_w))
+    cap = min(w, max(max_w, 0))
     idx = starts[:, None] + np.arange(w, dtype=np.int64)[None, :]
     np.clip(idx, 0, max(len(data) - 1, 0), out=idx)
     mat = data[idx] if len(data) else np.zeros((n, w), np.uint8)
     keep = np.arange(w, dtype=np.int64)[None, :] < \
-        np.minimum(lens, w)[:, None]
+        np.minimum(lens, cap)[:, None]
     mat = np.where(keep, mat, 0).astype(np.uint8)
-    leaf = StrLeaf(mat, np.minimum(lens, w).astype(np.int32), valid)
+    leaf = StrLeaf(mat, np.minimum(lens, cap).astype(np.int32), valid)
     return (leaf, lens) if return_full_lens else leaf

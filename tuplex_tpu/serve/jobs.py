@@ -381,7 +381,8 @@ class _JobRunner:
             src = TuplexFileSourceOperator(self.options, indir)
             return src.load_partitions(self.ctx)
         if getattr(stage, "source", None) is not None:
-            return _source_partitions(self.ctx, stage, lazy=False)
+            # a list: the canary below reads the same inputs a second time
+            return list(_source_partitions(self.ctx, stage, lazy=False))
         return self.partitions      # mid-pipeline: previous stage's output
 
     # ------------------------------------------------------------------
