@@ -753,7 +753,10 @@ class LocalBackend:
         mm_snap = self.mm.metrics_snapshot()
         fl_snap = len(self.failure_log)
         metrics: dict[str, Any] = {"fast_path_s": 0.0, "slow_path_s": 0.0,
-                                   "general_path_s": 0.0, "compile_s": 0.0}
+                                   "general_path_s": 0.0, "compile_s": 0.0,
+                                   # partitions run again without compaction
+                                   # after their bucket overflowed
+                                   "compaction_reruns": 0}
         if EX.enabled():
             # exception-plane baseline (runtime/excprof): snapshot the
             # plan-time code inventory + resolve-plan verdict BEFORE any
@@ -933,6 +936,7 @@ class LocalBackend:
             metrics["fast_path_s"] += m.get("fast_path_s", 0.0)
             metrics["slow_path_s"] += m.get("slow_path_s", 0.0)
             metrics["general_path_s"] += m.get("general_path_s", 0.0)
+            metrics["compaction_reruns"] += m.get("compaction_reruns", 0)
             exceptions.extend(excs)
             if limit >= 0 and emitted_total + outp.num_rows > limit:
                 outp = _truncate_partition(outp, limit - emitted_total)
@@ -1288,7 +1292,8 @@ class LocalBackend:
         # retry -> degrade ladder a real device failure takes
         t0 = time.perf_counter()
         with TR.span("partition:dispatch", "exec") as _sp:
-            _sp.set("rows", part.num_rows).set("start", part.start_index)
+            _sp.set("rows", part.num_rows).set("start", part.start_index) \
+                .set("compacted", int(use_comp))
             with TR.span("h2d:leaf-stage", "xfer") as _hsp:
                 batch = C.stage_partition(part, self.bucket_mode)
                 leaf_h2d = 0
@@ -1502,6 +1507,9 @@ class LocalBackend:
                                  if not k.startswith("#")}
                 else:
                     outs = _get_outs(pending_outs)
+                # whether the function that ran compacted this batch (its
+                # plan may be empty where `partition:dispatch` says 1)
+                _sp.set("compacted", int("#rowidx" in outs))
             rowidx = outs.pop("#rowidx", None)
             ovf = outs.pop("#overflow", None)
             if rowidx is not None and bool(np.asarray(ovf)):
@@ -1516,6 +1524,7 @@ class LocalBackend:
                     "compaction bucket overflow (stage %s); re-running "
                     "partition without compaction", stage.key()[:8])
                 self._compaction_off.add(stage.key())
+                metrics["compaction_reruns"] = 1
                 packed = not intermediate   # keep the handoff's dict outs
                 nkey = ("stagefn", stage.key() + "/" + part.schema.name,
                         False, packed)
@@ -1676,7 +1685,10 @@ class LocalBackend:
         t0 = time.perf_counter()
         if fallback_idx:
             with TR.span("resolve:interpreter", "exec") as _sp:
-                _sp.set("rows", len(fallback_idx))
+                # `boxed`: rows boxed at ingest, which never rode the
+                # columnar path (the rest fell here off the device)
+                _sp.set("rows", len(fallback_idx)).set(
+                    "boxed", len(fallback_idx & part.fallback.keys()))
                 pipeline = stage.python_pipeline(part.user_columns)
                 order = sorted(fallback_idx)
                 ex_on = EX.enabled()

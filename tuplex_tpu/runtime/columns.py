@@ -234,21 +234,34 @@ def matrix_to_varlen(mat: np.ndarray,
     return np.ascontiguousarray(mat[:n])[keep], ln
 
 
+# rows a block of `varlen_to_matrix`: the index block of the widest string
+# column stays under 2 MB, which the allocator hands back from its heap;
+# whole-column temporaries (12 bytes an element, 60 MB for one 48-byte column
+# of a 114,688-row batch) were mapped fresh and faulted in page by page on
+# every call, or not, as the heap's state had it: jobs of one process ran
+# 0.6 s apart from the next one's (PERF.md section 6, PR 33)
+_VARLEN_BLOCK_ROWS = 4096
+
+
 def varlen_to_matrix(payload: np.ndarray, offs: np.ndarray,
                      lens: np.ndarray, w: int) -> np.ndarray:
     """(payload, per-row offsets, lengths) -> [N, w] zero-padded byte
-    matrix (vectorized gather — same technique as arrow_string_to_leaf)."""
+    matrix: a gather straight into the result, a block of rows at a time
+    (the technique of arrow_string_to_leaf)."""
     n = len(lens)
     mat = np.zeros((n, max(w, 1)), np.uint8)
     if n == 0 or w <= 0 or len(payload) == 0:
         return mat
     ln = np.clip(np.asarray(lens, dtype=np.int64), 0, w)
-    idx = np.asarray(offs, dtype=np.int64)[:, None] + \
-        np.arange(w, dtype=np.int64)[None, :]
-    np.clip(idx, 0, len(payload) - 1, out=idx)
-    g = np.asarray(payload, dtype=np.uint8)[idx]
-    keep = np.arange(w, dtype=np.int64)[None, :] < ln[:, None]
-    return np.where(keep, g, 0).astype(np.uint8)
+    offs = np.asarray(offs, dtype=np.int64)
+    payload = np.asarray(payload, dtype=np.uint8)
+    cols = np.arange(w, dtype=np.int64)[None, :]
+    for a in range(0, n, _VARLEN_BLOCK_ROWS):
+        out = mat[a:a + _VARLEN_BLOCK_ROWS]
+        rows = slice(a, a + len(out))
+        np.take(payload, offs[rows, None] + cols, mode="clip", out=out)
+        out[cols >= ln[rows, None]] = 0
+    return mat
 
 
 # ---------------------------------------------------------------------------
