@@ -245,3 +245,179 @@ def test_strleaf_wire_view_roundtrip():
     np.testing.assert_array_equal(back.lengths, leaf.lengths)
     for i in range(5):
         assert C.decode_str(back, i) == C.decode_str(leaf, i)
+
+
+# ---------------------------------------------------------------------------
+# the unpack's two routes: one native call a partition (`unpack_varlen`),
+# `columns.varlen_to_matrix` an entry where the module is not loaded
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(params=[True, False], ids=["native", "no-native"])
+def unpack_route(request, monkeypatch):
+    """Steer `native.get()` as `TUPLEX_TPU_NO_NATIVE` does at a process's
+    start; hands back whether the native call is the route."""
+    from tuplex_tpu import native as N
+
+    if not request.param:
+        monkeypatch.setenv("TUPLEX_TPU_NO_NATIVE", "1")
+        monkeypatch.setattr(N, "_mod", None)
+        monkeypatch.setattr(N, "_tried", False)
+        assert N.get() is None
+    elif N.get() is None or not hasattr(N.get(), "unpack_varlen"):
+        pytest.skip("no compiler available")
+    return request.param
+
+
+def _lattice(n, dead=()):
+    """The stage lattice that gives the packer a `#live` mask: every row
+    valid, kept and clean but `dead`, which fail one of the three by
+    turns (the `#err` codes of those that fail it ride `sparse32`)."""
+    keep = np.ones(n, np.bool_)
+    err = np.zeros(n, np.int32)
+    rowvalid = np.ones(n, np.bool_)
+    for j, i in enumerate(dead):
+        if j % 3 == 0:
+            keep[i] = False
+        elif j % 3 == 1:
+            err[i] = 7 | (300 + j) << 8
+        else:
+            rowvalid[i] = False
+    return {"#keep": keep, "#err": err, "#rowvalid": rowvalid}
+
+
+def _unpack_case(case):
+    """(arrays, the arrays `to_host` must give back): the wire drops what
+    lies past a row's clamped length and every varlen byte of a dead row;
+    a padding row's `#err` code is noise and reads 0."""
+    rng = np.random.default_rng(len(case))
+    n, w = 300, 24
+    mat, lens = _str_matrix(rng, n, w)
+    if case == "empty-strings":
+        lens[::3] = 0
+        mat[::3] = 0
+        return ({"0#bytes": mat, "0#len": lens},) * 2
+    if case == "max-width-rows":
+        lens[:] = w
+        mat = rng.integers(1, 256, (n, w), np.uint8)
+        return ({"0#bytes": mat, "0#len": lens},) * 2
+    if case == "length-past-w":
+        # past the width but inside the narrowed u8 wire: clamped to the
+        # width on the device and on the host alike, `#len` left as it is
+        mat = rng.integers(1, 256, (n, w), np.uint8)
+        lens[:] = w
+        lens[5], lens[6] = 30, 255
+        return ({"0#bytes": mat, "0#len": lens},) * 2
+    if case == "zero-rows":
+        return ({"0#bytes": np.zeros((0, w), np.uint8),
+                 "0#len": np.zeros(0, np.int32),
+                 "1": np.zeros(0, np.int64)},) * 2
+    if case == "all-empty-column":
+        mat2, lens2 = _str_matrix(rng, n, 8)
+        return ({"0#bytes": np.zeros((n, w), np.uint8),
+                 "0#len": np.zeros(n, np.int32),
+                 "1#bytes": mat2, "1#len": lens2},) * 2
+    dead = list(range(2, n, 7)) if case == "live-mask" else [1, 2, 3, 298]
+    arrays = {"0#bytes": mat, "0#len": lens, **_lattice(n, dead)}
+    if case == "all-four-kinds":
+        mat2, lens2 = _str_matrix(rng, n, 1)
+        i64 = rng.integers(-2**62, 2**62, n)       # `hi32` and `lo32v`
+        i64[::2] = rng.integers(-9, 9, n // 2 + n % 2)
+        u64 = rng.integers(0, 2**63, n).astype(np.uint64) * np.uint64(2)
+        arrays.update({"1#bytes": mat2, "1#len": lens2, "2": i64, "3": u64})
+    want = {k: v.copy() for k, v in arrays.items()}
+    for k in ("0#bytes", "1#bytes", "2", "3"):
+        if k in want:
+            want[k][dead] = 0
+    want["#err"][~arrays["#rowvalid"]] = 0
+    return arrays, want
+
+
+_UNPACK_CASES = ["empty-strings", "max-width-rows", "length-past-w",
+                 "live-mask", "zero-rows", "all-empty-column",
+                 "all-four-kinds"]
+
+
+@pytest.mark.parametrize("case", _UNPACK_CASES)
+def test_varlen_unpack_gives_the_same_bytes_on_both_routes(
+        unpack_route, case):
+    from tuplex_tpu import native as N
+
+    arrays, want = _unpack_case(case)
+    out, got = _varlen_roundtrip(arrays)
+    kinds = {kind for kind, _k, _shape, _dt in out.vspec}
+    assert "str" in kinds
+    if case == "all-four-kinds":
+        assert kinds == {"str", "hi32", "lo32v", "sparse32"}
+    assert set(got) == set(want)
+    for k, w in want.items():
+        g = np.asarray(got[k])
+        assert g.dtype == w.dtype and g.shape == w.shape, k
+        np.testing.assert_array_equal(g, w, err_msg=k)
+        if k.endswith("#bytes"):
+            assert g.flags.writeable and g.flags.c_contiguous, k
+    if unpack_route:
+        # and byte for byte what the numpy twin makes of the same buffers
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(N, "_mod", None)
+            mp.setattr(N, "_tried", True)
+            twin = out.to_host()
+        for k in got:
+            assert np.asarray(twin[k]).tobytes() == \
+                np.asarray(got[k]).tobytes(), k
+
+
+def test_varlen_unpack_span_says_which_route_built_the_matrices(
+        unpack_route):
+    from tuplex_tpu.runtime import tracing
+
+    arrays, _ = _unpack_case("all-four-kinds")
+    out, _ = _varlen_roundtrip(arrays)
+    tracing.clear()
+    tracing.enable(True)
+    try:
+        out.to_host()
+    finally:
+        tracing.enable(False)
+    found = [e for e in tracing.events() if e["name"] == "d2h:varlen-unpack"]
+    tracing.clear()
+    (sp,) = found
+    assert sp["args"]["native"] == int(unpack_route)
+    assert sp["args"]["entries"] == len(out.vspec) == 7
+    assert sp["args"]["bytes"] > 0
+
+
+@pytest.mark.parametrize("fault", ["past-the-payload", "length-past-width",
+                                   "negative-length", "short-output",
+                                   "read-only-output"])
+def test_native_unpack_varlen_refuses_what_it_cannot_read_in_bounds(fault):
+    """The native entry never reads past the payload nor writes past a
+    matrix: lengths that sum past the payload's end, or lie outside
+    [0, width], raise `ValueError` (the twin clips its reads)."""
+    from tuplex_tpu import native as N
+
+    nat = N.get()
+    if nat is None or not hasattr(nat, "unpack_varlen"):
+        pytest.skip("no compiler available")
+    payload = np.arange(1, 41, dtype=np.uint8)
+    lens = np.array([4, 0, 8, 3], np.int64)
+    out = np.full((4, 8), 0xEE, np.uint8)
+    second = (np.array([8, 8, 8], np.int64), 8, np.empty((3, 8), np.uint8))
+    assert nat.unpack_varlen(payload, [(lens, 8, out), second]) == 39
+    assert out[0].tolist() == [1, 2, 3, 4, 0, 0, 0, 0]
+    assert not out[1].any() and out[2].tolist() == list(range(5, 13))
+    assert second[2][2].tolist() == list(range(32, 40))
+    if fault == "past-the-payload":
+        args = (payload[:38], [(lens, 8, out), second])
+    elif fault == "length-past-width":
+        args = (payload, [(np.array([4, 9], np.int64), 8, out[:2])])
+    elif fault == "negative-length":
+        args = (payload, [(np.array([4, -1], np.int64), 8, out[:2])])
+    elif fault == "short-output":
+        args = (payload, [(lens, 8, out[:3])])
+    else:
+        frozen = out.copy()
+        frozen.flags.writeable = False
+        args = (payload, [(lens, 8, frozen)])
+    with pytest.raises(TypeError if fault == "read-only-output"
+                       else ValueError):
+        nat.unpack_varlen(*args)

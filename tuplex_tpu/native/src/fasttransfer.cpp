@@ -417,6 +417,101 @@ static PyObject *cut_strings(PyObject *, PyObject *args) {
   return Py_BuildValue("(NN)", out, over);
 }
 
+// One partition's varlen payload (runtime/packing.PackedOuts) in ONE call:
+// the rows of every entry lie back to back in `payload`, entry after entry,
+// so the running offset is all the state there is — no cumsum, no offsets
+// array — and the interpreter lock is released once around the partition.
+//   entries: sequence of (lens, width, out) — lens: n int64 per-row byte
+//            counts, each in [0, width]; out: a writable buffer of
+//            n * max(width, 1) bytes, filled as the zero-padded
+//            [n, max(width, 1)] matrix (every byte of it is written)
+//   returns the payload bytes consumed; ValueError where a length lies
+//            outside [0, width] or the rows run past the payload's end
+struct VarlenEntry {
+  Py_buffer lens, out;
+  Py_ssize_t w;
+};
+
+static PyObject *unpack_varlen(PyObject *, PyObject *args) {
+  Py_buffer payload;
+  PyObject *entries_obj;
+  if (!PyArg_ParseTuple(args, "y*O", &payload, &entries_obj)) return nullptr;
+  PyObject *seq = PySequence_Fast(entries_obj, "entries must be a sequence");
+  if (!seq) {
+    PyBuffer_Release(&payload);
+    return nullptr;
+  }
+  const Py_ssize_t k = PySequence_Fast_GET_SIZE(seq);
+  std::vector<VarlenEntry> entries;
+  entries.reserve(static_cast<size_t>(k));
+  bool ok = true;
+  for (Py_ssize_t ei = 0; ok && ei < k; ei++) {
+    VarlenEntry e;
+    PyObject *item = PySequence_Fast_GET_ITEM(seq, ei);
+    if (!PyTuple_Check(item)) {
+      PyErr_SetString(PyExc_TypeError, "an entry is a (lens, width, out)");
+      ok = false;
+      break;
+    }
+    if (!PyArg_ParseTuple(item, "y*nw*", &e.lens, &e.w, &e.out)) {
+      ok = false;
+      break;
+    }
+    entries.push_back(e);
+    const Py_ssize_t n = e.lens.len / 8;
+    if (e.w < 0 || e.lens.len % 8 || e.out.len != n * (e.w > 0 ? e.w : 1)) {
+      PyErr_SetString(PyExc_ValueError,
+                      "an entry needs n int64 lengths, a width >= 0 and "
+                      "n * max(width, 1) bytes of output");
+      ok = false;
+    }
+  }
+  Py_DECREF(seq);
+  int64_t at = 0;
+  if (ok) {
+    const char *src = reinterpret_cast<const char *>(payload.buf);
+    const int64_t end = payload.len;
+    Py_BEGIN_ALLOW_THREADS;
+    for (size_t ei = 0; ok && ei < entries.size(); ei++) {
+      const VarlenEntry &e = entries[ei];
+      const int64_t w = e.w;
+      const Py_ssize_t n = e.lens.len / 8;
+      const int64_t *lp = reinterpret_cast<const int64_t *>(e.lens.buf);
+      char *m = reinterpret_cast<char *>(e.out.buf);
+      if (w == 0) memset(m, 0, static_cast<size_t>(n));
+      for (Py_ssize_t i = 0; i < n; i++, m += w) {
+        const int64_t li = lp[i];
+        if (li < 0 || li > w || li > end - at) {
+          ok = false;
+          break;
+        }
+        if (w == 4 && !(li & 3)) {
+          // the 4-byte kinds (a word or nothing): a load and a store
+          uint32_t word = 0;
+          if (li) memcpy(&word, src + at, 4);
+          memcpy(m, &word, 4);
+        } else {
+          memcpy(m, src + at, static_cast<size_t>(li));
+          memset(m + li, 0, static_cast<size_t>(w - li));
+        }
+        at += li;
+      }
+    }
+    Py_END_ALLOW_THREADS;
+    if (!ok)
+      PyErr_SetString(PyExc_ValueError,
+                      "a length outside [0, width], or rows past the "
+                      "payload's end");
+  }
+  for (auto &e : entries) {
+    PyBuffer_Release(&e.lens);
+    PyBuffer_Release(&e.out);
+  }
+  PyBuffer_Release(&payload);
+  if (!ok) return nullptr;
+  return PyLong_FromLongLong(at);
+}
+
 static PyObject *decode_str(PyObject *, PyObject *args) {
   PyObject *mat_obj, *lens_obj;
   Py_ssize_t w, n;
@@ -779,6 +874,8 @@ static PyMethodDef Methods[] = {
      "arrow offsets+data -> padded byte matrix"},
     {"cut_strings", cut_strings, METH_VARARGS,
      "an arrow table slice's string columns -> padded byte matrices"},
+    {"unpack_varlen", unpack_varlen, METH_VARARGS,
+     "a packed partition's varlen payload -> padded byte matrices"},
     {"decode_str", decode_str, METH_VARARGS, "bulk decode str column"},
     {"decode_columns", decode_columns, METH_VARARGS,
      "typed column buffers -> list of row tuples"},

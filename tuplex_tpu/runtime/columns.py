@@ -234,7 +234,12 @@ def matrix_to_varlen(mat: np.ndarray,
     return np.ascontiguousarray(mat[:n])[keep], ln
 
 
-# rows a block of `varlen_to_matrix`: the index block of the widest string
+# `varlen_to_matrix` is the fallback's path since PR 34: the packed wire's
+# unpack is one native call a partition by default
+# (`runtime/packing._varlen_matrices` -> `unpack_varlen` of
+# native/src/fasttransfer.cpp), and this gather serves where the module is
+# not loaded (`TUPLEX_TPU_NO_NATIVE`, no compiler) and `StrLeaf.from_wire`.
+# Rows a block of it: the index block of the widest string
 # column stays under 2 MB, which the allocator hands back from its heap;
 # whole-column temporaries (12 bytes an element, 60 MB for one 48-byte column
 # of a 114,688-row batch) were mapped fresh and faulted in page by page on

@@ -499,8 +499,10 @@ class PackedOuts:
             fetched = host.nbytes
             if self.vspec:
                 with TR.span("d2h:varlen-unpack", "xfer") as _vsp:
-                    vb = self._unpack_varlen(out)
+                    vb, native = self._unpack_varlen(out)
                     _vsp.set("bytes", vb)
+                    _vsp.set("native", int(native))
+                    _vsp.set("entries", len(self.vspec))
                 fetched += vb
             if self.extras:
                 ex = jax.device_get(self.extras)
@@ -517,16 +519,15 @@ class PackedOuts:
                   flush=True)
         return out
 
-    def _unpack_varlen(self, out: dict) -> int:
+    def _unpack_varlen(self, out: dict) -> tuple:
         """Fetch payload[:total] and rebuild every varlen entry in place
         — str byte matrices, i64 high words, sparse '#err' codes. The
         per-row lengths re-derive deterministically from the fixed buffer
         (shipped lens / '#need' bitmaps), so no offsets travel. Returns
-        bytes fetched."""
-        from .columns import varlen_to_matrix
-
+        (bytes fetched, whether the native call built the matrices)."""
         live = out.pop("#live", None)
-        lens = {}
+        live4 = None if live is None else np.asarray(live, dtype=np.int64) * 4
+        lens = []
         total = 0
         for kind, k, (b, w), dt in self.vspec:
             if kind == "str":
@@ -535,23 +536,19 @@ class PackedOuts:
                 if live is not None and live.shape == ln.shape:
                     ln = ln * live
             elif kind == "lo32v":
-                ln = np.asarray(live, dtype=np.int64) * 4
+                ln = live4
             else:
                 ln = np.asarray(out[k + "#need"],
                                 dtype=np.int64).reshape(-1) * 4
-            lens[(kind, k)] = ln
+            lens.append(ln)
             total += int(ln.sum())
         cap = int(self.vbuf.shape[0])
         want = min(_pad(total), cap) if total else 0
         payload = np.asarray(jax.device_get(self.vbuf[:want])) if want \
             else np.zeros(0, np.uint8)
-        off = 0
-        for kind, k, (b, w), dt in self.vspec:
-            ln = lens[(kind, k)]
-            offs = off + np.concatenate(
-                [[0], np.cumsum(ln, dtype=np.int64)])[:-1]
-            mat = varlen_to_matrix(payload, offs, ln, w)
-            off += int(ln.sum())
+        mats, native = _varlen_matrices(
+            payload, lens, [w for _kind, _k, (_b, w), _dt in self.vspec])
+        for (kind, k, _shape, dt), mat in zip(self.vspec, mats):
             if kind == "str":
                 out[k] = mat
                 continue
@@ -559,22 +556,52 @@ class PackedOuts:
                 np.ascontiguousarray(mat).view("<u4")[:, 0])
             if kind == "lo32v":
                 # dead rows carried no bytes -> lo 0 -> value 0 (unread)
-                out[k] = (words.astype(np.int32).astype(np.int64)
+                out[k] = (words.view(np.int32).astype(np.int64)
                           if np.dtype(dt) == np.dtype(np.int64)
-                          else words.astype(np.uint64)).astype(np.dtype(dt))
+                          else words.astype(np.uint64))
                 continue
             need = np.asarray(out.pop(k + "#need"), dtype=np.bool_)
             if kind == "sparse32":
                 out[k] = np.where(need, words.view("<i4"),
-                                  0).astype(np.dtype(dt))
-            else:   # hi32: patch the rows whose high word isn't the
-                    # low word's sign/zero extension
+                                  0).astype(np.dtype(dt), copy=False)
+            elif need.any():
+                # hi32: patch the rows whose high word isn't the low
+                # word's sign/zero extension
                 base = np.asarray(out[k]).view(np.uint64)
                 lo = base & np.uint64(0xFFFFFFFF)
                 full = lo | (words.astype(np.uint64) << np.uint64(32))
                 out[k] = np.where(need, full,
                                   base).view(np.dtype(dt))
-        return payload.nbytes
+        return payload.nbytes, native
+
+
+def _varlen_matrices(payload: np.ndarray, lens: list, widths: list):
+    """The varlen payload's entries, which lie back to back in it, each as
+    its zero-padded [n, max(w, 1)] uint8 matrix: (matrices, native).
+    `lens`: an int64 array an entry, every value in [0, w]. One native call
+    a partition (`unpack_varlen`: a memcpy a row, the interpreter lock let
+    go once) where the module is loaded; `columns.varlen_to_matrix` an
+    entry, its pure-numpy twin, where it is not."""
+    from ..native import get as _native_get
+
+    nat = _native_get()
+    if nat is not None and hasattr(nat, "unpack_varlen"):
+        lens = [np.ascontiguousarray(ln, dtype=np.int64) for ln in lens]
+        mats = [np.empty((len(ln), max(w, 1)), np.uint8)
+                for ln, w in zip(lens, widths)]
+        nat.unpack_varlen(np.ascontiguousarray(payload, dtype=np.uint8),
+                          list(zip(lens, widths, mats)))
+        return mats, True
+    from .columns import varlen_to_matrix
+
+    mats = []
+    off = 0
+    for ln, w in zip(lens, widths):
+        offs = off + np.concatenate(
+            [[0], np.cumsum(ln, dtype=np.int64)])[:-1]
+        mats.append(varlen_to_matrix(payload, offs, ln, w))
+        off += int(ln.sum())
+    return mats, False
 
 
 class PackedStageFn:
