@@ -5,8 +5,10 @@ calibrated corpus (compiler/graphlint module docstring):
 
   * no CLEAN stage anywhere carries a wedge-severity finding — a false
     positive here silently degrades a healthy stage to the interpreter;
-  * the flights airport build side is pre-degraded by EXACTLY the pinned
-    rule ``wide-str-compaction`` (ROADMAP residue c);
+  * the flights airport table, cleaned with every column live, is
+    pre-degraded by EXACTLY the pinned rule ``wide-str-compaction``
+    (ROADMAP residue c; as a join's build side its two capwords operators
+    are dead since PR 35 and the shape no longer plans);
   * re-analysis of the planned flights stages finds exactly one more
     carrier of the rule — the probe-side mega-segment whose production
     compile blows even a 300 s XLA:CPU deadline (the compile plane vets
@@ -51,6 +53,8 @@ def _planned_stages(ctx, sink, tag):
 
 
 def main() -> int:
+    import string
+
     import tuplex_tpu
     from tuplex_tpu.compiler import graphlint as GL
     from tuplex_tpu.models import flights, logs, nyc311, tpch, zillow
@@ -75,6 +79,17 @@ def main() -> int:
     flights.generate_airport_db(air)
     labelled += _planned_stages(
         ctx, flights.build_pipeline(ctx, perf, car, air), "flights")
+    # the airport table cleaned as a data set of its own, every column
+    # live: under the flights joins its two capwords operators are dead
+    # and dropped with their columns (projection through joins, PR 35),
+    # so the wedging shape no longer plans there
+    airports = ctx.csv(air, columns=flights.AIRPORT_COLS, delimiter=":",
+                       header=False, null_values=["", "N/a", "N/A"])
+    airports = airports.mapColumn(
+        "AirportName", lambda x: string.capwords(x) if x else None)
+    airports = airports.mapColumn(
+        "AirportCity", lambda x: string.capwords(x) if x else None)
+    labelled += _planned_stages(ctx, airports, "flights_airports")
     tp = os.path.join(tmp, "li.csv")
     tpch.generate_csv(tp, 500, seed=4)
     labelled += _planned_stages(ctx, tpch.q6(ctx.csv(tp)), "tpch_q6")
@@ -112,7 +127,7 @@ def main() -> int:
                 f"{label}: FALSE POSITIVE wedge finding(s) {sorted(wedges)}"
     assert pre_degraded and all(lbl.startswith("flights")
                                 for lbl in pre_degraded), \
-        (f"expected the flights airport build side (and only it) "
+        (f"expected the flights airport table (and only it) "
          f"pre-degraded at plan time, got {pre_degraded}")
 
     # 2) submission-plane preview: re-analyze every planned stage the

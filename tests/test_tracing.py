@@ -602,7 +602,8 @@ def test_csv_job_yields_ingest_and_boxing_spans_under_its_job(
     assert all(e["job"] == job["id"] for e in under)
     (read,) = [e for e in under if e["name"] == "ingest:read-csv"]
     # projection pushdown: the pipeline reads one column of the three
-    assert read["args"] == {"bytes": size, "rows": 3000, "columns": 1}
+    assert read["args"] == {"bytes": size, "rows": 3000, "columns": 1,
+                            "file_columns": 3}
     (box,) = [e for e in under if e["name"] == "collect:box-rows"]
     assert box["parent"] == job["id"] and box["args"]["rows"] == 3000
     # the job span covers the boxing: it closes after its last child

@@ -28,6 +28,14 @@ import copy
 from typing import Callable
 
 _PROFILE_ROW_CAP = 1000
+# An arm counts as observed where the sample took it in at least ARM_SHARE
+# of the test's trials, and a test says anything only from MIN_TRIALS
+# trials on: a single row decides nothing. Some 700-1,000 rows are sampled,
+# so "never taken" and "taken once" are one draw apart for an event of one
+# row in a thousand, and a plan that turned on that draw would turn, and
+# compile anew, between two files of one distribution.
+MIN_TRIALS = 100
+ARM_SHARE = 0.01
 
 
 def branch_key(node: ast.AST) -> tuple:
@@ -39,11 +47,15 @@ class _WrapTests(ast.NodeTransformer):
 
     def __init__(self):
         self.keys: list[tuple] = []
+        self.worth: list[tuple] = []    # (then, else): worth pruning at all
 
     def _wrap(self, node):
         node = self.generic_visit(node)
         idx = len(self.keys)
         self.keys.append(branch_key(node))
+        self.worth.append((arm_weight(node.body) >= 1,
+                           bool(node.orelse)
+                           and arm_weight(node.orelse) >= 1))
         call = ast.Call(func=ast.Name(id="__tpx_b__", ctx=ast.Load()),
                         args=[ast.Constant(value=idx), node.test],
                         keywords=[])
@@ -55,16 +67,16 @@ class _WrapTests(ast.NodeTransformer):
     visit_IfExp = _wrap
 
 
-def _build_instrumented(udf) -> tuple[Callable, dict, list]:
+def _build_instrumented(udf) -> tuple[Callable, dict, "_WrapTests"]:
     tree = copy.deepcopy(udf.tree)
     w = _WrapTests()
     tree = w.visit(tree)
     ast.fix_missing_locations(tree)
-    hits: dict[int, list[bool]] = {}
+    hits: dict[int, list[int]] = {}
 
     def rec(i, v):
-        s = hits.setdefault(i, [False, False])
-        s[0 if v else 1] = True
+        s = hits.setdefault(i, [0, 0])
+        s[0 if v else 1] += 1
         return v
 
     g = dict(udf.globals)
@@ -78,7 +90,7 @@ def _build_instrumented(udf) -> tuple[Callable, dict, list]:
         ast.fix_missing_locations(mod)
         exec(compile(mod, "<branchprof>", "exec"), g)
         f = g[tree.name]
-    return f, hits, w.keys
+    return f, hits, w
 
 
 _CHEAP_CALLS = {"len", "abs", "min", "max", "ord", "chr", "bool"}
@@ -106,19 +118,35 @@ def arm_weight(arm) -> int:
     return w
 
 
+def observed(n_true: int, n_false: int,
+             worth: tuple = (True, True)) -> tuple[bool, bool]:
+    """(saw_true, saw_false) of one test from its counts: both arms stand
+    as observed below MIN_TRIALS trials (no evidence prunes either), an arm
+    is observed from ARM_SHARE of the trials on, and an arm not `worth`
+    pruning (`arm_weight`) is observed whatever the sample did, so that a
+    profile says only what changes the emitted kernel."""
+    trials = n_true + n_false
+    if trials < MIN_TRIALS:
+        return True, True
+    need = max(1.0, ARM_SHARE * trials)
+    return (n_true >= need or not worth[0], n_false >= need or not worth[1])
+
+
 def profile_branches(udf, rows, call: Callable) -> dict:
     """{branch_key: (saw_true, saw_false)} from running the instrumented UDF
-    over `rows` via `call(f, row)` (the operator's own calling convention).
-    Rows that raise contribute whatever branches they reached before the
-    error — same evidence the reference's TraceVisitor collects. Returns {}
-    when the UDF has no branches or cannot be instrumented (no pruning)."""
+    over `rows` via `call(f, row)` (the operator's own calling convention);
+    what counts as seen is `observed`'s to say, and a test with both arms
+    seen is left out (it prunes nothing). Rows that raise contribute
+    whatever branches they reached before the error — same evidence the
+    reference's TraceVisitor collects. Returns {} when the UDF has no
+    branches or cannot be instrumented (no pruning)."""
     if not rows:
         return {}
     if not any(isinstance(n, (ast.If, ast.IfExp))
                for n in ast.walk(udf.tree)):
         return {}
     try:
-        f, hits, keys = _build_instrumented(udf)
+        f, hits, w = _build_instrumented(udf)
     except Exception:
         return {}
     for r in rows[:_PROFILE_ROW_CAP]:
@@ -126,4 +154,5 @@ def profile_branches(udf, rows, call: Callable) -> dict:
             call(f, r)
         except Exception:
             pass
-    return {keys[i]: tuple(v) for i, v in hits.items()}
+    prof = {w.keys[i]: observed(*v, w.worth[i]) for i, v in hits.items()}
+    return {k: v for k, v in prof.items() if v != (True, True)}

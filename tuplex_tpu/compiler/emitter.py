@@ -301,12 +301,14 @@ class Frame:
         self._assign_target(node.target, self.eval(node.value))
 
     def _spec_arms(self, node) -> tuple[bool, bool]:
-        """(prune_then, prune_else): arms the operator's sample NEVER took
-        (branch speculation, reference RemoveDeadBranchesVisitor.cc:1-147).
-        An arm is prunable only with positive evidence the OTHER arm ran —
-        a node the sample never reached proves nothing about either arm —
-        and only when its body is worth skipping: predicated execution of a
-        cheap assignment costs less than the violation bookkeeping."""
+        """(prune_then, prune_else): arms the operator's sample did not
+        observe (branch speculation, reference RemoveDeadBranchesVisitor.cc:
+        1-147; `branchprof.observed` says what counts: a share of enough
+        trials, never a single row). An arm is prunable only with positive
+        evidence the OTHER arm ran — a node the sample never reached proves
+        nothing about either arm — and only when its body is worth
+        skipping: predicated execution of a cheap assignment costs less
+        than the violation bookkeeping."""
         prof = self.em.branch_profile
         if not prof:
             return False, False

@@ -374,7 +374,8 @@ def super_type(a: Type, b: Type) -> Type:
 
 
 def normal_case_type(
-    sample: Iterable[Any], threshold: float = 0.9
+    sample: Iterable[Any], threshold: float = 0.9,
+    rare_nulls_deviate: bool = False
 ) -> tuple[Type, Type, float]:
     """Data-driven speculation over a sample of values.
 
@@ -387,6 +388,12 @@ def normal_case_type(
     Reference semantics: FileInputOperator.cc:228-232 + CSVStatistic
     (majority >= tuplex.normalcaseThreshold, default 0.9 at
     ContextOptions.cc:507).
+
+    `rare_nulls_deviate`: the majority type is promoted to its Option only
+    where nulls are at least `1 - threshold` of the sample (the paper's
+    null-value optimisation: a null rarer than that is a deviant row, not
+    part of the normal case). Without it one null among a thousand values
+    makes the normal case an Option, so the type turns on a single row.
     """
     counts: dict[Type, int] = {}
     general: Type = UNKNOWN
@@ -410,7 +417,8 @@ def normal_case_type(
 
     # consider promoting majority with nulls into Option[majority]
     candidates = [best_t]
-    if NULL in counts and best_t is not NULL:
+    if NULL in counts and best_t is not NULL and not (
+            rare_nulls_deviate and counts[NULL] < (1.0 - threshold) * n):
         candidates.append(option(best_t))
     best_frac = 0.0
     best_nc = best_t

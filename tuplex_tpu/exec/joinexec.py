@@ -46,6 +46,9 @@ class JoinExecutor:
                                      intermediate=intermediate)
             if _sp is not TR.NOOP:
                 _sp.set("rows_out", res.metrics.get("rows_out", 0))
+                for k in ("columns_in", "columns_out", "filled"):
+                    if res.metrics.get(k) is not None:
+                        _sp.set(k, res.metrics[k])
         return res
 
     def _execute_impl(self, stage, left_partitions: list[C.Partition],
@@ -93,6 +96,7 @@ class JoinExecutor:
             vec = None
         build = None
         out_parts = []
+        self._filled = 0        # probe rows a left join filled with None
         for part in left_partitions:
             self.backend.mm.touch(part)
             with TR.span("join:probe", "exec") as _psp:
@@ -115,7 +119,13 @@ class JoinExecutor:
         m = {"wall_s": time.perf_counter() - t0,
              "rows_out": sum(p.num_rows for p in out_parts),
              "exception_rows": len(excs),
-             "compile_s": cs, "stage_compiles": cn}
+             "compile_s": cs, "stage_compiles": cn,
+             "columns_out": len(stage.output_schema.columns or ())}
+        if left_partitions:
+            m["columns_in"] = len(left_partitions[0].schema.columns or ())
+        if op.how == "left":
+            m["filled"] = self._filled + (vec.filled if vec is not None
+                                          else 0)
         return StageResult(out_parts, excs, m)
 
     # ------------------------------------------------------------------
@@ -177,6 +187,7 @@ class JoinExecutor:
                     values.append(tuple(lvals + [key] + rvals))
             elif op.how == "left":
                 values.append(tuple(lvals) + (key,) + empty_right)
+                self._filled += 1
         schema = op.schema()
         if not values:
             return C.Partition(schema=schema, num_rows=0, leaves={},
@@ -317,10 +328,18 @@ class _VectorBuild:
                 if not isinstance(row_vals, tuple) or \
                         len(row_vals) != n_cols:
                     return None      # arity-weird boxed rows: row-wise path
+                normal_mask_all[off + i] = False
+                if row_vals[rk] is None and not rt.is_optional():
+                    # a build row without a key (an airport the database
+                    # gives no code): only a probe key that is None equals
+                    # it, and no normal probe row holds one (can_probe
+                    # takes a key column of the build's own type, which is
+                    # no Option), so the row lives in the backup dict
+                    # alone, for boxed probe rows
+                    continue
                 if not T.python_value_conforms(row_vals[rk], rt):
                     return None      # cross-type key: python == semantics
                 boxed_rows.append(tuple(row_vals))
-                normal_mask_all[off + i] = False
             off += rp.num_rows
         normal_idx = np.nonzero(normal_mask_all)[0]
         if len(normal_idx) == 0:
@@ -346,6 +365,8 @@ class _VectorBuild:
         self.key_width = sig.shape[1]
         self.boxed_rows = boxed_rows
         self.boxed_sigs = None
+        self.filled = 0         # probe rows a left join filled with None
+        self._sigs: dict = {}   # id(partition) -> signatures, can_probe's
         self._pydict: Optional[dict] = None
         if boxed_rows and not self._encode_boxed_sigs(rt):
             return None              # can't sign boxed keys: stay exact
@@ -388,27 +409,65 @@ class _VectorBuild:
 
     def can_probe(self, lpart: C.Partition) -> bool:
         """Cheap qualification; ALL partitions must pass or the whole join
-        uses the row-wise path (mixed paths would mix output schemas)."""
+        uses the row-wise path (mixed paths would mix output schemas). The
+        signatures are kept for `probe`, which asks next."""
         op = self.op
         if op.left_column not in lpart.schema.columns:
             return False
         lk = lpart.schema.columns.index(op.left_column)
+        sig = self._probe_signatures(lpart, lk)
+        if sig is not None:
+            self._sigs[id(lpart)] = sig
+        return sig is not None
+
+    def _probe_signatures(self, lpart: C.Partition, lk: int
+                          ) -> Optional[np.ndarray]:
+        """The probe key's signatures in the build side's byte layout, or
+        None where byte equality would not be python equality (i64 against
+        f64 keys). Keys of one type and width take `key_signature_matrix`
+        as they are. A string key against an Option of a string, or at
+        another width (a stage's output keeps its own; harmonize covers
+        one dataset's partitions), goes there as a one-column view at the
+        build key's type and width, so the layout has one definition; a
+        key that can equal no build key (longer than its width; None
+        against a plain string) signs as all-0xFF, as boxed keys do."""
         lt = lpart.schema.types[lk]
         rt = self.big.schema.types[self.rk]
-        if lt.name != rt.name:
-            return False  # e.g. i64 vs f64 keys: byte equality would diverge
-        sig = _key_signatures(lpart, lk)
-        # width mismatch (str keys of different bucket W): fallback rather
-        # than padding — harmonize only covers one dataset's partitions
-        return sig is not None and sig.shape[1] == self.key_width
+        if lt.name == rt.name:
+            sig = _key_signatures(lpart, lk)
+            if sig is None or sig.shape[1] == self.key_width:
+                return sig
+        leaf = lpart.leaves.get(str(lk))
+        bleaf = self.big.leaves.get(str(self.rk))
+        if lt.without_option() is not T.STR or \
+                rt.without_option() is not T.STR or \
+                not isinstance(leaf, C.StrLeaf) or \
+                not isinstance(bleaf, C.StrLeaf):
+            return None
+        n, w = lpart.num_rows, bleaf.width
+        never = leaf.lengths > w
+        valid = leaf.valid
+        if bleaf.valid is None and valid is not None:
+            never, valid = never | ~valid, None
+        elif bleaf.valid is not None and valid is None:
+            valid = np.ones(n, np.bool_)
+        view = C.Partition(
+            schema=T.row_of(["k"], [rt]), num_rows=n,
+            leaves={"0": C.StrLeaf(
+                np.ascontiguousarray(C.pad_to(leaf.bytes, w, axis=1)[:, :w]),
+                leaf.lengths, valid)})
+        sig = _key_signatures(view, 0)
+        if sig is None or sig.shape[1] != self.key_width:
+            return None
+        return np.where(never[:, None], np.uint8(0xFF), sig)
 
     def probe(self, lpart: C.Partition, excs: list
               ) -> Optional[C.Partition]:
-        op = self.op
-        ls = lpart.schema
-        lk = ls.columns.index(op.left_column)
-        sig = _key_signatures(lpart, lk)
-        if sig is None or sig.shape[1] != self.key_width:
+        sig = self._sigs.pop(id(lpart), None)
+        if sig is None:
+            lk = lpart.schema.columns.index(self.op.left_column)
+            sig = self._probe_signatures(lpart, lk)
+        if sig is None:
             return None
         return self._probe_sig(lpart, sig, excs)
 
@@ -536,6 +595,7 @@ class _VectorBuild:
                 if not outs and op.how == "left":
                     outs.append(tuple(lvals) + (key,) +
                                 (None,) * (ncols_r - 1))
+                    self.filled += 1
                 if outs:
                     extra_rows[i] = outs
                 bcnt[i] = len(outs)
@@ -544,6 +604,7 @@ class _VectorBuild:
         filler = np.zeros(n, np.bool_)
         if op.how == "left":
             filler = (total == 0) & ~is_fb
+            self.filled += int(filler.sum())
         out_per_row = np.where(filler, 1, total)
         m = int(out_per_row.sum())
         starts = np.concatenate([[0], np.cumsum(out_per_row)])[:-1]
@@ -580,7 +641,8 @@ class _VectorBuild:
         extra_rows = plan["extra_rows"]
         # gather left (minus key), key, right (minus key)
         with TR.span("join:gather", "exec") as _sp:
-            _sp.set("rows_out", m_vec)
+            _sp.set("rows_out", m_vec).set(
+                "columns", len(ls.columns) + len(self.big.schema.columns) - 1)
             lgather = self._gather(lpart, left_idx)
             rgather = self._gather(self.big, build_rows,
                                    valid_rows=has_match

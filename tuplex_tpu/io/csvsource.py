@@ -143,14 +143,33 @@ def infer_column_types(rows: list[list[str]], k: int,
             nc = T.STR
         if gc is T.UNKNOWN:
             gc = nc
-        # any mix the supertype can't name as a primitive decodes as the raw
-        # string — the cells ARE strings, downstream UDFs parse them
-        gb = gc.without_option() if gc.is_optional() else gc
-        if gb not in (T.I64, T.F64, T.BOOL, T.STR, T.NULL):
-            gc = T.option(T.STR) if gc.is_optional() else T.STR
         types.append(nc)
-        general_types.append(gc)
+        general_types.append(_cell_general(gc))
     return types, general_types
+
+
+def _cell_general(gc: T.Type) -> T.Type:
+    """Any mix the supertype can't name as a primitive decodes as the raw
+    string — the cells ARE strings, downstream UDFs parse them."""
+    gb = gc.without_option() if gc.is_optional() else gc
+    if gb not in (T.I64, T.F64, T.BOOL, T.STR, T.NULL):
+        return T.option(T.STR) if gc.is_optional() else T.STR
+    return gc
+
+
+def widen_general_types(general_types: list, rows: list[list[str]], k: int,
+                        null_values: Sequence[str]) -> list:
+    """`general_types` widened by every kind of cell that `rows` show. The
+    rows are windows from all over the file (`_evidence_rows`): the general
+    case has to name what one row in a thousand holds, and whether the head
+    of a file shows such a row is the toss of a coin."""
+    rows = [r for r in rows if len(r) == k]
+    out = []
+    for ci, gc in enumerate(general_types):
+        for cell in {r[ci] for r in rows}:
+            gc = T.super_type(gc, _cell_type(cell, null_values))
+        out.append(_cell_general(gc))
+    return out
 
 
 class CSVStatistic:
@@ -162,7 +181,8 @@ class CSVStatistic:
                  null_values: Optional[Sequence[str]] = None,
                  columns: Optional[Sequence[str]] = None,
                  type_hints: Optional[dict] = None,
-                 quotechar: str = '"'):
+                 quotechar: str = '"',
+                 evidence: Sequence[bytes] = ()):
         text = sample_bytes.decode("utf-8", errors="replace")
         # drop a possibly-truncated last line
         if not sample_bytes.endswith(b"\n") and "\n" in text:
@@ -196,6 +216,16 @@ class CSVStatistic:
         max_rows = options.get_int("tuplex.csv.maxDetectionRows", 1000)
         self.types, self.general_types = infer_column_types(
             body[:max_rows], k, self.null_values, threshold)
+        for block in evidence:
+            # a window cut out of the file: its first and last lines are
+            # pieces of lines
+            lines = block.decode("utf-8", errors="replace") \
+                .split("\n")[1:-1]
+            self.general_types = widen_general_types(
+                self.general_types,
+                list(_pycsv.reader(lines, delimiter=self.delimiter,
+                                   quotechar=self.quotechar)),
+                k, self.null_values)
         if type_hints:
             for key, t in type_hints.items():
                 idx = key if isinstance(key, int) else self.columns.index(key)
@@ -487,7 +517,7 @@ class CSVSourceOperator(L.LogicalOperator):
             # streamed: the counters move once the file's last batch is in
             # (a take() that stops early never read the whole file)
             _note_read(TR.NOOP, path, file_rows + len(bad_rows),
-                       len(out_columns))
+                       len(out_columns), len(stat.columns))
             if bad_rows:
                 p = _bad_rows_partition(bad_rows, stat, proj_idx, raw_schema,
                                         offset)
@@ -532,7 +562,7 @@ class CSVSourceOperator(L.LogicalOperator):
                                    parse_options=parse_opts,
                                    convert_options=conv_opts)
             _note_read(_sp, path, table.num_rows + len(bad_rows),
-                       table.num_columns)
+                       table.num_columns, len(stat.columns))
         return table, bad_rows
 
 
@@ -583,15 +613,17 @@ def _cut_partitions(cuts: list, stat: "CSVStatistic", raw_schema: T.RowType,
         base += n + len(bad_rows)
 
 
-def _note_read(sp, path: str, rows: int, columns: int) -> None:
-    """One file is read: its size, rows and columns go on the
-    `ingest:read-csv` span and into the `ingest_*` counters (always on,
-    like the transfer counters)."""
+def _note_read(sp, path: str, rows: int, columns: int,
+               file_columns: int) -> None:
+    """One file is read: its size, rows, the columns read and the columns
+    the file has go on the `ingest:read-csv` span, and its size and rows
+    into the `ingest_*` counters (always on, like the transfer counters)."""
     try:
         nbytes = VirtualFileSystem.file_size(path)
     except Exception:
         nbytes = 0
-    sp.set("bytes", nbytes).set("rows", rows).set("columns", columns)
+    sp.set("bytes", nbytes).set("rows", rows).set("columns", columns) \
+      .set("file_columns", file_columns)
     xferstats.bump("ingest_bytes", nbytes)
     xferstats.bump("ingest_rows", rows)
     xferstats.bump("ingest_files", 1)
@@ -939,11 +971,12 @@ def make_csv_operator(options, pattern: str, columns=None, header=None,
         if stat is None:
             with VirtualFileSystem.open_read(files[0], "rb") as fp:
                 sample = fp.read(max_sample)
-            _sp.set("bytes", len(sample))
+                evidence = _evidence_windows(fp, files[0], max_sample)
+            _sp.set("bytes", len(sample) + sum(map(len, evidence)))
             stat = CSVStatistic(sample, options, delimiter=delimiter,
                                 header=header, null_values=null_values,
                                 columns=columns, type_hints=type_hints,
-                                quotechar=quotechar)
+                                quotechar=quotechar, evidence=evidence)
             if skey is not None:
                 if len(_STAT_CACHE) >= _STAT_CACHE_CAP:
                     _STAT_CACHE.pop(next(iter(_STAT_CACHE)))
@@ -952,6 +985,31 @@ def make_csv_operator(options, pattern: str, columns=None, header=None,
     return L.DecodeOperator(src, _decoded_schema(stat), stat.null_values,
                             general=T.row_of(stat.columns,
                                              stat.general_types))
+
+
+_EVIDENCE_WINDOWS = 15
+
+
+def _evidence_windows(fp, path: str, window: int) -> list:
+    """`_EVIDENCE_WINDOWS` more windows of `window` bytes, evenly spaced
+    over the file behind its head, for the general-case types alone
+    (`widen_general_types`); a file that small is read whole. The normal
+    case and the sample the UDFs are traced on stay the head's."""
+    try:
+        size = VirtualFileSystem.file_size(path)
+        n = _EVIDENCE_WINDOWS
+        if size <= window:
+            return []
+        if size <= (n + 2) * window:
+            fp.seek(window - 1)             # one byte back: a whole line
+            return [fp.read()]
+        out = []
+        for i in range(1, n + 1):           # the last one ends the file
+            fp.seek(window + (size - 2 * window) * i // n)
+            out.append(fp.read(window))
+        return out
+    except (OSError, AttributeError, ValueError):
+        return []                           # a source that cannot seek
 
 
 def _decoded_schema(stat: CSVStatistic) -> T.RowType:

@@ -53,13 +53,21 @@ _CITY_STATE = [("Boston, MA", "BOS"), ("New York, NY", "JFK"),
 # generators
 # ---------------------------------------------------------------------------
 
-def generate_perf_csv(path: str, n: int, seed: int = 13) -> str:
+def generate_perf_csv(path: str, n: int, seed: int = 13,
+                      columns=None) -> str:
+    """`columns`: a wider schema to write the same rows under (the BTS
+    file's 110 UPPER_SNAKE names, `bench/configs/flights-bts`): the 30
+    columns the pipeline reads hold their cells under whichever case the
+    schema spells them in, and every other column is empty."""
     import csv
 
     rng = random.Random(seed)
+    at = None if columns is None else [
+        PERF_COLS.index(c.lower()) if c.lower() in PERF_COLS else None
+        for c in columns]
     with open(path, "w", newline="") as fp:
         w = csv.writer(fp)
-        w.writerow(PERF_COLS)
+        w.writerow(PERF_COLS if columns is None else columns)
         for _ in range(n):
             o_city, o_code = rng.choice(_CITY_STATE)
             d_city, d_code = rng.choice(_CITY_STATE)
@@ -89,7 +97,8 @@ def generate_perf_csv(path: str, n: int, seed: int = 13) -> str:
                 float(rng.randint(0, 90)),
                 float(rng.randint(2, 40)), float(rng.randint(5, 50)),
             ]
-            w.writerow(row)
+            w.writerow(row if at is None else
+                       ["" if i is None else row[i] for i in at])
     return path
 
 

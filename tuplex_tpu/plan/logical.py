@@ -219,10 +219,11 @@ class UDFOperator(LogicalOperator):
             else:
                 from ..compiler.branchprof import profile_branches
 
-                rows = self.parent.cached_sample()
-                # too little evidence to call any arm dead
-                memo = {} if len(rows) < 32 else profile_branches(
-                    self.udf, rows, self._profile_call)
+                # (too few trials of a test call no arm dead:
+                # branchprof.MIN_TRIALS)
+                memo = profile_branches(
+                    self.udf, self.parent.cached_sample(),
+                    self._profile_call)
                 if ck is not None:
                     _cross_job_branchprofs[ck] = memo
             self._branch_prof_memo = memo
@@ -267,6 +268,14 @@ class UDFOperator(LogicalOperator):
         raise NotImplementedError
 
 
+def _output_type(outs: list) -> T.Type:
+    """The normal-case type of a UDF's sampled results. A `None` rarer than
+    `1 - normalcaseThreshold` of them is a deviant row (it resolves on the
+    general tier), so the type does not turn on one sampled row in a
+    thousand."""
+    return T.normal_case_type(outs, rare_nulls_deviate=True)[0]
+
+
 class MapOperator(UDFOperator):
     def _infer_schema(self) -> T.RowType:
         outs = []
@@ -282,17 +291,17 @@ class MapOperator(UDFOperator):
         if all(isinstance(o, tuple) for o in outs) and outs and \
                 len({len(o) for o in outs}) == 1:
             k = len(outs[0])
-            types = [T.normal_case_type([o[i] for o in outs])[0]
+            types = [_output_type([o[i] for o in outs])
                      for i in range(k)]
             return T.row_of([f"_{i}" for i in range(k)], types)
         # dict results keep column names (reference: map with dict output)
         if all(isinstance(o, dict) for o in outs) and outs:
             keys = list(outs[0].keys())
             if all(list(o.keys()) == keys for o in outs):
-                types = [T.normal_case_type([o[k] for o in outs])[0]
+                types = [_output_type([o[k] for o in outs])
                          for k in keys]
                 return T.row_of(keys, types)
-        nc, _, _ = T.normal_case_type(outs)
+        nc = _output_type(outs)
         return T.row_of(["_0"], [nc])
 
     def sample(self) -> list[Row]:
@@ -348,7 +357,7 @@ class WithColumnOperator(UDFOperator):
                 outs.append(apply_udf_python(self.udf, r))
             except Exception as e:
                 record_sample_exc(self, e, r)
-        nc = T.PYOBJECT if not outs else T.normal_case_type(outs)[0]
+        nc = T.PYOBJECT if not outs else _output_type(outs)
         cols = list(ps.columns)
         types = list(ps.types)
         if self.column in cols:
@@ -391,7 +400,7 @@ class MapColumnOperator(UDFOperator):
                 outs.append(self.udf.func(r.values[ci]))
             except Exception as e:
                 record_sample_exc(self, e, r)
-        nc = T.PYOBJECT if not outs else T.normal_case_type(outs)[0]
+        nc = T.PYOBJECT if not outs else _output_type(outs)
         types = list(ps.types)
         types[ci] = nc
         return T.row_of(ps.columns, types)

@@ -547,3 +547,238 @@ def _filter_selectivity(op) -> float:
         except Exception:
             passed += 1  # must reach this filter to raise: treat as pass
     return passed / len(sample)
+
+
+# ---------------------------------------------------------------------------
+# projection through joins (reference: csv.selectionPushdown crosses joins)
+# ---------------------------------------------------------------------------
+
+_MEMO_ATTRS = ("_sample_memo", "_chain_key_memo", "_branch_prof_memo",
+               "_prof_ci", "sample_exceptions", "_sample_trace_skipped")
+
+
+def relink(op: L.LogicalOperator, parents: list) -> L.LogicalOperator:
+    """A shallow copy of `op` over other parents, with every memo that
+    depends on its upstream chain cleared. The operator id survives the
+    copy, so metrics and history attribution are unchanged."""
+    import copy
+
+    op = copy.copy(op)
+    op.parents = list(parents)
+    if hasattr(op, "_schema_cache"):
+        op._schema_cache = None
+    for a in _MEMO_ATTRS:
+        op.__dict__.pop(a, None)
+    return op
+
+
+def _join_side_names(j, left_cols, right_cols) -> tuple:
+    """({output name -> left column}, {output name -> right column}) of a
+    join over sides with these columns; the key maps to both sides."""
+    left = {(c if c == j.left_column else j._decorate(c, 0)): c
+            for c in left_cols}
+    right = {j._decorate(c, 1): c for c in right_cols
+             if c != j.right_column}
+    return left, right
+
+
+def _join_columns(j, left_cols, right_cols) -> list:
+    """Output column order of JoinOperator.schema(), from names alone."""
+    return ([j._decorate(c, 0) for c in left_cols if c != j.left_column]
+            + [j.left_column]
+            + [j._decorate(c, 1) for c in right_cols if c != j.right_column])
+
+
+def _columns_after(op, cur):
+    """Column names after `op`, given the names `cur` before it (None when
+    they cannot be told without the operator's own schema)."""
+    if isinstance(op, L.RenameColumnOperator):
+        old = op.old if isinstance(op.old, str) else cur[op.old]
+        return [op.new if c == old else c for c in cur]
+    if isinstance(op, L.WithColumnOperator):
+        return cur if op.column in cur else cur + [op.column]
+    if isinstance(op, L.SelectColumnsOperator):
+        return [cur[c] if isinstance(c, int) else c for c in op.selected]
+    if isinstance(op, (L.MapColumnOperator, L.FilterOperator,
+                       L.ResolveOperator, L.IgnoreOperator,
+                       L.DecodeOperator)):
+        return cur
+    cols = op.columns()         # map, aggregate: the operator's own names
+    return list(cols) if cols else None
+
+
+# string methods that take no argument and raise on no string
+_TOTAL_STR_METHODS = frozenset({"lower", "upper", "strip", "lstrip", "rstrip",
+                                "title", "capitalize", "casefold", "swapcase"})
+
+
+def _total_when_guarded(op) -> bool:
+    """Whether a `mapColumn` UDF provably raises on no value a string
+    column can hold (a string or None): `f(x) if x else <constant>`, where
+    `f(x)` is `x` under methods of `_TOTAL_STR_METHODS` called with no
+    argument, or `string.capwords` of such. Deliberately narrow: it decides
+    whether an operator whose result nothing reads may be dropped, and an
+    operator that can raise drops its row (`x.format()` on "{0}" does, and
+    `x.center("a")`: neither is on the list)."""
+    if not isinstance(op, L.MapColumnOperator):
+        return False
+    udf, tree = op.udf, op.udf.tree
+    if not isinstance(tree, ast.Lambda) or len(udf.params) != 1:
+        return False
+    ptype = op.parent.schema()
+    t = ptype.types[ptype.columns.index(op.column)]
+    if t.without_option() is not T.STR:
+        return False
+    p, body = udf.params[0], tree.body
+    if not (isinstance(body, ast.IfExp) and isinstance(body.test, ast.Name)
+            and body.test.id == p and isinstance(body.orelse, ast.Constant)):
+        return False
+
+    def total(e) -> bool:
+        if isinstance(e, ast.Name):
+            return e.id == p
+        if not (isinstance(e, ast.Call) and not e.keywords
+                and isinstance(e.func, ast.Attribute)):
+            return False
+        f = e.func
+        if isinstance(f.value, ast.Name) and f.value.id != p:
+            import string
+
+            return (udf.globals.get(f.value.id) is string
+                    and f.attr == "capwords" and len(e.args) == 1
+                    and total(e.args[0]))
+        return (f.attr in _TOTAL_STR_METHODS and not e.args
+                and total(f.value))
+
+    return total(body.body)
+
+
+def project_through_joins(chain: list, source):
+    """Projection through joins: one backward pass over `chain`
+    (plan_stages' source→sink operator list) from the sink gives every
+    point of the chain the columns its consumers read — before a join, the
+    key plus what the operators after the join read of that side, through
+    the prefixes and renames; for the build side the same. A forward pass
+    then puts a `selectColumns` of the live columns before each join (and
+    at the foot of its build side, whose sub-plan `JoinStage` plans from
+    there), so that `required_source_columns` prunes the sources and no
+    join carries a column that nothing reads, and drops a `mapColumn`
+    whose result nothing reads and which provably cannot raise.
+
+    Returns (chain, joins crossed); the chain is the one given unless
+    something was pruned, and the user's DAG is never mutated."""
+    from .joins import JoinOperator
+
+    # ---- the column names before each operator, as the user wrote it ----
+    names: list = []
+    cur = list(source.columns() or ()) or None
+    for op in chain:
+        names.append(cur)
+        if cur is None:                 # unnamed rows: nothing to prune by
+            return chain, 0
+        if isinstance(op, JoinOperator):
+            cur = _join_columns(op, cur, list(op.right.columns() or ()))
+        else:
+            cur = _columns_after(op, cur)
+    # ---- backward: the columns live after each operator (None: all) ----
+    live: Optional[set] = None      # the sink's consumer reads every column
+    keep: dict = {}        # position of a join -> (left, right) live columns
+    drop: set = set()      # positions of dead operators that cannot raise
+    carry: Optional[set] = set()   # reads of the resolvers of the operator
+    for k in range(len(chain) - 1, -1, -1):             # before them
+        op = chain[k]
+        nxt = chain[k + 1] if k + 1 < len(chain) else None
+        if isinstance(op, L.ResolveOperator):
+            reads = udf_read_columns(op.udf)
+            carry = ALL if reads is ALL or carry is ALL else carry | reads
+            continue
+        if isinstance(op, L.IgnoreOperator):
+            continue
+        if isinstance(op, JoinOperator):
+            if live is not None:
+                lnames, rnames = _join_side_names(
+                    op, names[k], list(op.right.columns() or ()))
+                keep[k] = (
+                    {op.left_column} | {lnames[n] for n in live
+                                        if n in lnames},
+                    {op.right_column} | {rnames[n] for n in live
+                                         if n in rnames})
+                live = keep[k][0]
+        elif live is not None and isinstance(op, L.MapColumnOperator) and \
+                op.column not in live and not isinstance(
+                    nxt, (L.ResolveOperator, L.IgnoreOperator)) and \
+                _total_when_guarded(op):
+            drop.add(k)
+        else:
+            live = _live_before(op, live, names[k])
+        if carry is ALL:
+            live = None
+        elif carry and live is not None:
+            live = live | carry
+        carry = set()
+    if not keep and not drop:
+        return chain, 0
+    # ---- forward: selects before the joins, dead operators dropped ----
+    out: list = []
+    prev = source
+    cur = names[0]
+    crossed = 0
+    for k, op in enumerate(chain):
+        if k in drop:
+            continue
+        if isinstance(op, JoinOperator):
+            right = op.right
+            rcols = list(right.columns() or ())
+            if k in keep:
+                lkeep, rkeep = keep[k]
+                if len(lkeep) < len(cur) or len(rkeep) < len(rcols):
+                    crossed += 1
+                if len(lkeep) < len(cur):
+                    cur = [c for c in cur if c in lkeep]
+                    prev = L.SelectColumnsOperator(prev, cur)
+                    out.append(prev)
+                if len(rkeep) < len(rcols):
+                    rcols = [c for c in rcols if c in rkeep]
+                    right = L.SelectColumnsOperator(right, rcols)
+            if prev is not op.left or right is not op.right:
+                op = relink(op, [prev, right])
+            cur = _join_columns(op, cur, rcols)
+        else:
+            if op.parent is not prev:
+                if isinstance(op, L.SelectColumnsOperator) and any(
+                        isinstance(c, int) for c in op.selected):
+                    # positions shift under a pruned row: select by name
+                    op = L.SelectColumnsOperator(prev, [
+                        names[k][c] if isinstance(c, int) else c
+                        for c in op.selected])
+                else:
+                    op = relink(op, [prev] + list(op.parents[1:]))
+            cur = _columns_after(op, cur)
+        out.append(op)
+        prev = op
+    return out, crossed
+
+
+def _live_before(op, live: Optional[set], cols: list) -> Optional[set]:
+    """The columns live before `op` (None: all), given those live after it
+    and the column names before it. An operator runs whether or not its
+    result is read, so its own reads are always live."""
+    if isinstance(op, L.SelectColumnsOperator):
+        names = op_reads(op, cols)
+        if names is ALL or live is None:
+            return names                         # ALL is None
+        return (names & live) or names
+    if isinstance(op, L.MapOperator):
+        return udf_read_columns(op.udf)
+    if op.is_breaker():
+        return agg_required_columns(op)          # None: the whole row
+    reads = op_reads(op, cols)
+    if reads is ALL or live is None:
+        return None
+    if isinstance(op, L.RenameColumnOperator):
+        if not isinstance(op.old, str):
+            return None
+        return {op.old if n == op.new else n for n in live}
+    if isinstance(op, L.WithColumnOperator):
+        return (live - {op.column}) | reads
+    return live | reads

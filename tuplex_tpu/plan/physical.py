@@ -1019,6 +1019,23 @@ def _plan_stages_impl(sink: L.LogicalOperator, options=None):
 
         chain = push_filters_through_joins(chain)
 
+    from ..runtime import tracing as TR
+
+    with TR.span("plan:projection", "plan") as _sp:
+        stages = _cut_and_project(chain, source, limit, options, _sp)
+    return _finish_stages(stages, options)
+
+
+def _cut_and_project(chain: list, source, limit: int, options, _sp) -> list:
+    """The chain cut into stages at its breakers, with every column that
+    no consumer reads pruned: across the joins (`project_through_joins`)
+    and at the file sources (`_apply_projection`). Inside the
+    `plan:projection` span, which also covers the cut itself and the
+    in-stage filter rewrites that have to come between the two."""
+    from ..runtime import tracing as TR
+    from .optimizer import project_through_joins
+
+    chain, crossed = project_through_joins(chain, source)
     stages: list = []
     cur: list[L.LogicalOperator] = []
     cur_source: Optional[L.LogicalOperator] = source
@@ -1082,6 +1099,21 @@ def _plan_stages_impl(sink: L.LogicalOperator, options=None):
 
                 out_req = agg_required_columns(nxt.op)
             _apply_projection(st, out_req)
+    if _sp is not TR.NOOP:
+        srcs = [st for st in stages if isinstance(st, TransformStage)
+                and hasattr(getattr(st.source, "stat", None), "columns")]
+        files = [len(st.source.stat.columns) for st in srcs]
+        _sp.set("sources", len(srcs)).set("file_columns", sum(files)) \
+           .set("kept_columns", sum(
+               len(getattr(st, "source_projection", None) or ()) or n
+               for st, n in zip(srcs, files))) \
+           .set("joins_crossed", crossed)
+    return stages
+
+
+def _finish_stages(stages: list, options) -> list:
+    """Branch speculation, segmentation and the fused folds, over the
+    projected stages."""
     # sample-driven branch speculation (reference: normal-case dead-branch
     # removal, RemoveDeadBranchesVisitor.cc; on by default there too).
     # Applied BEFORE segmentation so the compile probes see the same
@@ -1158,7 +1190,22 @@ def _apply_projection(stage: TransformStage, output_required=None) -> None:
     # integer selections resolve to NAMES first (positions shift when
     # columns are pruned)
     new_ops = []
+    names = list(src.stat.columns)      # the unpruned row's, by position
+    dead = set(names) - set(req)        # pruned source columns, as named now
     for op in stage.ops:
+        if isinstance(op, L.RenameColumnOperator):
+            old = op.old if isinstance(op.old, str) else names[op.old]
+            names = [op.new if c == old else c for c in names]
+            if old in dead:             # a rename of a pruned column
+                dead.discard(old)
+                dead.add(op.new)
+                continue
+        elif isinstance(op, L.WithColumnOperator):
+            dead.discard(op.column)
+            if op.column not in names:
+                names.append(op.column)
+        elif isinstance(op, (L.MapOperator, L.SelectColumnsOperator)):
+            dead = set()                # past these, every name is read
         if isinstance(op, L.DecodeOperator) and op.parent is src:
             keep_idx = [src.stat.columns.index(c) for c in req]
             declared = T.row_of(req, [op.declared.types[i] for i in keep_idx])
