@@ -119,6 +119,23 @@ def test_queue_wait_blocked_on_pool_reports_as_compile():
     assert r["buckets"]["device"] == pytest.approx(200e-6)
 
 
+def test_the_collect_side_wait_for_the_chip_reports_as_device():
+    """`dispatch:device-wait` heads a partition's collect (a d2h-plane
+    wrapper) since PR 36: the thread is blocked on the chip there, so the
+    slice is the device's, and the fetch after it stays d2h's."""
+    evts = [
+        _sp("partition:dispatch", 0, 100, tid=1),
+        _sp("partition:collect-fast", 100, 900, tid=1),
+        _sp("dispatch:device-wait", 110, 600, tid=1, depth=1),
+        _sp("d2h:packed-fetch", 720, 200, tid=1, depth=1),
+    ]
+    r = CP.analyze_events(evts, t0_us=0.0, t1_us=1000.0)
+    assert r["buckets"]["device"] == pytest.approx(700e-6)
+    assert r["buckets"]["d2h"] == pytest.approx(300e-6)
+    assert "device_wait" not in r["buckets"]
+    assert {seg[2] for seg in r["path"]} <= set(CP.BUCKETS)
+
+
 def test_queue_waits_ride_as_scalars_and_unattributed_absorbs_gap():
     evts = [_sp("job", 0, 400, depth=0)]
     r = CP.analyze_events(evts, wall_s=0.002, queued_s=0.0005,

@@ -421,3 +421,59 @@ def test_native_unpack_varlen_refuses_what_it_cannot_read_in_bounds(fault):
     with pytest.raises(TypeError if fault == "read-only-output"
                        else ValueError):
         nat.unpack_varlen(*args)
+
+
+# ---------------------------------------------------------------------------
+# the payload leaves the executable as whole chunk buffers (PR 36): the
+# fetch of payload[:total] takes no executable of its own
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("nbytes, chunks, chunk", [
+    (0, 1, 64), (1, 1, 64), (65535, 1, 65536), (65536, 1, 65536),
+    (3 * 65536 + 1, 3, 65600),
+    (18_464_256, 16, 1_154_048),         # Z1's stage at 114,688 slots
+    (1 << 30, 16, 1 << 26),
+])
+def test_payload_chunking(nbytes, chunks, chunk):
+    from tuplex_tpu.runtime.packing import _payload_chunking
+
+    assert _payload_chunking(nbytes) == (chunks, chunk)
+    assert chunks * chunk >= nbytes and chunk % 64 == 0
+
+
+@pytest.mark.parametrize("fill", [0.0, 0.2, 0.55, 1.0],
+                         ids=lambda f: f"fill{f}")
+def test_varlen_fetch_takes_whole_chunks_and_no_device_op(fill, monkeypatch):
+    """A payload several chunks long comes back exact whichever chunk the
+    content ends in, the fetch hands `jax.device_get` whole output buffers
+    of the executable (never a slice: that would be an executable of its
+    own, queued behind every dispatch in flight) and fetches no chunk past
+    the content's end."""
+    import jax
+
+    from tuplex_tpu.runtime import packing
+
+    rng = np.random.default_rng(36)
+    n, w = 4096, 96                        # capacity 393,216 B + '#err'
+    lens = np.minimum(rng.integers(0, w + 1, n),
+                      int(round(fill * w))).astype(np.int32)
+    mat, lens = _str_matrix(rng, n, w, lens)
+    arrays = {"0#bytes": mat, "0#len": lens,
+              "#err": np.zeros(n, np.int32)}
+    out = packing.PackedStageFn(lambda a: dict(a), donate=False)(arrays)
+    assert len(out.vbuf) == 6 and len({int(c.shape[0]) for c in out.vbuf}) == 1
+    chunk = int(out.vbuf[0].shape[0])
+    whole = {id(c) for c in out.vbuf} | {id(out.buf)}
+    fetched = []
+    real = jax.device_get
+
+    def spy(x):
+        fetched.extend(jax.tree_util.tree_leaves(x))
+        return real(x)
+
+    monkeypatch.setattr(packing.jax, "device_get", spy)
+    got = out.to_host()
+    assert all(id(a) in whole for a in fetched)
+    assert len(fetched) - 1 == -(-int(lens.sum()) // chunk)
+    for k, want in arrays.items():
+        np.testing.assert_array_equal(np.asarray(got[k]), want, err_msg=k)

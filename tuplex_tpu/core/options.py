@@ -333,13 +333,18 @@ DEFAULTS: dict[str, str] = {
                                             # stage metrics, bench JSON,
                                             # /metrics gauges, spans and
                                             # the dashboard. Default on.
-                                            # NOTE the enabled dispatch
-                                            # path blocks each partition
-                                            # until the device finishes
-                                            # (that IS the measurement) —
-                                            # TUPLEX_DEVPROF=0 is the env
-                                            # kill switch restoring the
-                                            # fully-async window with a
+                                            # The sample is taken where
+                                            # the collect side waits for
+                                            # a dispatch's outputs anyway
+                                            # (exec/local._await_dispatch)
+                                            # — exact where the host
+                                            # waited, an upper bound
+                                            # (`late`) where the outputs
+                                            # were ready first — so the
+                                            # schedule is the same on or
+                                            # off. TUPLEX_DEVPROF=0 is
+                                            # the env kill switch: it
+                                            # turns the RECORDING off, a
                                             # single flag check (zero
                                             # allocation, test-pinned).
                                             # Like trace/telemetry the

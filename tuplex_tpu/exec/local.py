@@ -200,6 +200,28 @@ class ExceptionRecord:
         return f"<{self.exc_name} at op#{self.op_id}: {self.row!r}>"
 
 
+@dataclass
+class _Launch:
+    """What a launched dispatch leaves on the window for its collect: a
+    dispatch returns at launch, and the wait for its outputs happens
+    once, at the head of that partition's collect (`_await_dispatch`).
+    The collect side fills in how the window stood as the wait began."""
+
+    #: host clock as the launch began
+    t: float
+    #: first call of its input spec: the trace and the compile or AOT
+    #: load lie after `t`
+    cold: bool
+    #: dispatches launched and not yet collected as the wait began, this
+    #: one included
+    in_flight: int = 1
+    #: when the stage's previous dispatch was seen ready (the chip runs
+    #: one at a time)
+    after: float = 0.0
+    #: when the wait saw this one ready
+    ready_at: float = 0.0
+
+
 class _DispatchFailed:
     """Sentinel riding the dispatch window when the device call itself
     raised synchronously (wedged runtime, lost mesh) — the collect side
@@ -756,7 +778,13 @@ class LocalBackend:
                                    "general_path_s": 0.0, "compile_s": 0.0,
                                    # partitions run again without compaction
                                    # after their bucket overflowed
-                                   "compaction_reruns": 0}
+                                   "compaction_reruns": 0,
+                                   # which side set the stage's pace: the
+                                   # collects whose wait found the chip
+                                   # still busy, and those whose outputs
+                                   # were ready when the host came
+                                   "dispatches_waited": 0,
+                                   "dispatches_ready": 0}
         if EX.enabled():
             # exception-plane baseline (runtime/excprof): snapshot the
             # plan-time code inventory + resolve-plan verdict BEFORE any
@@ -791,12 +819,31 @@ class LocalBackend:
         window_size = max(1, self.options.get_int(
             "tuplex.tpu.dispatchWindow", 3))
         window: deque = deque()
+        last_ready = 0.0    # when the stage's last dispatch was seen ready
 
         from ..utils.signals import check_interrupted
 
+        def armed(launch):
+            """How the window stands as this dispatch's wait begins."""
+            if launch is not None:
+                launch.in_flight = 1 + sum(
+                    1 for e in window if e[3] is not None)
+                launch.after = last_ready
+            return launch
+
+        def collect(part, outs, dispatch_s, launch):
+            nonlocal last_ready
+            try:
+                return self._collect_partition(
+                    stage, part, outs, dispatch_s,
+                    intermediate=intermediate, launch=armed(launch))
+            finally:
+                if launch is not None and launch.ready_at:
+                    last_ready = launch.ready_at
+
         def collect_one():
             nonlocal emitted_total, device_fn, use_comp, skey
-            part, outs, dispatch_s = window.popleft()
+            part, outs, dispatch_s, launch = window.popleft()
             if limit >= 0 and emitted_total >= limit:
                 return  # limit met: drop already-dispatched work unprocessed
             if isinstance(outs, _DispatchFailed) \
@@ -816,9 +863,7 @@ class LocalBackend:
                 try:
                     if isinstance(outs, _DispatchFailed):
                         raise outs.err
-                    outp, excs, m = self._collect_partition(
-                        stage, part, outs, dispatch_s,
-                        intermediate=intermediate)
+                    outp, excs, m = collect(part, outs, dispatch_s, launch)
                 except Exception as e:
                     if outs is None:
                         raise   # interpreter failure is deterministic
@@ -830,8 +875,9 @@ class LocalBackend:
 
                     if CQ.deserialize_defect(e):
                         # the loads-but-cannot-run gap surfaced at the
-                        # COLLECT site (async dispatch: nothing blocked
-                        # between launch and fetch, e.g. devprof off).
+                        # COLLECT site (a dispatch returns at launch:
+                        # what fails once the chip runs it fails at this
+                        # partition's wait or fetch).
                         # Pin the doomed specs + persist their .nodeser
                         # markers now so the retry below re-dispatches on
                         # a fresh in-process compile instead of the same
@@ -852,12 +898,9 @@ class LocalBackend:
                         "partition task failed (%s: %s); retrying once",
                         type(e).__name__, e)
                     try:
-                        _, outs2, d2 = self._dispatch_partition(
+                        outp, excs, m = collect(*self._dispatch_partition(
                             part, device_fn, skey, use_comp, stage,
-                            packed=packed)
-                        outp, excs, m = self._collect_partition(
-                            stage, part, outs2, d2,
-                            intermediate=intermediate)
+                            packed=packed))
                     except Exception as e2:
                         efn = self._elastic_stage_fn(stage, skey, in_schema)
                         outp = None
@@ -876,18 +919,15 @@ class LocalBackend:
                                 "action": "elastic"})
                             ekey = skey + "/elastic"
                             try:
-                                _, outs3, d3 = self._dispatch_partition(
+                                res3 = self._dispatch_partition(
                                     part, efn, ekey, False, stage,
                                     packed=packed)
-                                if outs3 is None:
+                                if res3[1] is None:
                                     # elastic fn couldn't trace either:
                                     # demote the whole stage cleanly
                                     self._not_compilable.add(skey)
                                 else:
-                                    outp, excs, m = \
-                                        self._collect_partition(
-                                            stage, part, outs3, d3,
-                                            intermediate=intermediate)
+                                    outp, excs, m = collect(*res3)
                                     # later partitions ride the elastic fn
                                     # UNDER ITS OWN bookkeeping key (the
                                     # mesh fn's traced-spec records must
@@ -927,9 +967,7 @@ class LocalBackend:
                             get_logger("exec").warning(
                                 "retry failed (%s: %s); partition runs on "
                                 "the interpreter", type(e2).__name__, e2)
-                            outp, excs, m = self._collect_partition(
-                                stage, part, None, 0.0,
-                                intermediate=intermediate)
+                            outp, excs, m = collect(part, None, 0.0, None)
             finally:
                 self.mm.unpin(part)
             self.mm.register(outp)
@@ -937,6 +975,8 @@ class LocalBackend:
             metrics["slow_path_s"] += m.get("slow_path_s", 0.0)
             metrics["general_path_s"] += m.get("general_path_s", 0.0)
             metrics["compaction_reruns"] += m.get("compaction_reruns", 0)
+            metrics["dispatches_waited"] += m.get("dispatches_waited", 0)
+            metrics["dispatches_ready"] += m.get("dispatches_ready", 0)
             exceptions.extend(excs)
             if limit >= 0 and emitted_total + outp.num_rows > limit:
                 outp = _truncate_partition(outp, limit - emitted_total)
@@ -970,7 +1010,7 @@ class LocalBackend:
             except Exception as e:
                 # synchronous dispatch failure: enqueue for the collect
                 # side's degrade ladder instead of killing the job
-                window.append((part, _DispatchFailed(e), 0.0))
+                window.append((part, _DispatchFailed(e), 0.0, None))
             if len(window) >= window_size:
                 collect_one()
         while window:
@@ -1282,11 +1322,15 @@ class LocalBackend:
     def _dispatch_partition(self, part: C.Partition, device_fn, skey: str,
                             use_comp: bool = False, stage=None,
                             packed: bool = True):
-        """Stage the batch and launch the device call WITHOUT blocking
-        (jax dispatch is async; the result is awaited in _collect_partition).
-        Returns (part, pending_outs | None, dispatch_seconds)."""
+        """Stage the batch and launch the device call WITHOUT blocking:
+        jax dispatch is async, nothing between the launch and the return
+        polls, blocks or fetches (devprof on or off, traced or not), and
+        the outputs are awaited once, at the head of this partition's
+        collect (`_await_dispatch`), so the window's other dispatches run
+        on the chip beside the host. Returns (part, pending_outs | None,
+        dispatch_seconds, _Launch | None)."""
         if device_fn is None or part.n_normal() == 0:
-            return (part, None, 0.0)
+            return (part, None, 0.0, None)
         faults.maybe("dispatch")   # chaos checkpoint (runtime/faults): a
         # raise here rides the window as _DispatchFailed into the same
         # retry -> degrade ladder a real device failure takes
@@ -1320,15 +1364,13 @@ class LocalBackend:
         cache_key = ("stagefn", skey, use_comp, packed)
         spec = batch.spec()                     # jit retraces per shape
         first_call = not self.jit_cache.was_traced(cache_key, spec)
+        # the launch stamp the collect side's wait measures from (devprof's
+        # launch -> seen-ready sample): before the call, so a first call's
+        # trace, compile or AOT load lies inside a cold sample
+        launch = _Launch(time.perf_counter(), first_call)
         try:
             # name formatted only when tracing is on — dispatch is the
-            # per-partition hot path and the off-path must stay free.
-            # The devprof gate is read ONCE: another thread flipping it
-            # mid-dispatch (a new Context's apply_options) must not pair
-            # a zero t_dev with a later record (a perf_counter-epoch
-            # "sample" would poison the histograms).
-            dp_on = DP.enabled() and stage is not None
-            t_dev = time.perf_counter() if dp_on else 0.0
+            # per-partition hot path and the off-path must stay free
             with TR.span("dispatch:launch", "exec") as _lsp:
                 with TR.device_annotation(f"tpx:dispatch:{skey[:12]}"
                                           if TR.enabled() else ""):
@@ -1340,20 +1382,6 @@ class LocalBackend:
                     _lsp.set("module",
                              getattr(device_fn, "last_module", None)) \
                         .set("first_call", int(first_call))
-            if dp_on:
-                # measured device time: wait for this dispatch's device
-                # work (is_ready polling — see devprof.block_ready) and
-                # record launch→ready per partition, cold (first call
-                # spans the compile/AOT-load wait) vs warm. Costs the
-                # dispatch/merge overlap — that is the price of
-                # attribution; TUPLEX_DEVPROF=0 restores the fully-async
-                # window with a single flag check here.
-                with TR.span("dispatch:device-wait", "exec"):
-                    DP.block_ready(outs)
-                DP.record_dispatch(stage.key(),
-                                   time.perf_counter() - t_dev,
-                                   cold=first_call, rows=part.num_rows,
-                                   owner=id(self))
             if leaf_h2d:
                 xferstats.note_h2d(leaf_h2d, tag="leaf_stage")
             self.jit_cache.note_traced(cache_key, spec)
@@ -1366,13 +1394,14 @@ class LocalBackend:
                 return self._redispatch_plain(part, skey, stage, t0,
                                               packed=packed)
             self._not_compilable.add(skey)
-            return (part, None, time.perf_counter() - t0)
+            return (part, None, time.perf_counter() - t0, None)
         except CompileTimeout as e:
             # the executable's compile was killed at the deadline (or the
             # `.timeout` negative cache skipped it): NOT a per-partition
             # problem — ride the window as a sentinel so the collect side
             # restarts the WHOLE stage on one degraded tier
-            return (part, _CompileTimedOut(e), time.perf_counter() - t0)
+            return (part, _CompileTimedOut(e), time.perf_counter() - t0,
+                    None)
         except Exception as e:
             if not first_call:
                 raise  # executed before: a real runtime failure
@@ -1381,11 +1410,11 @@ class LocalBackend:
             from . import compilequeue as CQ
 
             if CQ.deserialize_defect(e):
-                # the fork-handback executable LOADED but its device
-                # work failed when it actually ran — jax dispatch is
-                # async, so the "Symbols not found" gap can surface at
-                # the block/collect site, OUTSIDE AotJit.__call__'s
-                # defect handler. Pin the doomed specs to the plain
+                # the fork-handback executable LOADED but failed as the
+                # first call ran it, OUTSIDE AotJit.__call__'s defect
+                # handler (what surfaces only once the chip gets to it
+                # takes the same route from the collect side's wait:
+                # `collect_one`). Pin the doomed specs to the plain
                 # in-process jit (persisting their `.nodeser` markers
                 # for cold runs) and retry this partition once on the
                 # recompiled path instead of demoting the stage to the
@@ -1411,8 +1440,8 @@ class LocalBackend:
                 "stage trace failed (%s: %s); falling back to the "
                 "interpreter", type(e).__name__, e)
             self._note_demotion(skey, "trace", e, part)
-            return (part, None, time.perf_counter() - t0)
-        return (part, outs, time.perf_counter() - t0)
+            return (part, None, time.perf_counter() - t0, None)
+        return (part, outs, time.perf_counter() - t0, launch)
 
     def _note_demotion(self, skey: str, phase: str, e: BaseException,
                        part=None) -> None:
@@ -1436,19 +1465,47 @@ class LocalBackend:
         must never demote work to the interpreter)."""
         self._compaction_off.add(skey.split("/", 1)[0])
         if stage is None:
-            return (part, None, time.perf_counter() - t0)
+            return (part, None, time.perf_counter() - t0, None)
         plain_fn, _ = self._build_stage_fn(stage, part.schema, skey, False,
                                            packed=packed)
         if plain_fn is None:
-            return (part, None, time.perf_counter() - t0)
+            return (part, None, time.perf_counter() - t0, None)
         res = self._dispatch_partition(part, plain_fn, skey, False, stage,
                                        packed=packed)
-        return (res[0], res[1], time.perf_counter() - t0)
+        return (res[0], res[1], time.perf_counter() - t0, res[3])
 
     # ------------------------------------------------------------------
+    def _await_dispatch(self, stage, part: C.Partition, pending,
+                        launch: _Launch, metrics: dict) -> None:
+        """The job thread's wait for one dispatch's outputs: once, at the
+        head of its collect and BEFORE any fetch, so the chip's seconds
+        stay out of the `d2h:*` spans. The span opens on every collected
+        dispatch, also where the outputs were ready on arrival, and says
+        which side set this partition's pace: `ready` 1, the host did
+        (nothing was waited for); 0, the chip did. `in_flight` 1 means
+        nothing ran beside the host. The same `is_ready` poll with
+        attribution on or off (`devprof.block_ready`): devprof only
+        records. What the poll raises fails the task (`collect_one`'s
+        retry -> elastic -> interpreter ladder), as a failed fetch does."""
+        with TR.span("dispatch:device-wait", "exec") as _sp:
+            _sp.set("in_flight", launch.in_flight)
+            ready = DP.block_ready(pending)
+            launch.ready_at = now = time.perf_counter()
+            _sp.set("ready", int(ready))
+        key = "dispatches_ready" if ready else "dispatches_waited"
+        metrics[key] = metrics.get(key, 0) + 1
+        # the chip runs a stage's dispatches one at a time: this one began
+        # at its launch or when its predecessor ended, whichever was later.
+        # Exact to the poll's 0.2 ms where the host waited; an upper bound
+        # (`late`) where the outputs were ready when the host came
+        DP.record_dispatch(stage.key(), now - max(launch.t, launch.after),
+                           cold=launch.cold, rows=part.num_rows,
+                           owner=id(self), late=ready)
+
     def _collect_partition(self, stage: TransformStage, part: C.Partition,
                            pending_outs, dispatch_s: float,
-                           intermediate: bool = False):
+                           intermediate: bool = False,
+                           launch: Optional[_Launch] = None):
         import jax
 
         metrics: dict[str, float] = {}
@@ -1482,8 +1539,12 @@ class LocalBackend:
         lazy_data = None               # device-resident data columns (deferred)
         if pending_outs is not None:
             t0 = time.perf_counter()
+            if launch is None:      # a caller with outputs and no stamp
+                launch = _Launch(t0, False)
             with TR.span("partition:collect-fast", "exec") as _sp:
                 _sp.set("rows", n)
+                self._await_dispatch(stage, part, pending_outs, launch,
+                                     metrics)
                 if intermediate and isinstance(pending_outs, dict) \
                         and type(self) is LocalBackend:
                     # handoff-bound partition: pull ONLY the control arrays
@@ -1535,7 +1596,14 @@ class LocalBackend:
                         packed=packed, tag=stage.key(),
                         n_ops=len(stage.ops)))
                 batch = C.stage_partition(part, self.bucket_mode)
+                relaunch = _Launch(
+                    time.perf_counter(),
+                    not self.jit_cache.was_traced(nkey, batch.spec()),
+                    in_flight=launch.in_flight, after=launch.ready_at)
                 pending2 = nfn(batch.arrays)
+                self._await_dispatch(stage, part, pending2, relaunch,
+                                     metrics)
+                launch.ready_at = relaunch.ready_at
                 outs = _get_outs(pending2)
                 self.jit_cache.note_traced(nkey, batch.spec())
                 outs.pop("#rowidx", None)

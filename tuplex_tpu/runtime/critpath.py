@@ -87,7 +87,9 @@ _SPAN_BUCKET = (
     ("resolve:interpreter", "resolve_interpreter"),
     ("partition:merge", "merge"),
     ("partition:collect", "d2h"),          # result materialization plane
-    ("partition:dispatch", "device"),      # exclusive time = launch+wait
+    ("partition:dispatch", "device"),      # exclusive time = the launch
+    # the job thread BLOCKED on the chip, at the head of a collect
+    ("dispatch:device-wait", "device_wait"),
 )
 
 #: sweep priority per bucket — when spans overlap, the highest priority
@@ -100,8 +102,13 @@ _SPAN_BUCKET = (
 #: thread is BLOCKED on the pool, so it outranks device and folds into
 #: compile_xla in the reported vector (analyze_events) — a cold inline
 #: compile is blamed on the compile plane, an overlapped pre-compile
-#: (no wait span on the job thread) still costs nothing.
+#: (no wait span on the job thread) still costs nothing. ``device_wait``
+#: is its twin on the chip's side: the wait for a dispatch's outputs sits
+#: inside the collect that needs them (a d2h-plane wrapper), it is the
+#: chip the thread is blocked on, so it outranks everything and folds
+#: into ``device``.
 _PRIO = {
+    "device_wait": 12,
     "resolve_interpreter": 11, "resolve_general": 10, "merge": 9,
     "d2h": 8, "h2d": 7, "compile_wait": 6, "device": 5,
     "compile_xla": 4, "compile_lower": 3, "compile_trace": 2,
@@ -435,12 +442,15 @@ def analyze_events(evts, wall_s: Optional[float] = None,
     # blocked-on-the-compile-pool slices report as compile_xla: the wait
     # wraps the pool's whole trace/lower/xla run, so the aggregate
     # compile bucket is the honest attribution for the blocked caller
-    if "compile_wait" in bucket_us:
-        bucket_us["compile_xla"] = bucket_us.get("compile_xla", 0.0) \
-            + bucket_us.pop("compile_wait")
-        for p in path:
-            if p[2] == "compile_wait":
-                p[2] = "compile_xla"
+    # (and blocked-on-the-chip slices as device)
+    for wait, plane in (("compile_wait", "compile_xla"),
+                        ("device_wait", "device")):
+        if wait in bucket_us:
+            bucket_us[plane] = bucket_us.get(plane, 0.0) \
+                + bucket_us.pop(wait)
+            for p in path:
+                if p[2] == wait:
+                    p[2] = plane
     buckets = {b: 0.0 for b in BUCKETS}
     for b, us in bucket_us.items():
         if b != "unattributed":

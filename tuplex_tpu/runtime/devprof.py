@@ -15,12 +15,17 @@ is exactly the signal a cost-based plan optimizer needs. Three pieces:
   sidecar NEXT TO the content-addressed executable artifact, so a warm
   second process recovers the analysis with zero recompiles — the AOT
   store becomes a queryable cost database, not a pile of opaque blobs.
-* **measured device time** — the dispatch path (exec/local) blocks each
-  launched partition until ready and records the launch→ready delta per
-  stage, split cold (first call: includes the compile/AOT-load wait) vs
-  warm. Samples land in telemetry histograms
-  (``device_dispatch_seconds{stage,state}``) and a per-stage accumulator
-  consumed into stage metrics.
+* **measured device time** — a dispatch returns at launch; the collect
+  side (exec/local) waits for its outputs where the host needs them and
+  records, per stage, the seconds from the launch (or from the moment
+  the stage's previous dispatch was seen ready, whichever is later: the
+  chip runs one at a time) to the moment this one was seen ready, split
+  cold (first call: includes the compile/AOT-load wait) vs warm. Where
+  the host waited that is the chip's time for the partition to the
+  poll's 0.2 ms; where the host arrived after the chip it is an upper
+  bound and the sample is flagged ``late``. Samples land in telemetry
+  histograms (``device_dispatch_seconds{stage,state}``) and a per-stage
+  accumulator consumed into stage metrics.
 * **roofline** — a small per-platform peak table (TPU generations from
   published specs; CPU a labeled estimate) turns flops/bytes/seconds
   into achieved FLOP/s, achieved bytes/s, arithmetic intensity and
@@ -28,12 +33,11 @@ is exactly the signal a cost-based plan optimizer needs. Three pieces:
   MemoryManager budget.
 
 Disabled (``TUPLEX_DEVPROF=0`` env kill switch) the record path is one
-module-flag check — no allocation, no lock, no block_until_ready (the
-same zero-overhead contract tracing/telemetry pin, test-asserted). Note
-the ENABLED path deliberately blocks each dispatch until the device
-finishes: that is what "measured device time" means, and it trades a
-little dispatch/merge overlap for attribution (steady-state zillow on
-CPU measures within noise; kill the switch for maximum-overlap runs).
+module-flag check — no allocation, no lock (the same zero-overhead
+contract tracing/telemetry pin, test-asserted). The switch turns the
+RECORDING off and nothing else: the collect-side wait is part of the
+schedule (the host needs the outputs there either way), so a stage
+launches and collects in the same order with attribution on or off.
 """
 
 from __future__ import annotations
@@ -65,7 +69,7 @@ def enabled() -> bool:
 
 def enable(on: bool = True) -> None:
     """Process-wide gate. TUPLEX_DEVPROF=0 wins over any option-driven
-    enable (A/B overhead timing, maximum-overlap production runs)."""
+    enable (A/B overhead timing of the recording itself)."""
     global _enabled
     _enabled = bool(on) and not _env_disabled()
 
@@ -295,7 +299,7 @@ _DISP: dict[tuple, dict] = {}
 _WARM_KEEP = 64                     # bounded warm-sample window per stage
 
 
-def block_ready(outs) -> None:
+def block_ready(outs) -> bool:
     """Wait until a dispatch's device work is done — by POLLING
     ``Array.is_ready()``, never ``jax.block_until_ready``. The
     distinction is load-bearing: block_until_ready touches the result
@@ -308,32 +312,44 @@ def block_ready(outs) -> None:
     buffer internals, at ±0.2 ms precision — noise next to the
     histogram's ±12% buckets. Handles the packed wire's PackedOuts
     (buf/vbuf/extras attributes — not a pytree) and plain pytrees.
-    Best-effort: a failure here must never kill the dispatch."""
-    try:
-        import jax
 
-        buf = getattr(outs, "buf", None)
-        if buf is not None:
-            outs = (buf, getattr(outs, "vbuf", None),
-                    getattr(outs, "extras", None))
-        for leaf in jax.tree_util.tree_leaves(outs):
-            ready = getattr(leaf, "is_ready", None)
-            if ready is None:
-                continue
-            while not ready():
-                time.sleep(0.0002)
-    except Exception:   # pragma: no cover - attribution is best-effort
-        pass
+    Returns whether every output was ready at the first look (the host
+    arrived after the chip: nothing was waited for). Called by the
+    collect side with attribution on or off — it is the job thread's
+    wait for the outputs, not a measurement — so what a poll raises
+    propagates: a dispatch that failed asynchronously fails its task
+    there, as a failed fetch does."""
+    import jax
+
+    buf = getattr(outs, "buf", None)
+    if buf is not None:
+        outs = (buf, getattr(outs, "vbuf", None),
+                getattr(outs, "extras", None))
+    on_arrival = True
+    for leaf in jax.tree_util.tree_leaves(outs):
+        ready = getattr(leaf, "is_ready", None)
+        if ready is None:
+            continue
+        while not ready():
+            on_arrival = False
+            time.sleep(0.0002)
+    return on_arrival
 
 
 def record_dispatch(tag: str, seconds: float, cold: bool = False,
-                    rows: int = 0, owner: int = 0) -> None:
-    """One launched-partition sample: launch→ready seconds. `cold` marks
-    the first call of an input spec (includes the compile / AOT-load /
-    dedup wait) so roofline math prefers
-    warm samples (see stage_report for the cold-only fallback). `owner`
-    scopes the accumulator to the dispatching backend so concurrent
-    jobs sharing a stage key don't pool windows."""
+                    rows: int = 0, owner: int = 0,
+                    late: bool = False) -> None:
+    """One launched-partition sample: seconds from the launch (or the
+    stage's previous dispatch seen ready, the later of the two) to this
+    one seen ready. `cold` marks the first call of an input spec
+    (includes the compile / AOT-load / dedup wait) so roofline math
+    prefers warm samples (see stage_report for the cold-only fallback).
+    `late` marks a sample whose outputs were ready when the host came
+    for them: an upper bound on the chip's time (it holds host work
+    too), counted in the sums and the histogram but kept out of the warm
+    median that feeds `roofline_frac`. `owner` scopes the accumulator to
+    the dispatching backend so concurrent jobs sharing a stage key don't
+    pool windows."""
     if not _enabled or not tag or seconds < 0:
         return
     from . import telemetry
@@ -362,7 +378,7 @@ def record_dispatch(tag: str, seconds: float, cold: bool = False,
         if cold:
             acc["cold_s"] += seconds
             acc["cold_n"] += 1
-        elif len(acc["warm"]) < _WARM_KEEP:
+        elif not late and len(acc["warm"]) < _WARM_KEEP:
             acc["warm"].append(seconds)
 
 
@@ -499,9 +515,10 @@ def stage_report(tag: str, mm_budget: int = 0,
     device_s / device_cold_s / device_dispatches, flops / device_bytes
     (analysis x dispatch count), hbm_peak (per-execution peak footprint),
     roofline_frac (warm-median seconds vs the platform roof; a stage
-    dispatched only cold falls back to the SMALLEST sample — still
-    compile/load-inclusive, so it UNDERSTATES utilization — warm runs
-    self-correct it), and hbm_budget_frac when the MemoryManager budget
+    whose every sample was cold or `late` falls back to the SMALLEST
+    sample — still compile/load- or host-inclusive, so it UNDERSTATES
+    utilization — warm waited-for runs self-correct it), and
+    hbm_budget_frac when the MemoryManager budget
     is known. Also updates the bounded exposition snapshot (telemetry
     /metrics gauges)."""
     if not _enabled or not tag:
@@ -522,9 +539,9 @@ def stage_report(tag: str, mm_budget: int = 0,
         rep["flops"] = cost.flops * acc["n"]
         rep["device_bytes"] = cost.bytes_accessed * acc["n"]
         rep["hbm_peak"] = cost.peak_bytes
-        # cold-only fallback: the smallest observed sample is the least
-        # compile/load-inflated one (a mean over cold samples would bury
-        # the execution under the compile wait entirely)
+        # cold- or late-only fallback: the smallest observed sample is
+        # the least compile/load/host-inflated one (a mean over cold
+        # samples would bury the execution under the compile wait)
         rl = roofline(cost.flops, cost.bytes_accessed,
                       warm_med if warm_med > 0 else acc["min_s"])
         if "roofline_frac" in rl:

@@ -48,6 +48,22 @@ def _pad(nb: int) -> int:
     return -(-nb // _ALIGN) * _ALIGN
 
 
+# the varlen payload leaves the executable as this many equal buffers at
+# most (one where it is small): the host needs payload[:total] alone, and
+# a WHOLE buffer is what a transfer fetches without an executable. A slice
+# on the device is one, and a chip runs its executables in the order they
+# were enqueued — behind every dispatch in flight, so the fetch of one
+# partition would wait out the chip's run of the next two
+_VCHUNKS = 16
+_VCHUNK_MIN = 1 << 16
+
+
+def _payload_chunking(nbytes: int) -> tuple:
+    """(chunks, bytes a chunk) of a varlen payload of `nbytes` capacity."""
+    n = min(_VCHUNKS, max(1, nbytes // _VCHUNK_MIN))
+    return n, max(_pad(-(-nbytes // n)), _ALIGN)
+
+
 def _packable(dtype) -> bool:
     """Dtypes the in-executable bitcasts handle on every backend. 64-bit
     ints split into u32 halves arithmetically (the XLA-TPU x64 legalizer
@@ -380,15 +396,18 @@ def _u32_bytes(v):
 
 def _device_pack_varlen(entries: list):
     """Traced: scatter every varlen entry's actual row bytes into ONE
-    contiguous payload buffer. entries: (kind, key, mat u8 [B, w],
-    lens i32 [B], dt_str). Capacity is the static worst case so the
-    executable is shape-stable; the host fetches only payload[:total]
-    after re-deriving the per-row lengths from the fixed buffer."""
+    contiguous payload, handed back as equal chunks (`_payload_chunking`).
+    entries: (kind, key, mat u8 [B, w], lens i32 [B], dt_str). Capacity
+    is the static worst case so the executable is shape-stable; the host
+    fetches only the chunks that hold payload[:total] after re-deriving
+    the per-row lengths from the fixed buffer."""
     lens = [e[3].astype(jnp.int64) for e in entries]
     all_lens = jnp.concatenate(lens)
     offs = jnp.cumsum(all_lens) - all_lens          # exclusive cumsum
-    cap = _pad(sum(int(e[2].shape[0] * e[2].shape[1]) for e in entries))
-    payload = jnp.zeros(max(cap, 1), jnp.uint8)
+    nch, chunk = _payload_chunking(
+        sum(int(e[2].shape[0] * e[2].shape[1]) for e in entries))
+    cap = nch * chunk
+    payload = jnp.zeros(cap, jnp.uint8)
     vspec = []
     row0 = 0
     for (kind, k, mat, ln, dt), ln64 in zip(entries, lens):
@@ -402,7 +421,7 @@ def _device_pack_varlen(entries: list):
             mat.reshape(-1), mode="drop")
         vspec.append((kind, k, (b, w), dt))
         row0 += b
-    return payload, tuple(vspec)
+    return tuple(payload.reshape(nch, chunk)), tuple(vspec)
 
 
 def _build_varlen(args, outs, pack_outs):
@@ -472,9 +491,9 @@ def _build_varlen(args, outs, pack_outs):
 
 class PackedOuts:
     """Async handle for a packed stage result: one fixed-layout device
-    buffer + layout, an optional varlen payload buffer (str leaves as
-    actual bytes), plus any per-leaf arrays whose dtype can't ride the
-    buffer (f64)."""
+    buffer + layout, an optional varlen payload (str leaves as actual
+    bytes; `vbuf`: its equal chunks, each a buffer of its own), plus any
+    per-leaf arrays whose dtype can't ride the buffer (f64)."""
 
     __slots__ = ("buf", "spec", "extras", "vbuf", "vspec")
 
@@ -482,7 +501,7 @@ class PackedOuts:
         self.buf = buf
         self.spec = spec
         self.extras = extras or {}
-        self.vbuf = vbuf
+        self.vbuf = tuple(vbuf or ())
         self.vspec = tuple(vspec or ())
 
     def to_host(self) -> dict:
@@ -520,11 +539,13 @@ class PackedOuts:
         return out
 
     def _unpack_varlen(self, out: dict) -> tuple:
-        """Fetch payload[:total] and rebuild every varlen entry in place
-        — str byte matrices, i64 high words, sparse '#err' codes. The
-        per-row lengths re-derive deterministically from the fixed buffer
-        (shipped lens / '#need' bitmaps), so no offsets travel. Returns
-        (bytes fetched, whether the native call built the matrices)."""
+        """Fetch the chunks that hold payload[:total] (whole buffers: no
+        executable joins the queue behind the dispatches in flight) and
+        rebuild every varlen entry in place — str byte matrices, i64 high
+        words, sparse '#err' codes. The per-row lengths re-derive
+        deterministically from the fixed buffer (shipped lens / '#need'
+        bitmaps), so no offsets travel. Returns (bytes fetched, whether
+        the native call built the matrices)."""
         live = out.pop("#live", None)
         live4 = None if live is None else np.asarray(live, dtype=np.int64) * 4
         lens = []
@@ -542,10 +563,9 @@ class PackedOuts:
                                 dtype=np.int64).reshape(-1) * 4
             lens.append(ln)
             total += int(ln.sum())
-        cap = int(self.vbuf.shape[0])
-        want = min(_pad(total), cap) if total else 0
-        payload = np.asarray(jax.device_get(self.vbuf[:want])) if want \
-            else np.zeros(0, np.uint8)
+        chunk = int(self.vbuf[0].shape[0])
+        parts = jax.device_get(list(self.vbuf[:-(-total // chunk)]))
+        payload = np.concatenate(parts) if parts else np.zeros(0, np.uint8)
         mats, native = _varlen_matrices(
             payload, lens, [w for _kind, _k, (_b, w), _dt in self.vspec])
         for (kind, k, _shape, dt), mat in zip(self.vspec, mats):
@@ -656,7 +676,7 @@ class PackedStageFn:
             obuf, ospec = _device_pack(pack_outs, skip=vskip,
                                        lo32=lo32)
             vbuf, vspec = (_device_pack_varlen(entries) if entries
-                           else (jnp.zeros(0, jnp.uint8), ()))
+                           else ((), ()))
             cell["ospec"] = ospec
             cell["vspec"] = vspec
             return obuf, vbuf, extra_outs
