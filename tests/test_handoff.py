@@ -5,14 +5,16 @@ The tentpole contract: a map -> join -> aggregate pipeline crosses BOTH
 stage boundaries without a host round-trip of the intermediate data
 columns — only the join-key column is ever pulled (for the host-side
 signature factorization), and the join output feeds the aggregate
-entirely from its device view. HANDOFF_STATS records every lazy leaf
-force so the test asserts the absence of transfers, not just timings."""
+entirely from its device view. Every lazy leaf force is a `d2h:lazy-load`
+span naming the leaf (and a count in HANDOFF_STATS), so the test asserts
+the absence of transfers, not just timings."""
 
 import numpy as np
 import pytest
 
 from tuplex_tpu.core import typesys as T
 from tuplex_tpu.runtime import columns as C
+from tuplex_tpu.runtime import tracing, xferstats
 
 
 @pytest.fixture()
@@ -21,8 +23,21 @@ def handoff_ctx(monkeypatch):
     import tuplex_tpu
 
     C.HANDOFF_STATS["lazy_parts"] = 0
-    C.HANDOFF_STATS["forced"] = []
-    return tuplex_tpu.Context({"tuplex.tpu.deviceJoin": "true"})
+    C.HANDOFF_STATS["forced"] = 0
+    tracing.clear()
+    tracing.enable(True)
+    yield tuplex_tpu.Context({"tuplex.tpu.deviceJoin": "true"})
+    tracing.enable(False)
+    tracing.clear()
+
+
+def _lazy_loads() -> list:
+    """(tag, leaf, bytes) of every `d2h:lazy-load` span; one a forced
+    leaf, as HANDOFF_STATS counts them."""
+    loads = [(e["args"]["tag"], e["args"]["leaf"], e["args"]["bytes"])
+             for e in tracing.events() if e["name"] == "d2h:lazy-load"]
+    assert len(loads) == C.HANDOFF_STATS["forced"]
+    return loads
 
 
 def _join_csvs(tmp_path, n=5000, keys=50):
@@ -50,7 +65,7 @@ def test_map_join_aggregate_no_host_roundtrip(handoff_ctx, tmp_path):
     # the ONLY host pull is the join-key column of the map output (leaf
     # path "0" = 'id'): no other map column, and NO join-output column,
     # ever crossed to host
-    for tag, key in C.HANDOFF_STATS["forced"]:
+    for tag, key, _ in _lazy_loads():
         assert tag == "stage" and key.split("#")[0] == "0", (tag, key)
 
 
@@ -69,7 +84,7 @@ def test_map_join_aggregate_by_key_handoff(handoff_ctx, tmp_path):
     # grouped aggregate over the device-resident join output touches only
     # its KEY column ('tag' = output leaf path "2"); map-output pulls stay
     # confined to its join key ("0")
-    for tag, key in C.HANDOFF_STATS["forced"]:
+    for tag, key, _ in _lazy_loads():
         base = key.split("#")[0]
         assert (tag, base) in (("stage", "0"), ("join", "2")), (tag, key)
 
@@ -97,7 +112,24 @@ def test_lazy_partition_collect_matches_host(handoff_ctx, tmp_path):
     ctx = handoff_ctx
     lp, rp = _join_csvs(tmp_path, n=800, keys=7)
     left = ctx.csv(lp).map(lambda x: {"id": x["id"], "v": x["val"] + 1})
+    x0, t0 = xferstats.snapshot(), xferstats.tags()
     got = left.join(ctx.csv(rp), "id", "id").collect()
+    # each forced leaf is one `d2h:lazy-load` span; its bytes are those
+    # noted under the `lazy_load` tag, and the total counts every tag
+    loads = _lazy_loads()
+    assert loads and {tag for tag, _, _ in loads} <= {"stage", "join"}
+    t1 = xferstats.tags()
+    moved = {k: t1[k] - t0.get(k, 0) for k in t1
+             if k.startswith("d2h_bytes:") and t1[k] != t0.get(k, 0)}
+    assert moved["d2h_bytes:lazy_load"] == sum(b for _, _, b in loads)
+    assert xferstats.delta(x0)["d2h_bytes"] == sum(moved.values())
+    # a box span counts the loads forced under it (the join's probe
+    # forced its key before any boxing began)
+    evs = tracing.events()
+    for box in (e for e in evs if e["name"] == "collect:box-partition"):
+        under = [e for e in evs if e["name"] == "d2h:lazy-load"
+                 and e["parent"] == box["id"]]
+        assert box["args"]["lazy_loads"] == len(under)
 
     import tuplex_tpu
 
@@ -120,6 +152,28 @@ def test_handoff_rerun_stable(handoff_ctx, tmp_path):
         lambda a, b: a + b, lambda a, x: a + x["v"], 0)
     assert ds.collect() == [sum(range(1200))]
     assert ds.collect() == [sum(range(1200))]
+
+
+# ---------------------------------------------------------------------------
+# the gathers over a stage's outputs, each jitted under its site's name
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("site", ["view", "lazy", "load"])
+def test_named_gather_lowers_under_its_site(site):
+    import jax.numpy as jnp
+
+    from tuplex_tpu.exec import local
+
+    take = getattr(local, f"take_{site}")
+    idx = jnp.asarray(np.array([7, 0, 3, 3, 5], np.int32))
+    for a in (jnp.arange(24, dtype=jnp.int64).reshape(8, 3),   # str bytes
+              jnp.arange(8, dtype=jnp.float64) / 3,            # a number
+              jnp.asarray(np.arange(8) % 3 == 0)):             # validity
+        text = take.lower(a, idx).as_text().lstrip()
+        assert text.startswith(f"module @jit_tpx_take_{site}"), text[:80]
+        got, want = take(a, idx), jnp.take(a, idx, axis=0)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------

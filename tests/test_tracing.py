@@ -829,3 +829,169 @@ def test_trace_smoke_zillow():
         capture_output=True, text=True, timeout=540, env=env, cwd=REPO)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
     assert "trace-smoke OK" in r.stdout
+
+
+# ===========================================================================
+# the collect side: boxing a partition at a time, the merge's paths, the
+# stage loop's own steps, the memory manager
+# ===========================================================================
+
+def _named(evs, name):
+    return [e for e in evs if e["name"] == name]
+
+
+def _ctx_small_parts(**extra):
+    import tuplex_tpu
+
+    opts = {"tuplex.partitionSize": "1KB",
+            "tuplex.sample.maxDetectionRows": "64"}
+    opts.update(extra)
+    return tuplex_tpu.Context(opts)
+
+
+def test_collect_opens_one_box_span_a_partition(trace_on):
+    c = _ctx_small_parts()
+    got = c.parallelize(list(range(300))).map(
+        lambda x: x * 2 if x % 50 else str(x)).collect()
+    assert got == [x * 2 if x % 50 else str(x) for x in range(300)]
+    evs = tracing.events()
+    (outer,) = _named(evs, "collect:box-rows")
+    boxes = sorted(_named(evs, "collect:box-partition"),
+                   key=lambda e: e["ts"])
+    assert len(boxes) >= 3
+    assert all(b["parent"] == outer["id"] and b["tid"] == outer["tid"]
+               for b in boxes)
+    assert sum(b["args"]["rows"] for b in boxes) == len(got) \
+        == outer["args"]["rows"]
+    # the interpreter resolved the str rows, which no int column holds:
+    # each partition's native decode is spliced with its fallback rows
+    off = 0
+    for b in boxes:
+        a = b["args"]
+        chunk = got[off: off + a["rows"]]
+        off += a["rows"]
+        assert (a["columns"], a["native"], a["lazy_loads"]) == (1, 1, 0)
+        assert a["fallback"] == sum(isinstance(v, str) for v in chunk)
+    assert sum(b["args"]["fallback"] for b in boxes) == 6
+    # a nested column is boxed by the per-column Python path
+    tracing.clear()
+    got = c.parallelize(list(range(100))).map(
+        lambda x: (x, (x, x + 1))).collect()
+    assert got[7] == (7, (7, 8))
+    boxes = _named(tracing.events(), "collect:box-partition")
+    assert {(b["args"]["native"], b["args"]["columns"]) for b in boxes} \
+        == {(0, 2)}
+    assert sum(b["args"]["rows"] for b in boxes) == 100
+
+
+def _id_val_csv(tmp_path, n=3000):
+    p = str(tmp_path / "iv.csv")
+    with open(p, "w") as fp:
+        fp.write("id,val\n")
+        for i in range(n):
+            fp.write(f"{i % 7},{i % 50}\n")
+    return p
+
+
+def _children(evs, parent):
+    return {e["name"]: e for e in evs if e.get("parent") == parent["id"]}
+
+
+def test_merge_names_its_path_and_the_work_inside(trace_on, tmp_path,
+                                                  monkeypatch):
+    monkeypatch.setenv("TUPLEX_DEVICE_HANDOFF", "1")
+    c = _ctx_small_parts(**{"tuplex.partitionSize": "256KB"})
+    p = _id_val_csv(tmp_path)
+    # a slow path touched every partition: the data columns come off the
+    # device after all, and the resolved rows are spliced among them
+    ds = c.csv(p).map(lambda x: {"id": x["id"], "v": 100 // x["val"]}) \
+        .resolve(ZeroDivisionError, lambda x: {"id": x["id"], "v": -1})
+    got = ds.aggregate(lambda a, b: a + b, lambda a, x: a + x["v"],
+                       0).collect()
+    assert got == [sum(100 // (i % 50) if i % 50 else -1
+                       for i in range(3000))]
+    evs = tracing.events()
+    merges = _named(evs, "partition:merge")
+    assert merges and {m["args"]["path"] for m in merges} == {"resolved"}
+    spliced = 0
+    for m in merges:
+        kids = _children(evs, m)
+        fetch, splice = kids["d2h:merge-fetch"], kids["merge:splice"]
+        assert fetch["args"]["bytes"] > 0 and fetch["args"]["ready"] in (0, 1)
+        assert fetch["ts"] < splice["ts"]
+        spliced += splice["args"]["rows"]
+    assert spliced == 3000 // 50
+    # nothing resolved: the output stays on the device behind a gathered
+    # view, and nothing is fetched
+    tracing.clear()
+    got = c.csv(p).map(lambda x: {"id": x["id"], "v": x["val"] + 1}) \
+        .aggregate(lambda a, b: a + b, lambda a, x: a + x["v"], 0).collect()
+    assert got == [sum(i % 50 + 1 for i in range(3000))]
+    evs = tracing.events()
+    merges = _named(evs, "partition:merge")
+    assert merges and {m["args"]["path"] for m in merges} == {"lazy"}
+    for m in merges:
+        kids = _children(evs, m)
+        assert "d2h:merge-fetch" not in kids
+        view = kids["handoff:view"]
+        assert view["args"]["leaves"] == 2 and view["args"]["bytes"] > 0
+    # the job's last stage: a host merge, with its outputs fetched at the
+    # head of the collect and nothing spliced
+    tracing.clear()
+    assert len(c.csv(p).map(lambda x: x["val"] + 1).collect()) == 3000
+    evs = tracing.events()
+    merges = _named(evs, "partition:merge")
+    assert merges and {m["args"]["path"] for m in merges} == {"host"}
+    assert not _named(evs, "d2h:merge-fetch") + _named(evs, "merge:splice")
+
+
+def test_stage_loop_steps_open_their_spans(ctx, trace_on):
+    data = [(i, i % 7) for i in range(2000)]
+    got = ctx.parallelize(data, columns=["a", "b"]).map(
+        lambda x: x["a"] // x["b"]).collect()
+    assert len(got) == 2000 - 286
+    evs = tracing.events()
+    (st,) = _named(evs, "stage:execute")
+    kids = sorted((e for e in evs if e.get("parent") == st["id"]),
+                  key=lambda e: e["ts"])
+    assert all(e["tid"] == st["tid"] for e in kids)
+    # each partition: its outputs fetched, its error lattice read into
+    # codes, the rows the device classified exactly recorded, the output
+    # merged, then its exceptions ordered and committed
+    steps = ("partition:collect-fast", "resolve:codes", "resolve:exact-exit",
+             "partition:merge", "resolve:record")
+    order = [e["name"] for e in kids if e["name"] in steps]
+    n = len(_named(kids, "partition:merge"))
+    assert n >= 1 and order == list(steps) * n
+    # the rows with a code, the records made of them, the records ordered
+    # and committed to the exception plane: each partition's 286 in all
+    for name in steps[1:]:
+        if name != "partition:merge":
+            assert sum(e["args"]["rows"] for e in _named(kids, name)) \
+                == 286, name
+    # what the stage's own seconds leave is the loop itself
+    assert sum(e["dur"] for e in kids) <= st["dur"]
+
+
+def test_spill_and_swap_in_are_spans_where_they_run(trace_on, tmp_path):
+    import tuplex_tpu
+
+    c = tuplex_tpu.Context({"tuplex.executorMemory": "64KB",
+                            "tuplex.partitionSize": "32KB",
+                            "tuplex.scratchDir": str(tmp_path / "scratch")})
+    x0 = xferstats.snapshot()
+    got = c.parallelize(list(range(20000))).map(lambda x: x * 3).collect()
+    assert got == [x * 3 for x in range(20000)]
+    evs = tracing.events()
+    spills, swaps = _named(evs, "mm:spill"), _named(evs, "mm:swap-in")
+    assert spills and swaps
+    assert all(e["cat"] == "io" and e["args"]["bytes"] > 0
+               for e in spills + swaps)
+    by_id = {e["id"]: e for e in evs}
+    # registering an output evicts the oldest; boxing swaps it back in
+    assert {by_id[e["parent"]]["name"] for e in spills} <= {
+        "stage:execute", "mm:swap-in", "collect:box-partition"}
+    assert "collect:box-partition" in {by_id[e["parent"]]["name"]
+                                       for e in swaps}
+    assert sum(e["args"]["bytes"] for e in spills) \
+        == xferstats.delta(x0)["spill_bytes"] > 0

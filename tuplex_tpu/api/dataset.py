@@ -417,15 +417,26 @@ class DataSet:
         import time as _time
 
         from ..runtime import tracing as TR
-        from ..runtime.columns import partition_to_pylist
+        from ..runtime.columns import HANDOFF_STATS, box_rows
 
         with self._job(limit):
             partitions = self._run_plan(limit)
             out = []
             with TR.span("collect:box-rows", "exec") as _bsp:
                 for p in partitions:
-                    self._context.backend.touch_partition(p)
-                    out.extend(partition_to_pylist(p))
+                    # one span a partition, wherever boxing runs
+                    with TR.span("collect:box-partition", "exec") as sp:
+                        forced = HANDOFF_STATS["forced"]
+                        self._context.backend.touch_partition(p)
+                        rows, native = box_rows(p)
+                        out.extend(rows)
+                        if sp is not TR.NOOP:
+                            sp.set("rows", len(rows)) \
+                              .set("columns", len(p.schema.types)) \
+                              .set("native", int(native)) \
+                              .set("fallback", len(p.fallback)) \
+                              .set("lazy_loads",
+                                   HANDOFF_STATS["forced"] - forced)
                 if limit >= 0:
                     out = out[:limit]
                 _bsp.set("rows", len(out))

@@ -1047,7 +1047,7 @@ class _DeviceProbe(_VectorBuild):
                 arrs = {}
                 for k in C.result_keys_for_leaf(outs, pth):
                     h = np.asarray(jax.device_get(outs[k][:m]))
-                    xferstats.note_d2h(h.nbytes)
+                    xferstats.note_d2h(h.nbytes, tag="lazy_load")
                     arrs[k] = h
                 return C.leaf_from_result_arrays(arrs, pth,
                                                  leaf_types[pth], m)

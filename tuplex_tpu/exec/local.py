@@ -36,7 +36,25 @@ from ..runtime import excprof as EX
 from ..runtime import faults
 from ..runtime import tracing as TR
 from ..runtime import xferstats
+from ..runtime.jaxcfg import jax as _jax, jnp as _jnp
 from ..runtime.packing import PackedOuts, PackedStageFn
+
+
+def _named_take(site: str):
+    """A row gather over one stage output, `jnp.take(a, idx, axis=0)`,
+    jitted under the name of its site: its module reads
+    `jit_tpx_take_<site>` on the device, where eager calls all read
+    `jit__take`. One launch a leaf, as the eager call was."""
+    def take(a, idx):
+        return _jnp.take(a, idx, axis=0)
+
+    take.__name__ = take.__qualname__ = f"tpx_take_{site}"
+    return _jax.jit(take)
+
+
+take_view = _named_take("view")    # _attach_device_view's handoff view
+take_lazy = _named_take("lazy")    # _lazy_merge's handoff view
+take_load = _named_take("load")    # a LazyLeaves load of a lazy merge
 
 
 def _get_outs(pending):
@@ -74,6 +92,19 @@ def _note_shards_fetched(sp, pending) -> None:
             shards += len(v.addressable_shards)
     if shards:
         sp.set("shards", shards).set("devices", devices)
+
+
+def _all_ready(arrays: dict) -> bool:
+    """Whether every device array of `arrays` is ready now (no wait)."""
+    return all(v.is_ready() for v in arrays.values()
+               if hasattr(v, "is_ready"))
+
+
+def _note_view(sp, arrays: dict) -> None:
+    """`handoff:view`: the leaves gathered and their device bytes."""
+    if sp is not TR.NOOP:
+        sp.set("leaves", len(arrays)).set(
+            "bytes", sum(int(v.nbytes) for v in arrays.values()))
 
 
 def _cpu_device():
@@ -1113,9 +1144,11 @@ class LocalBackend:
             self._handoff_left -= est
             src = np.zeros(b2, dtype=np.int32)
             src[:m] = outp._gather_src
-            idx = jnp.asarray(src)
-            arrays = {k: jnp.take(pending_outs[k], idx, axis=0)
-                      for k in expect}
+            with TR.span("handoff:view", "xfer") as sp:
+                idx = jnp.asarray(src)
+                arrays = {k: take_view(pending_outs[k], idx)
+                          for k in expect}
+                _note_view(sp, arrays)
             rv = np.zeros(b2, dtype=np.bool_)
             rv[:m] = True
             arrays["#rowvalid"] = jnp.asarray(rv)
@@ -1204,9 +1237,10 @@ class LocalBackend:
 
             src = np.zeros(b2, dtype=np.int32)
             src[:m] = comp_src
-            idx = jnp.asarray(src)
-            view = {k: jnp.take(data_arrays[k], idx, axis=0)
-                    for k in expect}
+            with TR.span("handoff:view", "xfer") as sp:
+                idx = jnp.asarray(src)
+                view = {k: take_lazy(data_arrays[k], idx) for k in expect}
+                _note_view(sp, view)
             rv = np.zeros(b2, dtype=np.bool_)
             rv[:m] = True
             view["#rowvalid"] = jnp.asarray(rv)
@@ -1220,9 +1254,9 @@ class LocalBackend:
             def loader(pth):
                 arrs = {}
                 for k in C.result_keys_for_leaf(data_arrays, pth):
-                    g = jnp.take(data_arrays[k], gsrc, axis=0)
+                    g = take_load(data_arrays[k], gsrc)
                     h = np.asarray(jax.device_get(g))
-                    xferstats.note_d2h(h.nbytes)
+                    xferstats.note_d2h(h.nbytes, tag="lazy_load")
                     arrs[k] = h
                 return C.leaf_from_result_arrays(arrs, pth,
                                                  leaf_types[pth], m)
@@ -1624,41 +1658,47 @@ class LocalBackend:
                 src_map = np.full(n, -1, dtype=np.int64)
                 src_map[rowidx[jpos]] = jpos
             metrics["fast_path_s"] = dispatch_s + time.perf_counter() - t0
-            err = np.asarray(outs.pop("#err"))[:n]
-            keep = np.asarray(outs.pop("#keep"))[:n]
-            rowvalid = np.zeros(n, dtype=np.bool_)
-            if part.normal_mask is None:
-                rowvalid[:] = True
-            else:
-                rowvalid[:] = part.normal_mask
-            err_rows = rowvalid & (err != 0)
-            err_idx = np.nonzero(err_rows)[0]
-            fallback_idx.update(err_idx.tolist())
-            # packed lattice value: class code | operator << 8, the
-            # operator as its position in the stage on the device and as
-            # THIS job's operator id from here on. Read by
-            # the no-resolver exact exit below AND the general-tier gate: a
-            # row whose fast-path code is already an exact Python class
-            # decoded fine under the normal case — the general re-run cannot
-            # change its outcome, so it skips that tier either way.
-            codes = stage.op_ids_of_lattice(err[err_idx])
-            device_codes.update(
-                zip(err_idx.tolist(), unpack_device_codes(codes)))
-            bufs.add_many(err_idx, codes)
-            if EX.enabled():
-                # exception-plane unpack accounting (runtime/excprof):
-                # the raw packed lattice carries code + operator id, so
-                # per-stage x per-op x per-code counts come vectorized
-                # off the same array the resolve buckets consumed
-                ex_defer.append((EX.note_device, (stage.key(), n, codes),
-                                 {"fallback_rows": len(part.fallback),
-                                  "owner": id(self)}))
-            compiled_ok = rowvalid & keep & (err == 0)
-            fold_vals = []
-            while f"#fold{len(fold_vals)}" in outs:
-                fold_vals.append(outs.pop(f"#fold{len(fold_vals)}"))
-            foldok = outs.pop("#foldok", None)
-            out_arrays = {k: np.asarray(v) for k, v in outs.items()}
+            # the error lattice read into per-row codes and the resolve
+            # plan's buckets
+            with TR.span("resolve:codes", "exec") as _csp:
+                err = np.asarray(outs.pop("#err"))[:n]
+                keep = np.asarray(outs.pop("#keep"))[:n]
+                rowvalid = np.zeros(n, dtype=np.bool_)
+                if part.normal_mask is None:
+                    rowvalid[:] = True
+                else:
+                    rowvalid[:] = part.normal_mask
+                err_rows = rowvalid & (err != 0)
+                err_idx = np.nonzero(err_rows)[0]
+                fallback_idx.update(err_idx.tolist())
+                # packed lattice value: class code | operator << 8, the
+                # operator as its position in the stage on the device and
+                # as THIS job's operator id from here on. Read by the
+                # no-resolver exact exit below AND the general-tier gate:
+                # a row whose fast-path code is already an exact Python
+                # class decoded fine under the normal case — the general
+                # re-run cannot change its outcome, so it skips that tier
+                # either way.
+                codes = stage.op_ids_of_lattice(err[err_idx])
+                device_codes.update(
+                    zip(err_idx.tolist(), unpack_device_codes(codes)))
+                bufs.add_many(err_idx, codes)
+                if EX.enabled():
+                    # exception-plane unpack accounting (runtime/excprof):
+                    # the raw packed lattice carries code + operator id, so
+                    # per-stage x per-op x per-code counts come vectorized
+                    # off the same array the resolve buckets consumed
+                    ex_defer.append((EX.note_device, (stage.key(), n, codes),
+                                     {"fallback_rows": len(part.fallback),
+                                      "owner": id(self)}))
+                compiled_ok = rowvalid & keep & (err == 0)
+                fold_vals = []
+                while f"#fold{len(fold_vals)}" in outs:
+                    fold_vals.append(outs.pop(f"#fold{len(fold_vals)}"))
+                foldok = outs.pop("#foldok", None)
+                out_arrays = {k: np.asarray(v) for k, v in outs.items()}
+                if _csp is not TR.NOOP:
+                    _csp.set("rows", len(err_idx))
         else:
             # whole partition interpreted (UDF not compilable / forced /
             # no normal-case rows)
@@ -1708,44 +1748,48 @@ class LocalBackend:
         exc_by_row: dict[int, ExceptionRecord] = {}
         if fallback_idx and not stage.has_resolvers \
                 and not self.interpret_only:
-            if bufs is not None and not rplan.use_general:
-                # the exact-class rows sit in their plan-time buckets
-                # already — no per-row dict probe + class lookup here
-                exact = [(i, op_id, code, exception_name(code))
-                         for i, code, op_id in bufs.exact_rows()
-                         if i in fallback_idx]
-            else:
-                # general tier ran: its verdicts superseded fast-path codes
-                # in device_codes, so classify from there
-                exact = []
-                for i in sorted(fallback_idx):
-                    code_op = device_codes.get(i)
-                    if code_op is None:
-                        continue
-                    code, op_id = code_op
-                    if exception_class_for_code(code) is not None:
-                        exact.append((i, op_id, code,
-                                      exception_name(code)))
-            # decode a handful of rows so history previews stay informative;
-            # counts only need the class name
-            sample = {}
-            if exact:
-                sidx = [i for i, _, _, _ in exact[:5]]
-                sample = dict(zip(sidx, C.decode_rows(part, sidx)))
-            for i, op_id, code, name in exact:
-                exc_by_row[i] = ExceptionRecord(op_id, name, sample.get(i))
-                fallback_idx.discard(i)
-            if EX.enabled() and exact:
-                ex_defer.append((EX.note_outcomes,
-                                 (stage.key(),
-                                  [(code, op_id)
-                                   for _, op_id, code, _ in exact],
-                                  "exact-exit"), {"owner": id(self)}))
-                for i, _op, code, _nm in exact[:5]:
-                    if i in sample:
-                        ex_defer.append((EX.sample_row,
-                                         (stage.key(), code, sample[i]),
-                                         {}))
+            # one exception record a row the device classified exactly
+            with TR.span("resolve:exact-exit", "exec") as _esp:
+                if bufs is not None and not rplan.use_general:
+                    # the exact-class rows sit in their plan-time buckets
+                    # already — no per-row dict probe + class lookup here
+                    exact = [(i, op_id, code, exception_name(code))
+                             for i, code, op_id in bufs.exact_rows()
+                             if i in fallback_idx]
+                else:
+                    # general tier ran: its verdicts superseded fast-path codes
+                    # in device_codes, so classify from there
+                    exact = []
+                    for i in sorted(fallback_idx):
+                        code_op = device_codes.get(i)
+                        if code_op is None:
+                            continue
+                        code, op_id = code_op
+                        if exception_class_for_code(code) is not None:
+                            exact.append((i, op_id, code,
+                                          exception_name(code)))
+                # decode a handful of rows so history previews stay
+                # informative; counts only need the class name
+                sample = {}
+                if exact:
+                    sidx = [i for i, _, _, _ in exact[:5]]
+                    sample = dict(zip(sidx, C.decode_rows(part, sidx)))
+                for i, op_id, code, name in exact:
+                    exc_by_row[i] = ExceptionRecord(op_id, name, sample.get(i))
+                    fallback_idx.discard(i)
+                if EX.enabled() and exact:
+                    ex_defer.append((EX.note_outcomes,
+                                     (stage.key(),
+                                      [(code, op_id)
+                                       for _, op_id, code, _ in exact],
+                                      "exact-exit"), {"owner": id(self)}))
+                    for i, _op, code, _nm in exact[:5]:
+                        if i in sample:
+                            ex_defer.append((EX.sample_row,
+                                             (stage.key(), code, sample[i]),
+                                             {}))
+                if _esp is not TR.NOOP:
+                    _esp.set("rows", len(exc_by_row))
 
         # ---- interpreter path (ResolveTask analog) ------------------------
         # one compiled closure chain per stage + bulk row decode: no per-row
@@ -1818,7 +1862,6 @@ class LocalBackend:
                         _sp.set("codes", ",".join(
                             f"{k}:{v}" for k, v in
                             sorted(code_counts.items())[:6]))
-        exceptions = [exc_by_row[i] for i in sorted(exc_by_row)]
         metrics["slow_path_s"] = time.perf_counter() - t0
 
         outp = None
@@ -1829,13 +1872,21 @@ class LocalBackend:
                 # view)
                 outp = self._lazy_merge(stage, part, compiled_ok, lazy_data,
                                         src_map)
-                _msp.set("lazy", outp is not None)
+            if _msp is not TR.NOOP:
+                _msp.set("path", "lazy" if outp is not None
+                         else "resolved" if resolved else "host")
             if outp is None:
                 if lazy_data is not None:
                     # a slow path touched this partition (or the lazy layout
                     # didn't qualify): pull the data columns after all
-                    out_arrays = {k: np.asarray(v)
-                                  for k, v in _get_outs(lazy_data).items()}
+                    with TR.span("d2h:merge-fetch", "xfer") as _fsp:
+                        if _fsp is not TR.NOOP:
+                            _fsp.set("ready", int(_all_ready(lazy_data)))
+                        out_arrays = {k: np.asarray(v) for k, v in
+                                      _get_outs(lazy_data).items()}
+                        if _fsp is not TR.NOOP:
+                            _fsp.set("bytes", sum(
+                                v.nbytes for v in out_arrays.values()))
                 outp = self._merge(stage, part, compiled_ok, out_arrays,
                                    resolved, src_map=src_map)
                 if intermediate and device_outs is not None and not resolved \
@@ -1856,11 +1907,16 @@ class LocalBackend:
                 stage.fold_op.id,
                 tuple(v.item() for v in fold_vals),
                 [int(r) for r in kept_rank[badmask]])
-        # this attempt produced the partition's output: commit its
-        # exception-plane records (a failure above left them unrecorded
-        # for the task-failure ladder's re-run to record afresh)
-        for fn, a, kw in ex_defer:
-            fn(*a, **kw)
+        # this attempt produced the partition's output: order its
+        # exceptions and commit its exception-plane records (a failure
+        # above left them unrecorded for the task-failure ladder's re-run
+        # to record afresh)
+        with TR.span("resolve:record", "exec") as _rsp:
+            exceptions = [exc_by_row[i] for i in sorted(exc_by_row)]
+            for fn, a, kw in ex_defer:
+                fn(*a, **kw)
+            if _rsp is not TR.NOOP:
+                _rsp.set("rows", len(exceptions))
         return outp, exceptions, metrics
 
     # ------------------------------------------------------------------
@@ -2052,6 +2108,20 @@ class LocalBackend:
                 full, np.arange(m, dtype=np.int64), comp_src, m)
             outp._gather_src = comp_src   # device-view handoff indices
             return outp
+        with TR.span("merge:splice", "exec") as sp:
+            if sp is not TR.NOOP:
+                sp.set("rows", len(resolved))
+            return self._splice(stage, part, compiled_ok, out_arrays,
+                                resolved, src_map)
+
+    def _splice(self, stage: TransformStage, part: C.Partition,
+                compiled_ok: np.ndarray, out_arrays: dict,
+                resolved: dict[int, Row],
+                src_map: np.ndarray | None) -> C.Partition:
+        """`_merge` where rows were resolved off the compiled path: the
+        emit order, the compiled rows gathered into it and the resolved
+        ones folded in (boxed into `fallback` where they do not fit)."""
+        n = part.num_rows
         emit_rows: list[tuple[int, Optional[int], Optional[Row]]] = []
         # (orig_idx, compiled_src or None, resolved Row or None)
         for i in range(n):

@@ -1611,19 +1611,9 @@ def _split_oversize(stage: TransformStage, options,
         # usually cheap) and splits ONLY when the predicted compile blows
         # the budget — flights' 43-op mega-fusion ran >20 min at >120 GB
         # on XLA:CPU.
-        from ..runtime import tracing as TR
-
-        with TR.span("plan:split-tune", "plan") as _sp:
-            dec = ST.plan_split(n, budget, platform,
-                                prefer_fusion=platform == "cpu",
-                                op_costs=op_costs)
-            if _sp is not TR.NOOP:
-                # the verdict rides the span so a trace shows WHY a plan
-                # split without digging through logs
-                _sp.set("n_ops", n).set("k", dec.k) \
-                   .set("over_budget", bool(dec.over_budget)) \
-                   .set("predicted_compile_s",
-                        round(float(dec.predicted_compile_s or 0.0), 3))
+        dec = ST.plan_split(n, budget, platform,
+                            prefer_fusion=platform == "cpu",
+                            op_costs=op_costs)
         stage.split_decision = dec
         stage.predicted_compile_s = dec.predicted_compile_s
         if dec.k > 1 or dec.over_budget:

@@ -30,6 +30,7 @@ import numpy as np
 
 from ..core import typesys as T
 from ..core.row import Row
+from . import tracing as TR
 
 
 # ---------------------------------------------------------------------------
@@ -273,9 +274,17 @@ def varlen_to_matrix(payload: np.ndarray, offs: np.ndarray,
 # lazy (device-backed) leaves — the host side of the stage handoff
 # ---------------------------------------------------------------------------
 
-# process-wide handoff observability (tests + bench): which lazy leaf dicts
-# were created and which leaf paths were ever forced to host. Reset freely.
-HANDOFF_STATS = {"lazy_parts": 0, "forced": []}
+# process-wide handoff observability (tests + bench): how many lazy leaf
+# dicts were created and how many leaves were forced to host (each force is
+# a `d2h:lazy-load` span, which names the leaf). Reset freely.
+HANDOFF_STATS = {"lazy_parts": 0, "forced": 0}
+
+
+def leaf_nbytes(leaf) -> int:
+    """Host bytes of one leaf's arrays (0 for a null or boxed leaf)."""
+    arrays = (getattr(leaf, "data", None), getattr(leaf, "bytes", None),
+              getattr(leaf, "lengths", None), getattr(leaf, "valid", None))
+    return sum(int(a.nbytes) for a in arrays if isinstance(a, np.ndarray))
 
 
 class LazyLeaves(dict):
@@ -315,8 +324,15 @@ class LazyLeaves(dict):
     # -- value access (forces the touched leaf) -------------------------
     def _load(self, k):
         if not super().__contains__(k):
-            HANDOFF_STATS["forced"].append((self._tag, k))
-            super().__setitem__(k, self._loader(k))
+            HANDOFF_STATS["forced"] += 1
+            # a D2H wherever a consumer forces the leaf (the loader notes
+            # its bytes under `d2h_bytes:lazy_load`)
+            with TR.span("d2h:lazy-load", "xfer") as sp:
+                leaf = self._loader(k)
+                if sp is not TR.NOOP:
+                    sp.set("tag", self._tag).set("leaf", k) \
+                      .set("bytes", leaf_nbytes(leaf))
+            super().__setitem__(k, leaf)
             if all(dict.__contains__(self, k2) for k2 in self._keys):
                 self._loader = None   # release the device-array closure
         return super().__getitem__(k)
@@ -1180,9 +1196,15 @@ def _decode_columns_native(part: Partition, n: int) -> Optional[list]:
 def partition_to_pylist(part: Partition) -> list:
     """Bulk row decode (reference analog: PythonDataSet.cc fast decoders —
     bulk converters instead of per-row boxing)."""
+    return box_rows(part)[0]
+
+
+def box_rows(part: Partition) -> "tuple[list, bool]":
+    """`partition_to_pylist`, and whether the native decoder built the rows
+    (False: the per-column Python path, or nothing to decode)."""
     n = part.num_rows
     if n == 0:
-        return []  # empty partitions may carry no leaf arrays at all
+        return [], False  # empty partitions may carry no leaf arrays at all
     single = len(part.schema.types) == 1
     out_fast = _decode_columns_native(part, n)
     if out_fast is not None:
@@ -1202,7 +1224,7 @@ def partition_to_pylist(part: Partition) -> list:
                 out[i] = v[0]
             else:
                 out[i] = v
-    return out
+    return out, out_fast is not None
 
 
 def _column_pylist(part: Partition, path: str, t: T.Type, n: int) -> list:

@@ -189,16 +189,17 @@ class MemoryManager:
     def _swap_out_locked(self, part: C.Partition, entry: _Entry) -> None:
         os.makedirs(self.scratch, exist_ok=True)
         path = os.path.join(self.scratch, f"p{uuid.uuid4().hex}.npz")
-        arrays = _leaves_to_npz_dict(part)
-        obj = {p: l for p, l in part.leaves.items()
-               if isinstance(l, C.ObjectLeaf)}
-        np.savez(path, **arrays)
+        with tracing.span("mm:spill", "io") as _sp:
+            arrays = _leaves_to_npz_dict(part)
+            obj = {p: l for p, l in part.leaves.items()
+                   if isinstance(l, C.ObjectLeaf)}
+            np.savez(path, **arrays)
+            if _sp is not tracing.NOOP:
+                _sp.set("rows", part.num_rows).set("bytes", entry.nbytes)
         sp = SpilledPartition(path, obj)
         self.swap_out_count += 1
         self.swapped_bytes += entry.nbytes
         xferstats.bump("spill_bytes", entry.nbytes, tag="swap_out")
-        tracing.instant("mm:swap-out", "mem",
-                        {"rows": part.num_rows, "bytes": entry.nbytes})
         self._inmem -= entry.nbytes
         entry.nbytes = 0
         part._spilled = sp  # type: ignore[attr-defined]
@@ -213,14 +214,15 @@ class MemoryManager:
 
     def _swap_in_locked(self, part: C.Partition) -> None:
         sp = part._spilled  # type: ignore[attr-defined]
-        with tracing.span("mm:swap-in", "mem") as _sp:
+        with tracing.span("mm:swap-in", "io") as _sp:
             part.leaves = sp.load()
-            _sp.set("rows", part.num_rows)
+            nb = part.nbytes()
+            if _sp is not tracing.NOOP:
+                _sp.set("rows", part.num_rows).set("bytes", nb)
         part._spilled = None  # type: ignore[attr-defined]
         sp.delete()
         self.swap_in_count += 1
         entry = self._entries.get(id(part))
-        nb = part.nbytes()
         if entry is not None:
             entry.nbytes = nb
         self._inmem += nb
