@@ -32,6 +32,11 @@ def test_spill_larger_than_memory(tmp_path):
     assert res == [x + 1 for x in data]
     mm = c.backend.mm
     assert mm.swap_out_count > 0, "expected partitions to spill"
+    # each output was boxed at its collect, before it could spill: nothing
+    # is read back to be boxed
+    assert mm.swap_in_count == 0
+    # a sink reads the columns themselves: what spilled comes back
+    c.parallelize(data).map(lambda x: x + 1).tocsv(str(tmp_path / "o.csv"))
     assert mm.swap_in_count > 0
 
 

@@ -351,3 +351,40 @@ def test_devprof_off_records_nothing_and_keeps_the_schedule(
     # the pace counters are the stage's own, not devprof's
     assert rec_off["dispatches_waited"] == rec_on["dispatches_waited"] \
         == len(log_on) // 3
+
+
+# ---------------------------------------------------------------------------
+# (f) the answer is boxed at each collect, while the window runs the chip
+# ---------------------------------------------------------------------------
+
+def test_each_partition_is_boxed_at_its_collect_before_the_next_wait(
+        chip, spans, monkeypatch):
+    from tuplex_tpu.runtime import columns as C
+
+    real = C.box_rows
+    at_box = []     # (partitions boxed before, launches, polls) at each box
+
+    def logged(part):
+        at_box.append((len(at_box), chip.launched, dict(chip.polls)))
+        chip.log.append(("box", len(at_box) - 1))
+        return real(part)
+
+    monkeypatch.setattr(C, "box_rows", logged)
+    got, rec, _be = run_job(3)
+    assert got == WANT
+    n = chip.launched
+    assert len(at_box) == n == rec["partitions_boxed"]
+    # partition k: its wait, its fetch, its rows, then the next one's wait
+    assert [e for e in chip.log if e[0] != "launch"] == \
+        [(what, k) for k in range(n) for what in ("wait", "fetch", "box")]
+    for k, launched, polls in at_box:
+        # k + 1 and k + 2 are on the chip and were not looked at yet
+        assert launched == min(n, k + 3)
+        assert all(polls.get(j, 0) == 0 for j in range(k + 1, launched))
+    (job,) = spans("job")
+    (stage,) = spans("stage:execute")
+    boxes = spans("collect:box-partition")
+    assert len(boxes) == n
+    assert all(b["parent"] == stage["id"] and b["tid"] == job["tid"]
+               and b["args"]["in_window"] == 1 for b in boxes)
+    assert sum(b["args"]["rows"] for b in boxes) == ROWS

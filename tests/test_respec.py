@@ -169,13 +169,13 @@ def test_tier_restart_reverts_to_incumbent_generation(tmp_path,
     orig = XL.LocalBackend._run_stage_tier
     tiers = []
 
-    def fake(self, st, stream, first, inter, tier):
+    def fake(self, st, stream, first, inter, tier, box_output=False):
         tiers.append(tier)
         if getattr(st, "_respec_revert", None) is not None:
             # simulate the candidate generation blowing its compile
             # deadline at dispatch time
             raise XL._TierRestart("cpu", RuntimeError("candidate wedge"))
-        return orig(self, st, stream, first, inter, tier)
+        return orig(self, st, stream, first, inter, tier, box_output)
 
     monkeypatch.setattr(XL.LocalBackend, "_run_stage_tier", fake)
     res = backend.execute(stage, list(parts))

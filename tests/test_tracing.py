@@ -856,11 +856,14 @@ def test_collect_opens_one_box_span_a_partition(trace_on):
     assert got == [x * 2 if x % 50 else str(x) for x in range(300)]
     evs = tracing.events()
     (outer,) = _named(evs, "collect:box-rows")
+    (stage,) = _named(evs, "stage:execute")
     boxes = sorted(_named(evs, "collect:box-partition"),
                    key=lambda e: e["ts"])
     assert len(boxes) >= 3
-    assert all(b["parent"] == outer["id"] and b["tid"] == outer["tid"]
-               for b in boxes)
+    # the last stage boxes each partition at its collect, on the job's
+    # thread; the action only concatenates what it was handed
+    assert all(b["parent"] == stage["id"] and b["tid"] == outer["tid"]
+               and b["args"]["in_window"] == 1 for b in boxes)
     assert sum(b["args"]["rows"] for b in boxes) == len(got) \
         == outer["args"]["rows"]
     # the interpreter resolved the str rows, which no int column holds:
@@ -984,14 +987,23 @@ def test_spill_and_swap_in_are_spans_where_they_run(trace_on, tmp_path):
     assert got == [x * 3 for x in range(20000)]
     evs = tracing.events()
     spills, swaps = _named(evs, "mm:spill"), _named(evs, "mm:swap-in")
-    assert spills and swaps
+    by_id = {e["id"]: e for e in evs}
+    # registering an output evicts the oldest, once its rows are boxed:
+    # nothing is read back to be boxed
+    assert spills and not swaps
+    assert {by_id[e["parent"]]["name"] for e in spills} == {"stage:execute"}
+    assert {by_id[e["parent"]]["name"]
+            for e in _named(evs, "collect:box-partition")} \
+        == {"stage:execute"}
+    # a sink reads the columns themselves: what was spilled comes back
+    tracing.clear()
+    c.parallelize(list(range(20000))).map(lambda x: x * 3).tocsv(
+        str(tmp_path / "out.csv"))
+    evs = tracing.events()
+    spills += _named(evs, "mm:spill")
+    swaps = _named(evs, "mm:swap-in")
+    assert swaps and not _named(evs, "collect:box-partition")
     assert all(e["cat"] == "io" and e["args"]["bytes"] > 0
                for e in spills + swaps)
-    by_id = {e["id"]: e for e in evs}
-    # registering an output evicts the oldest; boxing swaps it back in
-    assert {by_id[e["parent"]]["name"] for e in spills} <= {
-        "stage:execute", "mm:swap-in", "collect:box-partition"}
-    assert "collect:box-partition" in {by_id[e["parent"]]["name"]
-                                       for e in swaps}
     assert sum(e["args"]["bytes"] for e in spills) \
         == xferstats.delta(x0)["spill_bytes"] > 0

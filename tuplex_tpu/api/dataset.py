@@ -328,8 +328,11 @@ class DataSet:
         with self._job(limit):
             return self._run_plan(limit, output_sink)
 
-    def _run_plan(self, limit: int, output_sink=None) -> list:
-        """The body of an action, inside its `job` span."""
+    def _run_plan(self, limit: int, output_sink=None,
+                  box_output: bool = False) -> list:
+        """The body of an action, inside its `job` span. `box_output`: the
+        action boxes its answer, so a backend that can box the last stage's
+        partitions as it collects them is asked to (`Partition.boxed`)."""
         from ..runtime import tracing as TR
         from ..utils.signals import capture_sigint, check_interrupted
 
@@ -390,11 +393,14 @@ class DataSet:
 
                     consumer = consumer_kind(stages, si)
                     kw = {}
-                    if output_sink is not None and \
-                            si == len(stages) - 1 and \
+                    last = si == len(stages) - 1
+                    if output_sink is not None and last and \
                             getattr(backend, "supports_sink_pushdown",
                                     False):
                         kw["sink"] = output_sink
+                    if box_output and last and \
+                            getattr(backend, "supports_box_output", False):
+                        kw["box_output"] = True
                     recorder.stage_started(stage)
                     backend.progress_cb = recorder.task_progress
                     try:
@@ -417,26 +423,21 @@ class DataSet:
         import time as _time
 
         from ..runtime import tracing as TR
-        from ..runtime.columns import HANDOFF_STATS, box_rows
+        from ..runtime.columns import box_partition
 
         with self._job(limit):
-            partitions = self._run_plan(limit)
+            # a last stage that can boxes each partition at its collect,
+            # inside the dispatch window; the rest are boxed here
+            partitions = self._run_plan(limit, box_output=True)
             out = []
             with TR.span("collect:box-rows", "exec") as _bsp:
                 for p in partitions:
-                    # one span a partition, wherever boxing runs
-                    with TR.span("collect:box-partition", "exec") as sp:
-                        forced = HANDOFF_STATS["forced"]
-                        self._context.backend.touch_partition(p)
-                        rows, native = box_rows(p)
-                        out.extend(rows)
-                        if sp is not TR.NOOP:
-                            sp.set("rows", len(rows)) \
-                              .set("columns", len(p.schema.types)) \
-                              .set("native", int(native)) \
-                              .set("fallback", len(p.fallback)) \
-                              .set("lazy_loads",
-                                   HANDOFF_STATS["forced"] - forced)
+                    rows = p.boxed
+                    if rows is None:
+                        rows = box_partition(
+                            p, self._context.backend.touch_partition,
+                            in_window=False)
+                    out.extend(rows)
                 if limit >= 0:
                     out = out[:limit]
                 _bsp.set("rows", len(out))

@@ -342,6 +342,9 @@ class LocalBackend:
     # (_general_case_pass); a backend whose rows are not all in this
     # process opts out
     host_resolve = True
+    # a transform stage's collect can box the action's answer partition by
+    # partition (`execute_any(box_output=True)`)
+    supports_box_output = True
 
     def __init__(self, options):
         self.options = options
@@ -624,13 +627,15 @@ class LocalBackend:
 
     # ------------------------------------------------------------------
     def execute_any(self, stage, partitions, context,
-                    intermediate=False) -> StageResult:
+                    intermediate=False, box_output=False) -> StageResult:
         """Dispatch by stage kind (reference: LocalBackend.cc:145-180).
         `intermediate`: a later stage consumes this one's output (enables
         the device-resident handoff; terminal outputs only ever go to
         host). It is False or the CONSUMER KIND — "stage" / "join" /
         "agg" — so the handoff gate can be tuned per consumer
-        (jaxcfg.device_handoff_enabled).
+        (jaxcfg.device_handoff_enabled). `box_output`: the action boxes
+        this (last) stage's output; a transform stage boxes each partition
+        at its collect, other kinds leave it to the action.
 
         Transfer attribution happens HERE, for every stage kind: the
         stage's xferstats delta (d2h/h2d bytes) lands on its metrics
@@ -652,15 +657,17 @@ class LocalBackend:
                                              intermediate=intermediate)
         else:
             res = self.execute(stage, partitions or [],
-                               intermediate=intermediate)
+                               intermediate=intermediate,
+                               box_output=box_output)
         xd = xferstats.delta(x_snap)
         res.metrics["d2h_bytes"] = xd["d2h_bytes"]
         res.metrics["h2d_bytes"] = xd["h2d_bytes"]
         return res
 
     # ------------------------------------------------------------------
-    def execute(self, stage: TransformStage,
-                partitions, intermediate: bool = False) -> StageResult:
+    def execute(self, stage: TransformStage, partitions,
+                intermediate: bool = False,
+                box_output: bool = False) -> StageResult:
         """Span-wrapped stage entry: one `stage:execute` span per stage
         (runtime/tracing); transfer attribution happens in execute_any so
         every stage kind gets it; the windowed impl below does the
@@ -669,7 +676,8 @@ class LocalBackend:
             if sp is not TR.NOOP:
                 sp.set("kind", type(stage).__name__)
                 sp.set("key", stage.key()[:12]).set("n_ops", len(stage.ops))
-            res = self._execute_windowed(stage, partitions, intermediate)
+            res = self._execute_windowed(stage, partitions, intermediate,
+                                         box_output)
             if sp is not TR.NOOP:
                 sp.set("rows_out", res.metrics.get("rows_out", 0))
                 for k in ("device_s", "flops", "hbm_peak",
@@ -681,7 +689,8 @@ class LocalBackend:
 
     def _execute_windowed(self, stage: TransformStage,
                           partitions,
-                          intermediate: bool = False) -> StageResult:
+                          intermediate: bool = False,
+                          box_output: bool = False) -> StageResult:
         """Window-pipelined dual-mode execution (reference analog:
         Executor/WorkQueue task parallelism, Executor.h:45-109 +
         LocalBackend.cc:1531-1586). Device dispatch is ASYNC — while the
@@ -735,7 +744,7 @@ class LocalBackend:
             stream = chain(list(seen), rec) if restarts else rec
             try:
                 res = self._run_stage_tier(stage, stream, first_part,
-                                           intermediate, tier)
+                                           intermediate, tier, box_output)
                 res.metrics["tier_restarts"] = restarts
                 return res
             except _TierRestart as tr:
@@ -792,12 +801,16 @@ class LocalBackend:
                     stage.key()[:12], tr.cause, tier, restarts)
 
     def _run_stage_tier(self, stage: TransformStage, stream, first_part,
-                        intermediate, tier: str) -> StageResult:
+                        intermediate, tier: str,
+                        box_output: bool = False) -> StageResult:
         """One tier attempt of the windowed executor. `tier` is 'device'
         (normal: accelerator/packed compile), 'cpu' (host-pinned compile
         after a device-tier deadline) or 'interpreter' (no compiled fast
         path at all). Raises _TierRestart when a compile deadline means
-        the stage must re-run one rung down."""
+        the stage must re-run one rung down. `box_output`: each output
+        partition is boxed (`Partition.boxed`) as it is collected, while
+        the chip runs the dispatches behind it; rows boxed on an aborted
+        tier go with its `out_parts`."""
         from collections import deque
 
         from . import compilequeue as CQ
@@ -815,7 +828,10 @@ class LocalBackend:
                                    # still busy, and those whose outputs
                                    # were ready when the host came
                                    "dispatches_waited": 0,
-                                   "dispatches_ready": 0}
+                                   "dispatches_ready": 0,
+                                   # output partitions boxed at their
+                                   # collect, inside the window
+                                   "partitions_boxed": 0}
         if EX.enabled():
             # exception-plane baseline (runtime/excprof): snapshot the
             # plan-time code inventory + resolve-plan verdict BEFORE any
@@ -1001,7 +1017,6 @@ class LocalBackend:
                             outp, excs, m = collect(part, None, 0.0, None)
             finally:
                 self.mm.unpin(part)
-            self.mm.register(outp)
             metrics["fast_path_s"] += m.get("fast_path_s", 0.0)
             metrics["slow_path_s"] += m.get("slow_path_s", 0.0)
             metrics["general_path_s"] += m.get("general_path_s", 0.0)
@@ -1011,6 +1026,13 @@ class LocalBackend:
             exceptions.extend(excs)
             if limit >= 0 and emitted_total + outp.num_rows > limit:
                 outp = _truncate_partition(outp, limit - emitted_total)
+            if box_output:
+                # before it is registered: the memory manager must not
+                # spill a partition only to read it back for its rows
+                outp.boxed = C.box_partition(outp, self.touch_partition,
+                                             in_window=True)
+                metrics["partitions_boxed"] += 1
+            self.mm.register(outp)
             emitted_total += outp.num_rows
             out_parts.append(outp)
             if self.progress_cb is not None:

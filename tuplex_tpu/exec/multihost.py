@@ -170,7 +170,8 @@ class MultiHostBackend(LocalBackend):
                 "output": M.shard_layout(outs["#err"])}
 
     # -- host-sharded reads (each process staged ONLY its byte range) ------
-    def execute(self, stage, partitions, intermediate: bool = False):
+    def execute(self, stage, partitions, intermediate: bool = False,
+                box_output: bool = False):
         import itertools
 
         it = iter(partitions or [])
@@ -188,7 +189,8 @@ class MultiHostBackend(LocalBackend):
                     _sp.set("rows_out", res.metrics.get("rows_out", 0))
             return res
         parts = [] if first is None else itertools.chain([first], it)
-        return super().execute(stage, parts, intermediate=intermediate)
+        return super().execute(stage, parts, intermediate=intermediate,
+                               box_output=box_output)
 
     def _execute_hostblock(self, stage, part):
         """Transform-stage execution over a host-sharded source: the global

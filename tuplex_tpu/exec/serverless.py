@@ -349,6 +349,8 @@ class ServerlessBackend(LocalBackend):
     # tasks writing S3 output.part-N, AWSLambdaBackend.cc:410-430)
     supports_sink_pushdown = True
     host_resolve = False
+    # the workers' part files come back unboxed: the action boxes them
+    supports_box_output = False
 
     def __init__(self, options):
         super().__init__(options)

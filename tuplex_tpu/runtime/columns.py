@@ -426,6 +426,9 @@ class Partition:
     normal_mask: Optional[np.ndarray] = None      # [N] bool; None => all normal
     fallback: dict[int, Any] = field(default_factory=dict)
     start_index: int = 0                          # global row offset of row 0
+    # the rows of an action's answer, boxed by the stage that made this
+    # partition (`box_partition` in its collect); None: not boxed yet
+    boxed: Optional[list] = field(default=None, repr=False, compare=False)
 
     @property
     def columns(self) -> tuple[str, ...]:
@@ -1197,6 +1200,26 @@ def partition_to_pylist(part: Partition) -> list:
     """Bulk row decode (reference analog: PythonDataSet.cc fast decoders —
     bulk converters instead of per-row boxing)."""
     return box_rows(part)[0]
+
+
+def box_partition(part: Partition, touch, in_window: bool) -> list:
+    """One output partition of an action's answer as Python rows, inside
+    its `collect:box-partition` span: `touch` (the backend's swap-in of a
+    spilled partition), then `box_rows`. `in_window`: the stage's collect
+    boxes it while the chip runs the dispatches behind it (1), or the
+    action boxes it after the plan (0)."""
+    with TR.span("collect:box-partition", "exec") as sp:
+        forced = HANDOFF_STATS["forced"]
+        touch(part)
+        rows, native = box_rows(part)
+        if sp is not TR.NOOP:
+            sp.set("rows", len(rows)) \
+              .set("columns", len(part.schema.types)) \
+              .set("native", int(native)) \
+              .set("fallback", len(part.fallback)) \
+              .set("lazy_loads", HANDOFF_STATS["forced"] - forced) \
+              .set("in_window", int(in_window))
+    return rows
 
 
 def box_rows(part: Partition) -> "tuple[list, bool]":
