@@ -84,7 +84,7 @@ def run_interp(f, values, columns=None):
 
 _INTERNAL_CODES = {"NORMALCASEVIOLATION", "BADPARSE_STRING_INPUT",
                    "NULLERROR", "GENERALCASEVIOLATION", "PYTHON_FALLBACK",
-                   "LOOPCAPEXCEEDED"}
+                   "LOOPCAPEXCEEDED", "REGEXFALLBACK"}
 
 
 def check(f, values, columns=None):

@@ -433,3 +433,32 @@ def test_pallas_nfa_row_block_edges(n, monkeypatch):
     rx = compile_nfa("a+b$")
     got = np.asarray(rx.match(b, l)).tolist()
     assert got == [re.search("a+b$", s) is not None for s in strings]
+
+
+@pytest.mark.parametrize("w,wout", [(1100, 16), (1152, 256), (1280, 1280),
+                                    (2048, 300)])
+def test_the_shift_barrel_equals_the_gather_past_the_one_hot_width(w, wout):
+    """Past `_OH_MAX_W` an accelerator slices by a barrel of static shifts
+    (`shifted_cols`), the CPU by the gather: forced here on the CPU, the
+    barrel gives the gather's bytes for `slice_` and `splice_prefix`."""
+    rng = np.random.default_rng(w)
+    n = 96
+    b = jnp.asarray(rng.integers(1, 256, (n, w), dtype=np.uint8))
+    ln = jnp.asarray(rng.integers(0, w + 1, n).astype(np.int32))
+    start = jnp.asarray(rng.integers(-40, w + 40, n).astype(np.int32))
+    stop = jnp.asarray(rng.integers(-40, w + 40, n).astype(np.int32))
+    end = jnp.asarray(rng.integers(0, w + 1, n).astype(np.int32))
+    nb = jnp.asarray(rng.integers(65, 91, (n, 12), dtype=np.uint8))
+    nl = jnp.asarray(rng.integers(0, 13, n).astype(np.int32))
+    valid = jnp.asarray(rng.random(n) < 0.7)
+    end = jnp.minimum(end, ln)
+    got = {}
+    for barrel in (True, False):
+        with S.mxu_gather_override(barrel):
+            got[barrel] = (
+                S.slice_(b, ln, start, stop, out_width=wout),
+                S.splice_prefix(b, ln, end, nb, nl, valid))
+    for (xb, xl), (yb, yl) in zip(got[True], got[False]):
+        assert xb.shape == yb.shape
+        np.testing.assert_array_equal(np.asarray(xl), np.asarray(yl))
+        np.testing.assert_array_equal(np.asarray(xb), np.asarray(yb))

@@ -81,16 +81,20 @@ def _build_instrumented(udf) -> tuple[Callable, dict, "_WrapTests"]:
 
     g = dict(udf.globals)
     g["__tpx_b__"] = rec
+    return compile_tree(tree, g), hits, w
+
+
+def compile_tree(tree, g: dict) -> Callable:
+    """The function of a UDF's tree (a lambda or a def), with globals `g`:
+    a sample profile re-runs the UDF under globals or a tree of its own."""
     if isinstance(tree, ast.Lambda):
         expr = ast.Expression(body=tree)
         ast.fix_missing_locations(expr)
-        f = eval(compile(expr, "<branchprof>", "eval"), g)
-    else:
-        mod = ast.Module(body=[tree], type_ignores=[])
-        ast.fix_missing_locations(mod)
-        exec(compile(mod, "<branchprof>", "exec"), g)
-        f = g[tree.name]
-    return f, hits, w
+        return eval(compile(expr, "<profile>", "eval"), g)
+    mod = ast.Module(body=[tree], type_ignores=[])
+    ast.fix_missing_locations(mod)
+    exec(compile(mod, "<profile>", "exec"), g)
+    return g[tree.name]
 
 
 _CHEAP_CALLS = {"len", "abs", "min", "max", "ord", "chr", "bool"}

@@ -63,6 +63,8 @@ class EmitCtx:
         self.seed = seed
         self._rng_base = None
         self._rng_n = 0
+        # engines of the compiled regex sites, in emission order
+        self.regex_sites: list[str] = []
 
     def next_rng_key(self):
         if self.seed is None:
@@ -91,12 +93,15 @@ class EmitCtx:
 
 class Emitter:
     def __init__(self, ctx: EmitCtx, globals_: dict[str, Any],
-                 branch_profile: Optional[dict] = None):
+                 branch_profile: Optional[dict] = None,
+                 regex_widths: Optional[dict] = None):
         self.ctx = ctx
         self.globals = globals_
         # sample branch observations for speculative arm pruning
         # (compiler/branchprof.py); None/{} disables speculation
         self.branch_profile = branch_profile or None
+        # sampled capture-group windows by pattern (compiler/regexprof.py)
+        self.regex_widths = regex_widths or {}
 
     # ------------------------------------------------------------------ UDF
     def eval_udf(self, udf: UDFSource, args: list[CV]) -> CV:
@@ -127,7 +132,8 @@ class Emitter:
         src = get_udf_source(func)
         if src.source == "":
             raise NotCompilable(f"no source for helper {src.name}")
-        sub = Emitter(self.ctx, {**src.globals})
+        sub = Emitter(self.ctx, {**src.globals},
+                      regex_widths=self.regex_widths)
         return sub.eval_udf(src, args)
 
 
@@ -1196,7 +1202,7 @@ class Frame:
         FunctionRegistry.h:71-205 codegens re.search; here the pattern
         compiles to whole-column kernel steps — ops/regex.py). Rows whose
         match needs deeper backtracking than the compiled engine explores
-        raise PYTHON_FALLBACK and resolve exactly on the interpreter."""
+        raise REGEXFALLBACK and resolve exactly on the interpreter."""
         from ..ops.regex import compile_regex
 
         if len(args) != 2:
@@ -1244,12 +1250,16 @@ class Frame:
                     rx2 = compile_regex("^" + pattern)
                 except NotCompilable:
                     rx2 = None
+            from ..ops.nfa import _nfa_impl
+
             if rx2 is None:
                 # boolean-only: exact existence via the bit-parallel
                 # engine; .group() raises NotCompilable and the whole UDF
                 # interprets
+                self._regex_site(f"nfa:{_nfa_impl()}")
                 return CV(t=T.option(T.tuple_of(T.STR)), elts=(),
                           valid=nfa.match(sb, sl), kind="match")
+            self._regex_site(f"nfa:{_nfa_impl()}+direct")
             matched, start = nfa.match_start(sb, sl)
             cell: list = []
 
@@ -1272,20 +1282,26 @@ class Frame:
                                             (rx2.n_groups + 1))),
                       elts=(), valid=matched, kind="match",
                       names=("#lazy_groups", _two_pass))
-        matched, suspect, gs, ge = rx.match(sb, sl)
-        self.raise_where(suspect & ~matched, ExceptionCode.PYTHON_FALLBACK)
+        self._regex_site(rx.engine())
+        matched, suspect, gs, ge = rx.match_decided(sb, sl)
+        self.raise_where(suspect & ~matched, ExceptionCode.REGEXFALLBACK)
         t_match = T.option(T.tuple_of(*[T.STR] * (rx.n_groups + 1)))
-        win = self._GROUP_WIN
-        if sb.shape[1] <= win:
+        wins = self.em.regex_widths.get(pattern)
+        if wins is None or len(wins) != rx.n_groups:
+            wins = (self._GROUP_WIN,) * rx.n_groups
+        w = sb.shape[1]
+        if all(w <= win for win in wins):
             elts = []
             for g in range(rx.n_groups + 1):
                 bb, bl = S.slice_(sb, sl, gs[g], ge[g])
                 elts.append(CV(t=T.STR, sbytes=bb, slen=bl))
             return CV(t=t_match, elts=tuple(elts), valid=matched,
                       kind="match")
-        # wide sources: capture groups slice to _GROUP_WIN instead of the
-        # source width — every downstream pass over a group column
-        # (parses, compares, output buffers, boxing) then reads 48
+        # wide sources: each capture group slices to its window instead of
+        # the source width — the window the operator's sample gives the
+        # group (compiler/regexprof.py), else _GROUP_WIN — so every
+        # downstream pass over a group column (parses, compares, output
+        # buffers, the wire, boxing, a join on it) reads that many
         # bytes/row, not W. Rows with a longer group ROUTE in ONE combined
         # raise (fail-safe, same contract as ops.strings._PARSE_WIN;
         # per-group raises fragmented statement fusion 4x). Slicing AND
@@ -1298,11 +1314,13 @@ class Frame:
             if not cell:
                 over = jnp.zeros(self.ctx.b, dtype=bool)
                 for g in range(1, rx.n_groups + 1):
-                    over = over | (ge[g] - gs[g] > win)
+                    if wins[g - 1] < w:
+                        over = over | (ge[g] - gs[g] > wins[g - 1])
                 elts = []
                 for g in range(rx.n_groups + 1):
                     bb, bl = S.slice_(sb, sl, gs[g], ge[g],
-                                      out_width=win if g else None)
+                                      out_width=min(wins[g - 1], w)
+                                      if g else None)
                     elts.append(CV(t=T.STR, sbytes=bb, slen=bl))
                 cell.append((tuple(elts), matched & over))
             return cell[0]
@@ -1313,29 +1331,64 @@ class Frame:
     _GROUP_WIN = 48
 
     def _re_sub(self, args: list[CV]) -> CV:
-        """Compiled re.sub for the class-run subset ('[class]+' / '\\d+' /
-        '\\s+' style — one character class repeated at least once, the
-        common data-cleaning shape; reference: FunctionRegistry re.sub).
-        Everything else falls back to the interpreter."""
+        """Compiled re.sub: a ^-anchored pattern with a constant or a
+        per-row replacement (_re_sub_anchored); otherwise, with a constant
+        replacement, the class-run subset ('[class]+' / '\\d+' / '\\s+'
+        style — one character class repeated at least once, the common
+        data-cleaning shape; reference: FunctionRegistry re.sub) and the
+        general match loop. Everything else falls back to the
+        interpreter."""
         if len(args) != 3:
             raise NotCompilable("re.sub arity")
         pat, repl, s = args
         if not (pat.is_const and isinstance(pat.const, str)):
             raise NotCompilable("dynamic regex pattern")
-        if not (repl.is_const and isinstance(repl.const, str)):
+        if repl.is_const:
+            if not isinstance(repl.const, str):
+                raise NotCompilable("re.sub non-string replacement")
+            if "\\" in repl.const:
+                raise NotCompilable("re.sub backreference replacement")
+        elif not pat.const.startswith("^"):
             raise NotCompilable("re.sub dynamic replacement")
-        if "\\" in repl.const:
-            raise NotCompilable("re.sub backreference replacement")
+        elif repl.base is not T.STR or repl.valid is not None:
+            raise NotCompilable("re.sub replacement not a string")
         if s.valid is not None:
             self.raise_where(~s.valid, ExceptionCode.TYPEERROR)
         s = materialize(s, self.ctx.b)
         rb, rl = self._to_strpair(s)
         self._ascii_guard(rb, rl)
+        if pat.const.startswith("^"):
+            return self._re_sub_anchored(pat.const, repl, rb, rl)
         table = _class_run_table(pat.const)
         if table is not None:
+            self._regex_site("classrun")
             fb, fl = S.replace_class_runs(rb, rl, table, repl.const)
             return CV(t=T.STR, sbytes=fb, slen=fl)
         return self._re_sub_general(pat.const, repl.const, rb, rl)
+
+    def _re_sub_anchored(self, pattern: str, repl: CV, rb, rl) -> CV:
+        """re.sub with a ^-anchored pattern: ^ matches at position 0 alone
+        (compiled patterns carry no MULTILINE), so python replaces at most
+        the one match there, and the direct lowering's match end is all
+        the splice needs — no suffix-restart loop. The replacement may be
+        a per-row string: a row whose replacement holds a backslash (a
+        template — group references, escapes) falls back, as does a row
+        the engine cannot decide; an unmatched row is returned as is."""
+        from ..ops.regex import compile_regex
+
+        rx = compile_regex(pattern)        # may raise NotCompilable
+        rep = materialize(repl, self.ctx.b)
+        pb, pl = self._to_strpair(rep)
+        if not repl.is_const:
+            cols = jnp.arange(pb.shape[1], dtype=jnp.int32)[None, :]
+            self.raise_where(
+                jnp.any((pb == 92) & (cols < pl[:, None]), axis=1),
+                ExceptionCode.REGEXFALLBACK)
+        self._regex_site(rx.engine())
+        matched, suspect, _gs, ge = rx.match_decided(rb, rl)
+        self.raise_where(suspect & ~matched, ExceptionCode.REGEXFALLBACK)
+        fb, fl = S.splice_prefix(rb, rl, ge[0], pb, pl, matched)
+        return CV(t=T.STR, sbytes=fb, slen=fl)
 
     _RE_SUB_MAX_MATCHES = 8
 
@@ -1359,6 +1412,9 @@ class Frame:
         if nfa.nullable or not 0 < nfa.n_pos <= nfa._START_MAX_POS:
             raise NotCompilable("re.sub pattern outside compiled bounds")
         rx2 = compile_regex("^" + pattern)   # may raise NotCompilable
+        from ..ops.nfa import _nfa_impl
+
+        self._regex_site(f"nfa:{_nfa_impl()}+direct")
         b = self.ctx.b
         zero = jnp.zeros(b, dtype=rl.dtype)
         o = zero
@@ -1381,7 +1437,7 @@ class Frame:
             active = mk
         sufb, sufl = S.slice_(rb, rl, o, rl)
         suspect = suspect | (nfa.match(sufb, sufl) & active)
-        self.raise_where(suspect, ExceptionCode.PYTHON_FALLBACK)
+        self.raise_where(suspect, ExceptionCode.REGEXFALLBACK)
         fb, fl = S.splice_spans(rb, rl,
                                 jnp.stack(starts, axis=1),
                                 jnp.stack(ends, axis=1),
@@ -1444,7 +1500,7 @@ class Frame:
             # unanchored two-pass: the anchored engine runs only here,
             # where groups are actually consumed (+ its fail-safe routing)
             elts, suspect = m.names[1]()
-            self.raise_where(suspect, ExceptionCode.PYTHON_FALLBACK)
+            self.raise_where(suspect, ExceptionCode.REGEXFALLBACK)
         if not 0 <= idx < len(elts):
             raise NotCompilable(f"no such regex group {idx}")
         # match is None -> .group raises AttributeError (python semantics)
@@ -1542,6 +1598,11 @@ class Frame:
         if v.base is T.BOOL:
             return v.data.astype(jnp.int64)
         return v.data
+
+    def _regex_site(self, engine: str) -> None:
+        """Record one compiled regex site and the engine it runs (the
+        stage's compile span and its `regex_fallback_rows` read them)."""
+        self.ctx.regex_sites.append(engine)
 
     def _ascii_guard(self, sbytes, slen):
         """Index-space string ops count BYTES; multibyte UTF-8 rows diverge
@@ -2142,15 +2203,21 @@ class Frame:
             items = self._cv_iter_items(args[0])
             if items is None:
                 raise NotCompilable("join over non-static iterable")
-            out: Optional[CV] = None
-            sep_cv = const_cv(recv.const)
             for it in items:
                 if not (it.base is T.STR or
                         (it.is_const and isinstance(it.const, str))):
                     raise NotCompilable("join of non-str element")
-                out = it if out is None else self._str_concat(
-                    self._str_concat(out, sep_cv), it)
-            return out if out is not None else const_cv("")
+            if not items:
+                return const_cv("")
+            if all(it.is_const for it in items):
+                return const_cv(recv.const.join(it.const for it in items))
+            parts = []
+            for i, it in enumerate(items):
+                if i and recv.const:
+                    parts.append(S.broadcast_const(recv.const, self.ctx.b))
+                parts.append(self._to_strpair(materialize(it, self.ctx.b)))
+            fb, fl = S.concat_many(parts)
+            return CV(t=T.STR, sbytes=fb, slen=fl)
         if name in ("center", "ljust", "rjust"):
             # width semantics are per CHARACTER: multibyte rows must take
             # the interpreter path like the other byte-position methods
@@ -2618,6 +2685,17 @@ class Frame:
             self.raise_where(~has_k, ExceptionCode.STOPITERATION)
             return val
         return merge_cv(self, has_k, val, default)
+
+    def _builtin_list(self, args: list[CV]) -> CV:
+        """list() over a static iterable (`list(string.ascii_uppercase)`,
+        a tuple, a range): a list literal of its items, as eval_List
+        builds one."""
+        if len(args) > 1:
+            raise NotCompilable("list arity")
+        items = self._cv_iter_items(args[0]) if args else []
+        if items is None:
+            raise NotCompilable("list over non-static iterable")
+        return tuple_cv(list(items), kind="list")
 
     def _builtin_sorted(self, args: list[CV]) -> CV:
         """sorted() over a static iterable via a compare-exchange network

@@ -34,6 +34,8 @@ class ExceptionCode(enum.IntEnum):
     NULLERROR = 102            # unexpected None on a non-Option path
     GENERALCASEVIOLATION = 103
     LOOPCAPEXCEEDED = 104      # while-loop unroll cap hit: interpreter row
+    REGEXFALLBACK = 105        # compiled regex site cannot decide the row:
+                               # interpreter row
     PYTHON_FALLBACK = 110      # UDF not compilable: row routed to interpreter
     UNKNOWN = 120
 

@@ -1134,6 +1134,10 @@ def compile_traced(fn, args: tuple, donate_argnums=(), salt: str = "",
         _sp.set("tag", tag[:16])
         with _FORK_GATE:   # traces take the shared MLIR/C++ context
             traced = trace_m(*args)   # locks a fork must not snapshot
+        sites = getattr(fn, "regex_sites", None)
+        if sites:          # a stage fn's compiled regex sites
+            _sp.set("regex_sites", len(sites)).set(
+                "regex_engine", ",".join(sites))
     with _LOCK:
         STATS["traces"] += 1
     try:

@@ -253,6 +253,14 @@ class _Launch:
     ready_at: float = 0.0
 
 
+def _with_sites(device_fn, raw_fn):
+    """`device_fn`, the jitted form of `raw_fn`, keeping `raw_fn`'s compiled
+    regex sites (filled as it is traced): a stage's records count
+    `regex_fallback_rows` where they are."""
+    device_fn.regex_sites = getattr(raw_fn, "regex_sites", ())
+    return device_fn
+
+
 class _DispatchFailed:
     """Sentinel riding the dispatch window when the device call itself
     raised synchronously (wedged runtime, lost mesh) — the collect side
@@ -883,7 +891,8 @@ class LocalBackend:
             try:
                 return self._collect_partition(
                     stage, part, outs, dispatch_s,
-                    intermediate=intermediate, launch=armed(launch))
+                    intermediate=intermediate, launch=armed(launch),
+                    regex_sites=getattr(device_fn, "regex_sites", ()))
             finally:
                 if launch is not None and launch.ready_at:
                     last_ready = launch.ready_at
@@ -1021,6 +1030,10 @@ class LocalBackend:
             metrics["slow_path_s"] += m.get("slow_path_s", 0.0)
             metrics["general_path_s"] += m.get("general_path_s", 0.0)
             metrics["compaction_reruns"] += m.get("compaction_reruns", 0)
+            if "regex_fallback_rows" in m:
+                # rows a compiled regex site could not decide
+                metrics["regex_fallback_rows"] = metrics.get(
+                    "regex_fallback_rows", 0) + m["regex_fallback_rows"]
             metrics["dispatches_waited"] += m.get("dispatches_waited", 0)
             metrics["dispatches_ready"] += m.get("dispatches_ready", 0)
             exceptions.extend(excs)
@@ -1346,15 +1359,14 @@ class LocalBackend:
                         "tuplex.tpu.compileDeadlineS", 0.0)
                     return self.jit_cache.get_or_build(
                         ("stagefn", skey, use_comp, "cpupin"),
-                        lambda: _CpuJit(raw_fn, tag=stage.key(),
-                                        n_ops=len(stage.ops),
-                                        deadline=deadline)), use_comp
+                        lambda: _with_sites(_CpuJit(
+                            raw_fn, tag=stage.key(), n_ops=len(stage.ops),
+                            deadline=deadline), raw_fn)), use_comp
                 return self.jit_cache.get_or_build(
                     ("stagefn", skey, use_comp, packed),
-                    lambda: self._jit_stage_fn(raw_fn, packed=packed,
-                                               tag=stage.key(),
-                                               n_ops=len(stage.ops))), \
-                    use_comp
+                    lambda: _with_sites(self._jit_stage_fn(
+                        raw_fn, packed=packed, tag=stage.key(),
+                        n_ops=len(stage.ops)), raw_fn)), use_comp
             except NotCompilable:
                 self._not_compilable.add(skey)
                 return None, use_comp
@@ -1561,7 +1573,8 @@ class LocalBackend:
     def _collect_partition(self, stage: TransformStage, part: C.Partition,
                            pending_outs, dispatch_s: float,
                            intermediate: bool = False,
-                           launch: Optional[_Launch] = None):
+                           launch: Optional[_Launch] = None,
+                           regex_sites=()):
         import jax
 
         metrics: dict[str, float] = {}
@@ -1713,6 +1726,10 @@ class LocalBackend:
                     ex_defer.append((EX.note_device, (stage.key(), n, codes),
                                      {"fallback_rows": len(part.fallback),
                                       "owner": id(self)}))
+                if regex_sites:
+                    metrics["regex_fallback_rows"] = int(np.count_nonzero(
+                        (err[err_idx] & 0xFF)
+                        == int(ExceptionCode.REGEXFALLBACK)))
                 compiled_ok = rowvalid & keep & (err == 0)
                 fold_vals = []
                 while f"#fold{len(fold_vals)}" in outs:

@@ -348,6 +348,63 @@ class CompiledRegex:
         suspect = died_late
         return alive, suspect, gs, ge
 
+    # suspect rows the exact engine examines per batch (a share of the
+    # batch, at least _DECIDE_MIN), and the width it scans them at: a
+    # longer row, or one beyond the share, stays suspect
+    _DECIDE_SHARE = 16
+    _DECIDE_MIN = 64
+    _DECIDE_W = 256
+
+    @property
+    def nfa(self):
+        """The pattern's exact NFA (ops/nfa.py), or None where the NFA
+        cannot hold it (then no suspect row is decided)."""
+        if not hasattr(self, "_nfa"):
+            from .nfa import compile_nfa
+
+            try:
+                self._nfa = compile_nfa(self.pattern)
+            except NotCompilable:
+                self._nfa = None
+        return self._nfa
+
+    def engine(self) -> str:
+        """The engines a site on this pattern runs: the direct lowering,
+        and the NFA implementation that decides its suspect rows."""
+        if self.nfa is None or self.first_var >= len(self.steps):
+            return "direct"
+        from .nfa import _nfa_impl
+
+        return f"direct+nfa:{_nfa_impl()}"
+
+    def match_decided(self, bytes_, lens):
+        """match(), with the suspect rows decided where that is exact: a
+        suspect row is one whose run died where unexplored backtracking
+        may remain, and the NFA answers exactly whether ANY match exists.
+        A suspect row it proves unmatched is an authoritative no-match
+        (python's re.search returns None there); one it proves matched
+        stays suspect, since its groups are unknown. The suspects are
+        gathered into a block of at most max(_DECIDE_MIN, N/_DECIDE_SHARE)
+        rows scanned at _DECIDE_W bytes, so the scan costs a small share
+        of the batch; suspects beyond the block or longer than the width
+        stay suspect (fail-safe)."""
+        alive, suspect, gs, ge = self.match(bytes_, lens)
+        nfa = self.nfa
+        if nfa is None or self.first_var >= len(self.steps):
+            return alive, suspect, gs, ge
+        n, w = bytes_.shape
+        k = min(n, max(self._DECIDE_MIN, n // self._DECIDE_SHARE))
+        dw = min(w, self._DECIDE_W)
+        cand = suspect & (lens <= dw)
+        idx = jnp.nonzero(cand, size=k, fill_value=n)[0]
+        real = idx < n
+        at = jnp.minimum(idx, n - 1)
+        sub_l = jnp.where(real, jnp.take(lens, at), 0)
+        found = nfa.match(jnp.take(bytes_[:, :dw], at, axis=0), sub_l)
+        unmatched = jnp.zeros(n, dtype=bool).at[idx].set(
+            real & ~found, mode="drop")
+        return alive, suspect & ~unmatched, gs, ge
+
 
 _REGEX_CACHE: dict[str, CompiledRegex] = {}
 

@@ -247,11 +247,35 @@ def slice_(bytes_, lens, start, stop, out_width: int | None = None):
                 out_len.astype(jnp.int32))
     start = jnp.clip(jnp.where(start < 0, start + lens, start), 0, lens)
     out_len = jnp.maximum(stop - start, 0)
-    idx = start[:, None] + cols
-    idx_c = jnp.clip(idx, 0, w - 1)
-    out = take_cols(bytes_, idx_c)
+    out = shifted_cols(bytes_, start, wout)
     keep = cols < out_len[:, None]
     return jnp.where(keep, out, 0).astype(jnp.uint8), out_len.astype(jnp.int32)
+
+
+def shifted_cols(mat, shift, wout: int):
+    """out[:, j] = mat[:, j + shift] for j < wout, with a per-row shift in
+    [0, W]; a column past the row's width reads mat's last column (the
+    callers mask by length). On an accelerator past _OH_MAX_W, where the
+    gather would run on the scalar core, the shift is a barrel of
+    log2(W) static column shifts, high bits first: each stage selects
+    between a matrix and its shifted copy and keeps only the columns the
+    remaining (smaller) shifts can still reach, so the stages shrink
+    towards `wout` (VPU work, no gather)."""
+    n, w = mat.shape
+    shift = shift.astype(jnp.int32)
+    if not (w > _OH_MAX_W and _mxu_gather()):
+        cols = jnp.arange(wout, dtype=jnp.int32)[None, :]
+        return take_cols(mat, jnp.clip(shift[:, None] + cols, 0, w - 1))
+    x = mat
+    for b in reversed(range(int(w).bit_length())):
+        s = 1 << b
+        need = wout + s - 1      # the columns shifts below 2**b can reach
+        if x.shape[1] < need + s:
+            x = jnp.pad(x, ((0, 0), (0, need + s - x.shape[1])),
+                        mode="edge")
+        on = ((shift >> b) & 1).astype(bool)[:, None]
+        x = jnp.where(on, x[:, s:s + need], x[:, :need])
+    return x[:, :wout]
 
 
 def char_at(bytes_, lens, idx):
@@ -444,6 +468,28 @@ def concat(a_bytes, a_lens, b_bytes, b_lens):
     inside = (pos < a_lens[:, None]) | valid_b
     out = jnp.where(inside, out, 0)
     return out.astype(jnp.uint8), (a_lens + b_lens).astype(jnp.int32)
+
+
+def concat_many(parts: list):
+    """a + b + ... for [(bytes, lens)] in one pass: each part lands at the
+    sum of the lengths before it, read where it covers the output (a
+    one-byte part is broadcast, wider ones gathered). No scatter, however
+    many parts: `''.join` of ten drawn letters is one assembly, not ten
+    growing concats. Returns (bytes [N, sum of widths], lens)."""
+    n = parts[0][0].shape[0]
+    wout = sum(b.shape[1] for b, _ in parts)
+    pos = jnp.arange(wout, dtype=jnp.int32)[None, :]
+    out = jnp.zeros((n, wout), dtype=jnp.uint8)
+    off = jnp.zeros(n, dtype=jnp.int32)
+    for b, ln in parts:
+        w = b.shape[1]
+        rel = pos - off[:, None]
+        inside = (rel >= 0) & (rel < ln[:, None])
+        got = jnp.broadcast_to(b, (n, wout)) if w == 1 else \
+            take_cols(b, jnp.clip(rel, 0, w - 1))
+        out = jnp.where(inside, got, out)
+        off = off + ln.astype(jnp.int32)
+    return out.astype(jnp.uint8), off
 
 
 # ---------------------------------------------------------------------------
@@ -1245,6 +1291,27 @@ def splice_spans(bytes_, lens, starts, ends, valid, new: str):
     n_spans = jnp.sum(valid.astype(jnp.int32), axis=1)
     out_lens = lens - total_removed + r * n_spans
     return out, out_lens.astype(lens.dtype)
+
+
+def splice_prefix(bytes_, lens, end, new_bytes, new_lens, valid):
+    """`new + s[end:]` where `valid`, else `s`: the output assembler of an
+    anchored re.sub, whose one match is s[:end] (emitter._re_sub_anchored).
+    The kept tail moves by `len(new) - end`, either way: over the source
+    padded on the left by Wnew it is one left shift (shifted_cols).
+    Returns (bytes [N, W + Wnew], lens)."""
+    n, w = bytes_.shape
+    wr = new_bytes.shape[1]
+    wout = w + wr
+    pos = jnp.arange(wout, dtype=jnp.int32)[None, :]
+    rl = jnp.where(valid, new_lens, 0).astype(jnp.int32)
+    cut = jnp.where(valid, end, 0).astype(jnp.int32)
+    out_len = lens.astype(jnp.int32) - cut + rl
+    src = jnp.pad(bytes_, ((0, 0), (wr, 0)))
+    tail = shifted_cols(src, wr - rl + cut, wout)
+    head = jnp.pad(new_bytes, ((0, 0), (0, wout - wr)))
+    out = jnp.where(pos < rl[:, None], head, tail)
+    out = jnp.where(pos < out_len[:, None], out, 0)
+    return out.astype(jnp.uint8), out_len
 
 
 def replace_class_runs(bytes_, lens, table: np.ndarray, new: str):

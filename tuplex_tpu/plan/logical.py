@@ -30,6 +30,7 @@ from ..utils.lru import LruDict
 
 _cross_job_samples: LruDict = LruDict(256)
 _cross_job_branchprofs: LruDict = LruDict(256)
+_cross_job_regexprofs: LruDict = LruDict(256)
 _cross_job_schemas: LruDict = LruDict(512)
 
 
@@ -227,6 +228,27 @@ class UDFOperator(LogicalOperator):
                 if ck is not None:
                     _cross_job_branchprofs[ck] = memo
             self._branch_prof_memo = memo
+        return memo
+
+    def regex_widths(self) -> dict:
+        """The window of each capture group of the UDF's constant regex
+        patterns, from its sample (compiler/regexprof.py): {pattern:
+        (width of group 1, ...)}; memoized like `branch_profile`."""
+        memo = getattr(self, "_regex_widths_memo", None)
+        if memo is None:
+            from ..compiler.regexprof import profile_group_widths, reaches_re
+
+            memo = {}
+            if reaches_re(self.udf):
+                ck = self.chain_key()
+                hit = _cross_job_regexprofs.get(ck) if ck is not None \
+                    else None
+                memo = hit if hit is not None else profile_group_widths(
+                    self.udf, self.parent.cached_sample(),
+                    self._profile_call)
+                if ck is not None:
+                    _cross_job_regexprofs[ck] = memo
+            self._regex_widths_memo = memo
         return memo
 
     def _profile_call(self, f, row) -> None:

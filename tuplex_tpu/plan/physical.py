@@ -462,6 +462,11 @@ class TransformStage:
         if self.fold_op is not None:
             h.update(b"fold")
             h.update(_op_identity(self.fold_op).encode())
+        for op in self.ops:
+            # the group windows are read off the sample too
+            rw = _regex_widths(op)
+            if rw:
+                h.update(b"rxw" + repr(sorted(rw.items())).encode())
         if self.speculate_branches:
             # the emitted kernel is specialized on the (data-dependent)
             # sample branch profile — a different dataset with the same UDF
@@ -525,6 +530,7 @@ class TransformStage:
             raise NotCompilable("stage has no general-case decode")
 
         plan = _compaction_plan(ops) if (compaction and not general) else {}
+        sites: list = []        # the regex sites' engines, filled at trace
         fold_spec = None
         if fused_fold and self.fold_op is not None and not general:
             from . import aggregates as A
@@ -567,6 +573,7 @@ class TransformStage:
                                                    full_err, overflow,
                                                    b2, b)
                         bcur = b2
+            sites[:] = ctx.regex_sites
             outs, out_t = result_arrays(row, bcur)
             outs = dict(outs)
             fin = keep & (ctx.err == 0)
@@ -590,8 +597,13 @@ class TransformStage:
         # `stagegen`), so a device trace names the stage it ran
         from ..runtime import tracing as TR
 
-        return TR.name_fn(fn, "stagegen" if general else "stage",
-                          self.key())
+        named = TR.name_fn(fn, "stagegen" if general else "stage",
+                           self.key())
+        # the engines of its compiled regex sites, filled as it is traced:
+        # compile:trace's attributes, and the executor's cue to count
+        # `regex_fallback_rows` (it keeps them on the jitted fn)
+        named.regex_sites = sites
+        return named
 
 
 def _fusion_barrier(ctx: EmitCtx, row: CV, keep):
@@ -823,7 +835,7 @@ def _emit_op(ctx: EmitCtx, op: L.LogicalOperator, row: CV, keep,
                 prof = None
     em = Emitter(ctx, getattr(op, "udf", None).globals
                  if getattr(op, "udf", None) else {},
-                 branch_profile=prof)
+                 branch_profile=prof, regex_widths=_regex_widths(op))
     frame = Frame(em, {})
     if isinstance(op, L.MapOperator):
         res = em.eval_udf(op.udf, [row])
@@ -1288,6 +1300,18 @@ def op_compiles(op: L.LogicalOperator, input_schema: T.RowType,
     return result
 
 
+def _regex_widths(op) -> dict:
+    """An operator's sampled capture-group windows ({} where it has
+    none: the emitter's fixed window then)."""
+    rw = getattr(op, "regex_widths", None)
+    if rw is None:
+        return {}
+    try:
+        return rw()
+    except Exception:
+        return {}
+
+
 def _branch_profile_sig(op) -> str:
     """Stable signature of an operator's sample branch observations (empty
     when the op has none). Feeds every cache whose value depends on the
@@ -1345,7 +1369,10 @@ def abstract_batch_arrays(input_schema: T.RowType):
     from ..runtime.jaxcfg import jax
     import numpy as np
 
-    arrays: dict = {"#rowvalid": jax.ShapeDtypeStruct((8,), np.bool_)}
+    # '#seed': the partition's PRNG seed, staged beside every batch
+    # (columns.stage_partition) and read by compiled `random` calls
+    arrays: dict = {"#rowvalid": jax.ShapeDtypeStruct((8,), np.bool_),
+                    "#seed": jax.ShapeDtypeStruct((), np.uint32)}
     for ci, ct in enumerate(input_schema.types):
         for path, lt in flatten_type(ct, str(ci)):
             base = lt.without_option() if lt.is_optional() else lt
@@ -1473,6 +1500,37 @@ def _vet_stage(stage: TransformStage, options) -> object:
         return report
     _apply_wedge_degrade(stage, report)
     return report
+
+
+def _source_str_extra_bytes(stage: TransformStage) -> int:
+    """Bytes a row of `stage`'s string inputs holds beyond the probe's 8
+    (abstract_batch_arrays), for a stage that reads a CSV source: each
+    string column at the width ingest will give it, the widest cell of
+    the source's sample bucketed as harmonize_partitions does. Never
+    below the probe's, so the estimate it feeds only ever falls; 0 for a
+    stage that reads no CSV source."""
+    from ..io.csvsource import CSVSourceOperator
+    from ..runtime.columns import bucket_size
+
+    src = stage.source
+    if not isinstance(src, CSVSourceOperator):
+        return 0
+    stat = src.stat
+    at = {c: i for i, c in enumerate(stat.columns)}
+    # memo on the sniffed statistic, which later jobs over the file share
+    widths = stat.__dict__.setdefault("_sampled_widths", {})
+    extra = 0
+    for c, t in zip(stage.input_schema.columns, stage.input_schema.types):
+        t = t.without_option() if t.is_optional() else t
+        if t is not T.STR or c not in at:
+            continue
+        if c not in widths:
+            i = at[c]
+            widths[c] = bucket_size(max(
+                (len(r[i].encode()) for r in stat.sample_rows
+                 if i < len(r)), default=0), minimum=8)
+        extra += max(widths[c] - 8, 0)
+    return extra
 
 
 #: probe-trace admission for _vet_stage: below ALL of these a stage can
@@ -1632,9 +1690,15 @@ def _split_oversize(stage: TransformStage, options,
             and not stage.force_interpret and report.peak_bytes > 0:
         mem_budget = options.get_size("tuplex.executorMemory", 1 << 30)
         psize = options.get_size("tuplex.partitionSize", 4 << 20)
-        est_rows = psize // max(report.input_row_bytes, 1) \
+        # a batch holds a partition's bytes: the probe's input row, its
+        # strings at the width ingest will give them (the probe's are 8
+        # bytes wide, so a log line of a kilobyte read as 8 bytes put
+        # millions of rows in a 32 MB partition)
+        in_row = report.input_row_bytes + _source_str_extra_bytes(stage)
+        est_rows = psize // max(in_row, 1) \
             if report.input_row_bytes > 0 else report.traced_rows
         peak = report.peak_bytes_at(est_rows)
+        remedy = "none"
         if mem_budget > 0 and peak > mem_budget:
             from ..compiler import graphlint as GL
             from ..utils.logging import get_logger
@@ -1654,6 +1718,12 @@ def _split_oversize(stage: TransformStage, options,
                 f"{mem_budget >> 20} MiB — {remedy}"))
             get_logger("plan").warning(
                 "graphlint: %s", report.findings[-1].message)
+        stage.static_peak = {"est_rows": int(est_rows),
+                             "peak_bytes": int(peak), "remedy": remedy}
+        from ..runtime import tracing as TR
+
+        TR.instant("plan:static-peak", "plan",
+                   dict(stage.static_peak, ops=n, budget=int(mem_budget)))
     if not max_ops or n <= max_ops or stage.force_interpret:
         return [stage]
     import math
@@ -1695,6 +1765,8 @@ def _split_oversize(stage: TransformStage, options,
         if dec is not None:
             seg.split_decision = dec
             seg.predicted_compile_s = ST.predict(platform, len(ops_run))
+        if hasattr(stage, "static_peak"):     # the check that cut it
+            seg.static_peak = stage.static_peak
         for op in ops_run:
             if not isinstance(op, (L.ResolveOperator, L.IgnoreOperator)):
                 schema = op.schema()
