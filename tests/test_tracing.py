@@ -922,7 +922,13 @@ def test_merge_names_its_path_and_the_work_inside(trace_on, tmp_path,
         fetch, splice = kids["d2h:merge-fetch"], kids["merge:splice"]
         assert fetch["args"]["bytes"] > 0 and fetch["args"]["ready"] in (0, 1)
         assert fetch["ts"] < splice["ts"]
-        spliced += splice["args"]["rows"]
+        # the emit order from masks, each leaf one take over a full cover
+        # of the partition; every resolved row folded into its slot
+        sa = splice["args"]
+        assert sa["take"] == 1 and sa["boxed"] == 0
+        assert sa["rows_out"] == m["args"]["rows"] == \
+            sa["gathered"] + sa["rows"]
+        spliced += sa["rows"]
     assert spliced == 3000 // 50
     # nothing resolved: the output stays on the device behind a gathered
     # view, and nothing is fetched

@@ -2151,24 +2151,22 @@ class LocalBackend:
             if sp is not TR.NOOP:
                 sp.set("rows", len(resolved))
             return self._splice(stage, part, compiled_ok, out_arrays,
-                                resolved, src_map)
+                                resolved, src_map, span=sp)
 
     def _splice(self, stage: TransformStage, part: C.Partition,
                 compiled_ok: np.ndarray, out_arrays: dict,
                 resolved: dict[int, Row],
-                src_map: np.ndarray | None) -> C.Partition:
+                src_map: np.ndarray | None, span=TR.NOOP) -> C.Partition:
         """`_merge` where rows were resolved off the compiled path: the
-        emit order, the compiled rows gathered into it and the resolved
-        ones folded in (boxed into `fallback` where they do not fit)."""
+        emit order read off masks, the compiled rows and the resolved
+        slots taken into it in one pass a leaf, and the resolved rows
+        folded in (boxed into `fallback` where they do not fit). Python
+        walks the resolved rows alone, never the partition's every row.
+        A resolved row wins over its compiled one."""
         n = part.num_rows
-        emit_rows: list[tuple[int, Optional[int], Optional[Row]]] = []
-        # (orig_idx, compiled_src or None, resolved Row or None)
-        for i in range(n):
-            if i in resolved:
-                emit_rows.append((i, None, resolved[i]))
-            elif compiled_ok[i]:
-                emit_rows.append((i, i, None))
-        m = len(emit_rows)
+        keys = np.fromiter(sorted(resolved), dtype=np.int64,
+                           count=len(resolved))
+        rows = [resolved[i] for i in keys.tolist()]
 
         if not out_arrays:
             # interpreter-only: build straight from python rows. Schema
@@ -2176,56 +2174,65 @@ class LocalBackend:
             # sample speculation — projection/segmentation may have changed
             # the shape.
             values = [row.unwrap() if len(row.values) == 1
-                      else tuple(row.values)
-                      for (_, _, row) in emit_rows]
-            rows_only = [row for (_, _, row) in emit_rows]
-            schema = _schema_from_rows(rows_only) or \
+                      else tuple(row.values) for row in rows]
+            schema = _schema_from_rows(rows) or \
                 _normalized_output_schema(stage)
             outp = C.build_partition(values, schema,
                                      start_index=part.start_index)
+            span.set("rows_out", outp.num_rows).set("gathered", 0) \
+                .set("boxed", len(outp.fallback)).set("take", 0)
             return outp
 
         from ..plan.physical import runtime_output_columns
 
+        rmask = np.zeros(n, dtype=np.bool_)
+        rmask[keys] = True
+        comp = np.asarray(compiled_ok[:n], dtype=np.bool_) & ~rmask
+        pos = np.cumsum(rmask | comp) - 1       # each emitted row's slot
+        m = int(pos[-1]) + 1 if n else 0
+        comp_src = np.nonzero(comp)[0]
+        res_ks = pos[keys]
         out_cols = runtime_output_columns(part.schema, stage.ops)
         n_full = n if src_map is None else \
             int(next(iter(out_arrays.values())).shape[0])
         full = C.partition_from_result_arrays(
             out_arrays, n_full, columns=out_cols,
             start_index=part.start_index)
-        comp_out = np.asarray([k for k, (_, src, _) in enumerate(emit_rows)
-                               if src is not None], dtype=np.int64)
-        comp_src = np.asarray([src for (_, src, _) in emit_rows
-                               if src is not None], dtype=np.int64)
-        if src_map is not None and comp_src.size:
+        # a source row for every slot, so gather_partition takes each leaf
+        # in one pass: a resolved slot reads its own row (row 0 of the
+        # compacted outputs, which hold 64 rows or more), cleared below
+        src = np.empty(m, dtype=np.int64)
+        if src_map is None:
+            src[pos[comp_src]] = comp_src
+            src[res_ks] = keys
+        else:
             # compacted device outputs: original position -> compact slot
-            comp_src = src_map[comp_src]
-        outp = C.gather_partition(full, comp_out, comp_src, m)
+            src[pos[comp_src]] = src_map[comp_src]
+            src[res_ks] = 0
+        outp = C.gather_partition(full, np.arange(m, dtype=np.int64), src, m)
+        _clear_rows(outp.leaves, res_ks)
         out_schema = outp.schema
+        span.set("rows_out", m).set("gathered", int(comp_src.size)) \
+            .set("take", 1)
 
-        res_ks = []
-        res_vals = []
-        for k, (_, src, row) in enumerate(emit_rows):
-            if row is None:
-                continue
-            res_ks.append(k)
-            res_vals.append(row.unwrap() if len(out_schema.columns) == 1
-                            else tuple(row.values))
-        if not res_ks:
-            return outp
-        if _bulk_fold_rows(outp.leaves, out_schema,
-                           np.asarray(res_ks, dtype=np.int64), res_vals):
-            return outp
-        normal_mask = np.ones(m, dtype=np.bool_)
-        fallback: dict[int, Any] = {}
-        for k, value in zip(res_ks, res_vals):
-            if _try_fold_row(outp.leaves, out_schema, k, value):
-                continue
-            normal_mask[k] = False
-            fallback[k] = value
-        if fallback:
-            outp.normal_mask = normal_mask
-            outp.fallback = fallback
+        single = len(out_schema.columns) == 1
+        fold = [(k, row.unwrap() if single else tuple(row.values))
+                for k, row in zip(res_ks.tolist(), rows)]
+        if fold and not _bulk_fold_rows(
+                outp.leaves, out_schema,
+                np.asarray([k for k, _ in fold], dtype=np.int64),
+                [v for _, v in fold]):
+            normal_mask = np.ones(m, dtype=np.bool_)
+            fallback: dict[int, Any] = {}
+            for k, value in fold:
+                if _try_fold_row(outp.leaves, out_schema, k, value):
+                    continue
+                normal_mask[k] = False
+                fallback[k] = value
+            if fallback:
+                outp.normal_mask = normal_mask
+                outp.fallback = fallback
+        span.set("boxed", len(outp.fallback))
         return outp
 
 
@@ -2349,6 +2356,25 @@ def _truncate_partition(p: C.Partition, k: int) -> C.Partition:
         normal_mask=None if p.normal_mask is None else p.normal_mask[:k],
         fallback={i: v for i, v in p.fallback.items() if i < k},
         start_index=p.start_index)
+
+
+def _clear_rows(leaves: dict, ks: np.ndarray) -> None:
+    """Zero the slots `ks` of every leaf (data, bytes, lengths, valid
+    bits), as `gather_partition`'s zero-fill leaves a slot it gives no
+    source row: the resolved rows are then folded into clean slots."""
+    for leaf in leaves.values():
+        if isinstance(leaf, C.NumericLeaf):
+            leaf.data[ks] = 0
+            if leaf.valid is not None:
+                leaf.valid[ks] = False
+        elif isinstance(leaf, C.StrLeaf):
+            leaf.bytes[ks] = 0
+            leaf.lengths[ks] = 0
+            if leaf.valid is not None:
+                leaf.valid[ks] = False
+        elif isinstance(leaf, C.ObjectLeaf):
+            for k in ks.tolist():
+                leaf.values[k] = None
 
 
 def _bulk_fold_rows(leaves: dict, schema: T.RowType,

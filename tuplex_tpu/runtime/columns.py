@@ -957,27 +957,36 @@ def leaf_from_result_arrays(arrays: dict, path: str, lt: T.Type,
 def gather_partition(part: Partition, out_positions: np.ndarray,
                      src_indices: np.ndarray, m: int) -> Partition:
     """New m-row partition with rows src_indices placed at out_positions
-    (other slots zero placeholders, to be filled by resolved rows)."""
+    (other slots zero placeholders, to be filled by resolved rows).
+
+    Where out_positions is exactly 0..m-1, a full cover, every slot has
+    its source and each array is one take; any other cover zero-fills
+    the array and scatters the rows into it. Both give the same arrays."""
+    cover = np.array_equal(out_positions, np.arange(m))
+
+    def place(a: np.ndarray, shape, dtype) -> np.ndarray:
+        if cover:
+            return a.take(src_indices, axis=0).astype(dtype, copy=False)
+        out = np.zeros(shape, dtype=dtype)
+        if len(src_indices):
+            out[out_positions] = a[src_indices]
+        return out
+
     leaves: dict[str, Leaf] = {}
     for path, leaf in part.leaves.items():
         if isinstance(leaf, NumericLeaf):
-            data = np.zeros(m, dtype=leaf.data.dtype)
-            valid = None if leaf.valid is None else np.zeros(m, np.bool_)
-            if len(src_indices):
-                data[out_positions] = leaf.data[src_indices]
-                if valid is not None:
-                    valid[out_positions] = leaf.valid[src_indices]
-            leaves[path] = NumericLeaf(data, valid)
+            leaves[path] = NumericLeaf(
+                place(leaf.data, m, leaf.data.dtype),
+                None if leaf.valid is None else
+                place(leaf.valid, m, np.bool_))
         elif isinstance(leaf, StrLeaf):
-            b = np.zeros((m, max(leaf.width, 1)), dtype=np.uint8)
-            ln = np.zeros(m, dtype=np.int32)
-            valid = None if leaf.valid is None else np.zeros(m, np.bool_)
-            if len(src_indices):
-                b[out_positions] = leaf.bytes[src_indices]
-                ln[out_positions] = leaf.lengths[src_indices]
-                if valid is not None:
-                    valid[out_positions] = leaf.valid[src_indices]
-            leaves[path] = StrLeaf(b, ln, valid)
+            w = leaf.width
+            leaves[path] = StrLeaf(
+                place(leaf.bytes, (m, w), np.uint8) if w else
+                np.zeros((m, 1), dtype=np.uint8),
+                place(leaf.lengths, m, np.int32),
+                None if leaf.valid is None else
+                place(leaf.valid, m, np.bool_))
         elif isinstance(leaf, NullLeaf):
             leaves[path] = NullLeaf(m)
         else:
